@@ -20,7 +20,10 @@ either):
   the same fleet and workload.
 
   PYTHONPATH=src python benchmarks/traffic_gen.py --smoke \
-      --workers golden:thread,pallas:subprocess | tee serve-fleet.csv
+      --workers golden:thread,pallas:thread | tee serve-fleet.csv
+
+Workers on a TPU host are threads: the chip belongs to the one process
+that holds it, so the fleet refuses subprocess workers there.
 """
 from __future__ import annotations
 

@@ -370,7 +370,9 @@ def _decode_session_report(prog, backend: str = "golden", seed: int = 0,
 
 
 def main(argv: list[str] | None = None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.list:
         print("\n".join(list_networks()))
         return 0

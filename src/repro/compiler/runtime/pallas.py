@@ -24,11 +24,16 @@ tile-by-tile assembly bit for bit — tiling/fusing an exact integer
 GEMM is associative, and the dequant scale is per output element. The
 pass-invariance suite and ``tests/test_fused_kernels.py`` pin this.
 
-On TPU the calls dispatch the actual Pallas kernels
-(``kernels/fused_hetero_gemm.py`` etc.); on CPU they fall back to the
-vectorized jnp oracles — still orders of magnitude faster than the
-interpreter's per-tile loop. ``mode`` is forwarded to the kernel
-wrappers ("auto" | "kernel" | "ref").
+``mode`` is forwarded to the kernel wrappers ("auto" | "kernel" |
+"ref"). Under "auto" on a TPU, dense layers and in-budget convs run
+the compiled Pallas kernels (``kernels/fused_hetero_gemm.py``);
+depthwise convs run an exact int32 einsum under XLA, and convs whose
+whole-spatial working set is over the VMEM budget run the jnp oracle
+under XLA. Off the TPU, "auto" runs the jnp oracles everywhere. Each
+executed layer records where it ran (``kernels.ops.conv_path`` /
+``kernel_path``) in :attr:`PallasExecutor.layer_paths` and bumps the
+``pallas.layer.<path>`` counter in ``obs.metrics.METRICS``, so no layer
+leaves the kernel silently.
 
 Per-program JIT cache: every distinct ``(program fingerprint, mode)``
 gets one *complete* table of jitted callables (split and fused
@@ -145,6 +150,8 @@ class PallasExecutor(ExecutorBackend):
         super().__init__(program, check_timing=check_timing, tracer=tracer)
         self.mode = mode
         self.fused = fused
+        #: layer name -> where its last execution ran (see layer_path)
+        self.layer_paths: dict[str, str] = {}
         if jit_cache_max is not None:
             with PallasExecutor._jit_cache_lock:
                 PallasExecutor._jit_cache_max = int(jit_cache_max)
@@ -228,22 +235,40 @@ class PallasExecutor(ExecutorBackend):
             cls._jit_cache.clear()
             cls._cache_hits = cls._cache_misses = 0
 
+    def layer_path(self, index: int, spatial: bool) -> str:
+        """Where layer ``index`` runs under this executor's mode: a
+        ``kops.conv_path`` name for a spatial conv input on the fused
+        path, else "xla_depthwise" or a ``kops.kernel_path`` name."""
+        lp = self.program.layers[index]
+        geom = lp.geometry
+        if spatial and self.fused:
+            return kops.conv_path(geom.in_shape[0], geom.in_shape[2],
+                                  geom.kernel, geom.pad, geom.out_hw,
+                                  lp.bits_w_lut, depthwise=lp.depthwise,
+                                  mode=self.mode)
+        return "xla_depthwise" if lp.depthwise \
+            else kops.kernel_path(self.mode)
+
     def run_layer(self, index: int, x_q) -> jnp.ndarray:
         """One fused kernel call for the whole layer (both split
         sides); falls back to the per-partition batched path
         (``ExecutorBackend.run_layer``) when ``fused=False``."""
-        if not self.fused:
-            return super().run_layer(index, x_q)
         lp = self.program.layers[index]
         if index not in self._weights:
             raise ExecutionError(f"layer {index} has no bound weights")
+        x_q = jnp.asarray(x_q, jnp.int8)
+        geom = lp.geometry
+        spatial = geom is not None and x_q.shape == geom.in_shape
+        path = self.layer_path(index, spatial)
+        self.layer_paths[lp.name] = path
+        METRICS.incr(f"pallas.layer.{path}")
+        if not self.fused:
+            return super().run_layer(index, x_q)
         wts = self._weights[index]
         for cp in (lp.lut, lp.dsp):
             if cp is not None:
                 self._check_stream(lp, cp)
-        x_q = jnp.asarray(x_q, jnp.int8)
-        geom = lp.geometry
-        if geom is not None and x_q.shape == geom.in_shape:
+        if spatial:
             # spatial input: im2col happens inside the fused call
             fn = self._fns[("fused-sp", lp.bits_w_lut, lp.depthwise, geom)]
         else:

@@ -26,8 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-
-
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 128
@@ -54,7 +52,7 @@ def _bitserial_kernel(x_ref, planes_ref, scale_ref, out_ref, acc_ref, *,
 
     @pl.when(k == nk - 1)
     def _done():
-        out_ref[...] = acc_ref[...].astype(jnp.float32) * scale_ref[...][None, :]
+        out_ref[...] = acc_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "bn", "bk",
@@ -65,7 +63,8 @@ def bitserial_gemm(x: jax.Array, planes: jax.Array, w_scale: jax.Array,
     """out[M, N] (fp32) = (x int8 @ reconstruct(planes)) * w_scale.
 
     x: [M, K] int8; planes: [bits, K, N] int8 in {0, 1}
-    (``ref.bitplane_decompose`` layout); w_scale: [N] fp32.
+    (``ref.bitplane_decompose`` layout); w_scale: [N] fp32, handed to
+    the kernel as a [1, N] row in (1, bn) blocks.
     M, K, N must divide by the block shape (pad at the ops.py layer).
     """
     m, k = x.shape
@@ -88,11 +87,11 @@ def bitserial_gemm(x: jax.Array, planes: jax.Array, w_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bits, bk, bn), lambda i, j, kk: (0, kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         **kwargs,
-    )(x, planes, w_scale)
+    )(x, planes, w_scale.reshape(1, -1))

@@ -24,13 +24,15 @@ the raw zero-padded NHWC activation block and generates im2col patches
 each tap is a [M, C] x [C, bn] matmul against the matching weight
 rows). No column matrix is ever materialized — not in DDR (the
 ``L{i}.col`` staging copy is gone from compiled programs) and not in
-VMEM. The whole spatial input must fit on chip; the ``ops.py`` wrapper
-falls back to the vectorized jnp path when it does not (see
-``fused_conv_vmem_bytes``).
+VMEM. The whole spatial input must fit on chip; where it does not
+(``fused_conv_vmem_bytes``), the ``ops.py`` wrapper runs the jnp
+oracle under XLA instead and ``ops.conv_path`` reports "xla_vmem".
 
-Both kernels are validated in interpret mode against the pure-jnp
-oracles (``ref.fused_hetero_gemm_ref``); on CPU the wrappers dispatch
-the oracles directly, still as one jitted call per layer.
+Both kernels compile for the TPU (``tests/test_tpu_compile.py``) and
+are validated in interpret mode against the pure-jnp oracles
+(``ref.fused_hetero_gemm_ref``). Under ``mode="auto"`` off the TPU the
+wrappers dispatch the oracles instead, still as one jitted call per
+layer.
 """
 from __future__ import annotations
 
@@ -53,12 +55,27 @@ def _plane_weights(bits: int) -> list[int]:
     return [2 ** b for b in range(bits - 1)] + [-(2 ** (bits - 1))]
 
 
-def _unpack_int4_block(p: jax.Array) -> jax.Array:
-    """[bk, bn//2] int8 packed -> [bk, bn] int8 (sign-extended nibbles)."""
-    lo = jnp.left_shift(p, 4) >> 4          # arithmetic shift sign-extends
-    hi = p >> 4
-    out = jnp.stack([lo, hi], axis=-1)      # [bk, bn//2, 2]
-    return out.reshape(p.shape[0], p.shape[1] * 2)
+def unpack_int4_block(p: jax.Array) -> jax.Array:
+    """[bk, bn//2] packed bytes -> [bk, bn] int8 codes (sign-extended).
+
+    The low nibbles are the block's first ``bn//2`` columns and the high
+    nibbles its last ``bn//2`` (``ref.pack_int4`` with ``block=bn``), so
+    the unpack is one concat of two halves. The shifts run in int32:
+    Mosaic has no int8 vector shifts.
+    """
+    w = p.astype(jnp.int32)
+    lo = (w << 28) >> 28                    # arithmetic shift sign-extends
+    hi = w >> 4
+    return jnp.concatenate([lo, hi], axis=1).astype(jnp.int8)
+
+
+def dsp_blocks(packed: jax.Array, bn: int) -> jax.Array:
+    """[K, N//2] packed bytes -> [N//bn, K, bn//2]: one contiguous slab
+    per DSP column block. A (bk, bn//2) block of the 2-D layout is only
+    legal on the TPU while it spans the whole array (its 64 lanes are
+    not a multiple of 128); a slab's last dim always does."""
+    k, nh = packed.shape
+    return packed.reshape(k, nh // (bn // 2), bn // 2).transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -92,13 +109,12 @@ def _fused_kernel(x_ref, planes_ref, packed_ref, scale_ref, out_ref,
 
     @pl.when(j >= nn_lut)
     def _dsp():
-        w = _unpack_int4_block(packed_ref[...])      # [bk, bn] int8
+        w = unpack_int4_block(packed_ref[...])       # [bk, bn] int8
         acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _done():
-        out_ref[...] = acc_ref[...].astype(jnp.float32) \
-            * scale_ref[...][None, :]
+        out_ref[...] = acc_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n_lut_blocks", "bm",
@@ -112,7 +128,8 @@ def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
 
     x: [M, K] int8; planes: [bits, K, N_lut] int8 {0, 1} plane stack of
     the LUT columns; packed: [K, N_dsp//2] int8 ``ref.pack_int4`` bytes
-    of the DSP columns; w_scale: [N_lut + N_dsp] fp32. N_lut must be
+    (``block=bn``) of the DSP columns; w_scale: [N_lut + N_dsp] fp32,
+    handed to the kernel as a [1, N] row in (1, bn) blocks. N_lut must be
     ``n_lut_blocks * bn``; every extent must divide by its block (pad at
     the ops.py layer). Returns fp32 [M, N_lut + N_dsp] in split column
     order.
@@ -148,16 +165,16 @@ def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
             pl.BlockSpec((bits, bk, bn),
                          lambda i, j, kk: (0, kk, jnp.minimum(j, nl - 1)
                                            if nl else 0)),
-            pl.BlockSpec((bk, bn // 2),
-                         lambda i, j, kk: (kk, jnp.maximum(j - nl, 0))),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((None, bk, bn // 2),
+                         lambda i, j, kk: (jnp.maximum(j - nl, 0), kk, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         **kwargs,
-    )(x, planes, packed, w_scale)
+    )(x, planes, dsp_blocks(packed, bn), w_scale.reshape(1, -1))
 
 
 # ---------------------------------------------------------------------------
@@ -180,28 +197,42 @@ def fused_conv_vmem_bytes(in_hw: int, c_in: int, kernel: int, pad: int,
     return x_bytes + w_bytes + acc_bytes
 
 
+def _stride_phases(x: jax.Array, stride: int, extent: int) -> jax.Array:
+    """[H, W, C] -> [stride**2, extent, extent, C]: phase ``a*stride+b``
+    holds rows ``a::stride`` and columns ``b::stride``, so every tap of
+    a strided window is a unit-stride slice of one phase (Mosaic slices
+    vectors with unit strides only). Rows past the input are zeros and
+    are never read."""
+    e = extent * stride
+    h, w, c = x.shape
+    x = jnp.pad(x[:e, :e], ((0, max(e - h, 0)), (0, max(e - w, 0)), (0, 0)))
+    x = x.reshape(extent, stride, extent, stride, c)
+    return x.transpose(1, 3, 0, 2, 4).reshape(stride * stride, extent,
+                                              extent, c)
+
+
 def _fused_conv_kernel(x_ref, planes_ref, packed_ref, scale_ref, out_ref, *,
                        bits: int, nn_lut: int, kernel: int, stride: int,
-                       out_hw: int, c_in: int, m_pad: int):
+                       out_hw: int, c_in: int):
     """One column-block grid step: generate im2col patches in-kernel
     (tap-by-tap static unroll over the (kh, kw) window) and contract
     them against this block's weight rows — LUT blocks through the
-    bitplane path, DSP blocks through packed int4."""
+    bitplane path, DSP blocks through packed int4. A 1x1 conv arrives
+    as its [M, C] pixel rows and is its own single tap."""
     j = pl.program_id(0)
-    x = x_ref[...]                           # [H+2p, W+2p, C] int8
+    x = x_ref[...]
     m = out_hw * out_hw
-    span = stride * (out_hw - 1) + 1
 
     def taps():
+        if kernel == 1:
+            yield 0, x                       # [M, C]
+            return
         for t, (dh, dw) in enumerate(
                 (a, b) for a in range(kernel) for b in range(kernel)):
-            xt = jax.lax.slice(x, (dh, dw, 0),
-                               (dh + span, dw + span, c_in),
-                               (stride, stride, 1))  # [oh, oh, C]
-            xt = xt.reshape(m, c_in)
-            if m_pad != m:
-                xt = jnp.pad(xt, ((0, m_pad - m), (0, 0)))
-            yield t, xt
+            ph = (dh % stride) * stride + dw % stride
+            r0, c0 = dh // stride, dw // stride
+            xt = x[ph, r0:r0 + out_hw, c0:c0 + out_hw, :]  # [oh, oh, C]
+            yield t, xt.reshape(m, c_in)
 
     @pl.when(j < nn_lut)
     def _lut():
@@ -213,26 +244,25 @@ def _fused_conv_kernel(x_ref, planes_ref, packed_ref, scale_ref, out_ref, *,
                 part = jax.lax.dot(xt, planes_ref[b, rows],
                                    preferred_element_type=jnp.int32)
                 acc = acc + s[b] * part
-        out_ref[...] = acc.astype(jnp.float32) * scale_ref[...][None, :]
+        out_ref[...] = acc.astype(jnp.float32) * scale_ref[...]
 
     @pl.when(j >= nn_lut)
     def _dsp():
         acc = jnp.zeros(out_ref.shape, jnp.int32)
         for t, xt in taps():
-            w = _unpack_int4_block(
-                packed_ref[t * c_in:(t + 1) * c_in, :])
+            w = unpack_int4_block(packed_ref[t * c_in:(t + 1) * c_in, :])
             acc = acc + jax.lax.dot(xt, w,
                                     preferred_element_type=jnp.int32)
-        out_ref[...] = acc.astype(jnp.float32) * scale_ref[...][None, :]
+        out_ref[...] = acc.astype(jnp.float32) * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "bits", "n_lut_blocks", "n_dsp_blocks", "kernel", "stride", "out_hw",
-    "bn", "bm", "interpret"))
+    "bn", "interpret"))
 def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
                     w_scale: jax.Array, bits: int, n_lut_blocks: int,
                     n_dsp_blocks: int, kernel: int, stride: int,
-                    out_hw: int, *, bm: int = 8, bn: int = DEFAULT_BN,
+                    out_hw: int, *, bn: int = DEFAULT_BN,
                     interpret: bool = False) -> jax.Array:
     """Single-launch im2col-free conv GEMM.
 
@@ -240,12 +270,14 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
     activation block (code 0 is real 0.0 under the symmetric
     quantizer); planes: [bits, kernel**2*C, >=bn] LUT plane stack in
     (kh, kw, c) row order (the HWIO flattening); packed:
-    [kernel**2*C, >=bn//2] int4-pair bytes; w_scale:
-    [(n_lut_blocks + n_dsp_blocks) * bn] fp32 in split region order.
-    The grid covers ``n_lut_blocks`` LUT column blocks then
-    ``n_dsp_blocks`` DSP blocks; a region with zero blocks still needs
-    one (dummy, never-consumed) weight block so its BlockSpec stays
-    in-bounds. The m extent is padded to ``bm`` sublanes in-kernel.
+    [kernel**2*C, >=bn//2] int4-pair bytes (``ref.pack_int4``,
+    ``block=bn``); w_scale: [(n_lut_blocks + n_dsp_blocks) * bn] fp32
+    in split region order. The grid covers ``n_lut_blocks`` LUT column
+    blocks then ``n_dsp_blocks`` DSP blocks; a region with zero blocks
+    still needs one (dummy, never-consumed) weight block so its
+    BlockSpec stays in-bounds. Before the launch, a strided window is
+    split into its stride phases and a 1x1 conv is gathered into its
+    [out_hw**2, C] pixel rows, so the kernel slices with unit strides.
     Returns fp32 [out_hw**2, N] in split column order.
     """
     c_in = x_sp.shape[2]
@@ -260,7 +292,11 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
     if w_scale.shape[0] < n:
         raise ValueError(f"scales {w_scale.shape[0]} < grid columns {n}")
     m = out_hw * out_hw
-    m_pad = (m + bm - 1) // bm * bm
+    if kernel == 1:
+        span = stride * (out_hw - 1) + 1
+        x = x_sp[:span:stride, :span:stride].reshape(m, c_in)
+    else:
+        x = _stride_phases(x_sp, stride, (kernel - 1) // stride + out_hw)
 
     kwargs = {}
     if not interpret:
@@ -268,23 +304,23 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
             dimension_semantics=("arbitrary",))
 
     nl = n_lut_blocks
-    out = pl.pallas_call(
+    zeros = (0,) * x.ndim
+    return pl.pallas_call(
         functools.partial(
             _fused_conv_kernel, bits=bits, nn_lut=nl, kernel=kernel,
-            stride=stride, out_hw=out_hw, c_in=c_in, m_pad=m_pad),
+            stride=stride, out_hw=out_hw, c_in=c_in),
         grid=(nn,),
         in_specs=[
-            pl.BlockSpec(x_sp.shape, lambda j: (0, 0, 0)),
+            pl.BlockSpec(x.shape, lambda j: zeros),
             pl.BlockSpec((max(bits, 1), k, bn),
                          lambda j: (0, 0, jnp.minimum(j, nl - 1)
                                     if nl else 0)),
-            pl.BlockSpec((k, bn // 2),
-                         lambda j: (0, jnp.maximum(j - nl, 0))),
-            pl.BlockSpec((bn,), lambda j: (j,)),
+            pl.BlockSpec((None, k, bn // 2),
+                         lambda j: (jnp.maximum(j - nl, 0), 0, 0)),
+            pl.BlockSpec((1, bn), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((m_pad, bn), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, n), jnp.float32),
+        out_specs=pl.BlockSpec((m, bn), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(x_sp, planes, packed, w_scale)
-    return out[:m]
+    )(x, planes, dsp_blocks(packed, bn), w_scale[:n].reshape(1, n))

@@ -9,7 +9,8 @@ efficiency trade) and unpacked to int8 in VMEM right before the MXU.
 
 Tiling mirrors ``bitserial_gemm``: grid (nm, nn, nk) with K innermost
 and an int32 VMEM accumulator; the weight block is [bk, bn//2] packed
-bytes, unpacked in-register to [bk, bn]. Per-column fp32 scales are
+bytes, unpacked in-register to [bk, bn] (``unpack_int4_block``, shared
+with the fused kernels). Per-column fp32 scales are
 applied in the epilogue on the last K step.
 """
 from __future__ import annotations
@@ -21,18 +22,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fused_hetero_gemm import dsp_blocks, unpack_int4_block
+
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 128
-
-
-def _unpack_int4_block(p: jax.Array) -> jax.Array:
-    """[bk, bn//2] int8 packed -> [bk, bn] int8 (sign-extended nibbles)."""
-    lo = jnp.left_shift(p, 4) >> 4          # arithmetic shift sign-extends
-    hi = p >> 4
-    out = jnp.stack([lo, hi], axis=-1)      # [bk, bn//2, 2]
-    return out.reshape(p.shape[0], p.shape[1] * 2)
 
 
 def _int4_kernel(x_ref, w_ref, scale_ref, out_ref, acc_ref, *, nk: int):
@@ -42,13 +37,13 @@ def _int4_kernel(x_ref, w_ref, scale_ref, out_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_int4_block(w_ref[...])               # [bk, bn] int8
+    w = unpack_int4_block(w_ref[...])                # [bk, bn] int8
     acc_ref[...] += jax.lax.dot(x_ref[...], w,
                                 preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _done():
-        out_ref[...] = acc_ref[...].astype(jnp.float32) * scale_ref[...][None, :]
+        out_ref[...] = acc_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -57,8 +52,9 @@ def int4_gemm(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array, *,
               bk: int = DEFAULT_BK, interpret: bool = False) -> jax.Array:
     """out[M, N] (fp32) = (x int8 @ unpack(w_packed)) * w_scale.
 
-    x: [M, K] int8; w_packed: [K, N//2] int8 (``ref.pack_int4`` layout);
-    w_scale: [N] fp32. Shapes must divide by blocks (pad in ops.py).
+    x: [M, K] int8; w_packed: [K, N//2] int8 (``ref.pack_int4`` layout,
+    ``block=bn``); w_scale: [N] fp32, handed to the kernel as a [1, N]
+    row in (1, bn) blocks. Shapes must divide by blocks (pad in ops.py).
     """
     m, k = x.shape
     kw, n_half = w_packed.shape
@@ -80,12 +76,12 @@ def int4_gemm(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array, *,
         grid=(nm, nn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn // 2), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((None, bk, bn // 2), lambda i, j, kk: (j, kk, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         **kwargs,
-    )(x, w_packed, w_scale)
+    )(x, dsp_blocks(w_packed, bn), w_scale.reshape(1, -1))

@@ -2,10 +2,14 @@
 
 Responsibilities the raw kernels don't take:
   * shape padding to block multiples (and un-padding the result);
-  * backend dispatch — on TPU the Pallas kernel runs compiled; on CPU
-    (tests, this container) the call automatically falls back to the
-    pure-jnp oracle, with ``interpret=True`` available to execute the
-    actual kernel body for validation;
+  * backend dispatch by ``mode``: "auto" runs the compiled Pallas
+    kernel on a TPU and the pure-jnp oracle anywhere else; "kernel"
+    runs the kernel (in interpret mode off the TPU, which is how the
+    tests execute kernel bodies on the CPU); "ref" runs the oracle;
+  * two conv cases that never reach a kernel, whatever the mode
+    (:func:`conv_path` names them): depthwise layers are an exact
+    int32 einsum under XLA, and a conv whose whole-spatial working set
+    is over :data:`FUSED_CONV_VMEM_BUDGET` runs the oracle under XLA;
   * GQA head broadcasting for flash attention.
 
 These wrappers are the only entry points the model zoo uses.
@@ -35,6 +39,39 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _use_ref(mode: str) -> bool:
+    return mode == "ref" or (mode == "auto" and not _on_tpu())
+
+
+def kernel_path(mode: str = "auto") -> str:
+    """Where a dense GEMM under ``mode`` runs: "kernel" (Pallas,
+    compiled for the TPU), "interpret" (the kernel body in interpret
+    mode off the TPU) or "ref" (the jnp oracle)."""
+    if _use_ref(mode):
+        return "ref"
+    return "kernel" if _on_tpu() else "interpret"
+
+
+def conv_path(in_hw: int, c_in: int, kernel: int, pad: int, out_hw: int,
+              bits: int, *, depthwise: bool = False, mode: str = "auto",
+              vmem_budget: int | None = None) -> str:
+    """Where a conv layer's GEMM runs: a :func:`kernel_path` name, or
+    "xla_depthwise" (depthwise layers, on every backend) or "xla_vmem"
+    (the in-kernel im2col working set is over the VMEM budget, so the
+    oracle runs under XLA instead of the kernel)."""
+    if depthwise:
+        return "xla_depthwise"
+    path = kernel_path(mode)
+    if path == "ref":
+        return path
+    budget = FUSED_CONV_VMEM_BUDGET if vmem_budget is None else vmem_budget
+    k = kernel * kernel * c_in
+    if fused_conv_vmem_bytes(in_hw, c_in, kernel, pad, out_hw * out_hw, k,
+                             bits) > budget:
+        return "xla_vmem"
+    return path
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     rem = x.shape[axis] % mult
     if rem == 0:
@@ -53,7 +90,7 @@ def bitserial_matmul(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array,
     mode: "auto" (kernel on TPU, oracle elsewhere), "kernel" (interpret
     off-TPU), or "ref".
     """
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if _use_ref(mode):
         return ref.bitserial_gemm_ref(x_q, w_q, w_scale, bits)
     bm, bk, bn = block
     m, k = x_q.shape
@@ -74,17 +111,17 @@ def int4_matmul(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array, *,
 
     x_q: [M, K] int8; w_q: [K, N] int32 codes in [-8, 7]; w_scale: [N].
     """
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if _use_ref(mode):
         n = w_q.shape[1]
         packed = ref.pack_int4(_pad_to(w_q, 1, 2))
         return ref.int4_gemm_ref(x_q, packed, _pad_to(w_scale, 0, 2))[:, :n]
     bm, bk, bn = block
     m, k = x_q.shape
     n = w_q.shape[1]
-    packed = ref.pack_int4(_pad_to(w_q, 1, 2))
+    packed = ref.pack_int4(_pad_to(w_q, 1, bn), block=bn)
     xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(packed, 0, bk), 1, bn // 2)
-    sp = _pad_to(_pad_to(w_scale, 0, 2), 0, bn)
+    wp = _pad_to(packed, 0, bk)
+    sp = _pad_to(w_scale, 0, bn)
     out = _int4_kernel(xp, wp, sp, bm=bm, bn=bn, bk=bk,
                        interpret=not _on_tpu())
     return out[:m, :n]
@@ -126,7 +163,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         rep = hq // hkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if _use_ref(mode):
         return ref.flash_attention_ref(q, k, v, causal=causal,
                                        kv_offset=kv_offset)
     bq, bkv = block
@@ -166,7 +203,7 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
     w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
     if w_lut is None and w_dsp is None:
         raise ValueError("fused_matmul: both split sides are empty")
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if _use_ref(mode):
         return ref.fused_hetero_gemm_ref(x_q, w_lut, s_lut, bits,
                                          w_dsp, s_dsp)
     if w_lut is None:
@@ -179,11 +216,10 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
     n_lut, n_dsp = w_lut.shape[1], w_dsp.shape[1]
     planes = ref.bitplane_decompose(w_lut, bits)
     pp = _pad_to(_pad_to(planes, 1, bk), 2, bn)
-    packed = ref.pack_int4(_pad_to(w_dsp, 1, 2))
-    wp = _pad_to(_pad_to(packed, 0, bk), 1, bn // 2)
+    packed = ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn)
+    wp = _pad_to(packed, 0, bk)
     n_lut_pad = pp.shape[2]
-    sp = jnp.concatenate([_pad_to(s_lut, 0, bn),
-                          _pad_to(_pad_to(s_dsp, 0, 2), 0, bn)])
+    sp = jnp.concatenate([_pad_to(s_lut, 0, bn), _pad_to(s_dsp, 0, bn)])
     xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
     out = _fused_kernel(xp, pp, wp, sp, bits, n_lut_pad // bn,
                         bm=bm, bn=bn, bk=bk, interpret=not _on_tpu())
@@ -207,9 +243,9 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
 
     x_sp: [H, W, C] int8 spatial activations (*unpadded*; zero padding
     happens here); weights/scales as :func:`fused_matmul` with K =
-    ``kernel**2 * C`` rows in (kh, kw, c) order. Falls back to the
-    vectorized jnp path (still a single fused jit call) when the
-    spatial working set exceeds ``vmem_budget``.
+    ``kernel**2 * C`` rows in (kh, kw, c) order. Runs the vectorized
+    jnp path (still a single fused jit call) where :func:`conv_path`
+    says "ref" or "xla_vmem".
     """
     w_lut, s_lut = _norm_side(w_lut, s_lut)
     w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
@@ -217,10 +253,9 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
         raise ValueError("fused_conv_matmul: both split sides are empty")
     m = out_hw * out_hw
     k = kernel * kernel * x_sp.shape[2]
-    budget = FUSED_CONV_VMEM_BUDGET if vmem_budget is None else vmem_budget
-    fits = fused_conv_vmem_bytes(x_sp.shape[0], x_sp.shape[2], kernel, pad,
-                                 m, k, bits) <= budget
-    if mode == "ref" or (mode == "auto" and not _on_tpu()) or not fits:
+    path = conv_path(x_sp.shape[0], x_sp.shape[2], kernel, pad, out_hw,
+                     bits, mode=mode, vmem_budget=vmem_budget)
+    if path in ("ref", "xla_vmem"):
         x_col = ref.conv_patches_ref(x_sp, kernel, stride, pad, out_hw)
         return ref.fused_hetero_gemm_ref(x_col.reshape(m, k), w_lut, s_lut,
                                          bits, w_dsp, s_dsp)
@@ -239,9 +274,9 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
         packed = jnp.zeros((k, bn // 2), jnp.int8)
         n_dsp_pad, s_d = 0, None
     else:
-        packed = _pad_to(ref.pack_int4(_pad_to(w_dsp, 1, 2)), 1, bn // 2)
+        packed = ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn)
         n_dsp_pad = packed.shape[1] * 2
-        s_d = _pad_to(_pad_to(s_dsp, 0, 2), 0, bn)
+        s_d = _pad_to(s_dsp, 0, bn)
     sp = jnp.concatenate([s for s in (s_l, s_d) if s is not None])
     out = _fused_conv_kernel(xp, planes, packed, sp, bits,
                              n_lut_pad // bn, n_dsp_pad // bn, kernel,

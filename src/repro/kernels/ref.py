@@ -10,7 +10,8 @@ Also hosts the representation helpers shared by oracle and kernel:
     integer tensor becomes ``bits`` binary planes with per-plane signed
     weights (two's complement: MSB plane weight is -2^(bits-1)).
   * ``pack_int4`` / ``unpack_int4`` — two int4 codes per int8 byte
-    along the last axis (the DSP-core-analogue packed layout).
+    along the last axis, the two nibbles of a byte half a column block
+    apart (the DSP-core-analogue packed layout).
 """
 from __future__ import annotations
 
@@ -45,23 +46,38 @@ def bitplane_reconstruct(planes: jax.Array) -> jax.Array:
     return jnp.sum(planes.astype(jnp.int32) * s, axis=0)
 
 
-def pack_int4(q: jax.Array) -> jax.Array:
-    """Pack signed int4 codes pairwise along the last axis: [..., N] ->
-    [..., N//2] int8 with even index in the low nibble."""
-    if q.shape[-1] % 2 != 0:
-        raise ValueError("last axis must be even to pack int4 pairs")
-    lo = jnp.asarray(q[..., 0::2], jnp.int32) & 0xF
-    hi = jnp.asarray(q[..., 1::2], jnp.int32) & 0xF
-    return ((hi << 4) | lo).astype(jnp.int8)
+def pack_int4(q: jax.Array, block: int | None = None) -> jax.Array:
+    """Pack signed int4 codes two per byte along the last axis: [..., N]
+    -> [..., N//2] int8.
+
+    The last axis is cut into blocks of ``block`` columns (one block
+    when None). Byte ``j`` of a block holds column ``j`` in its low
+    nibble and column ``j + block//2`` in its high nibble, so a kernel
+    unpacks one ``[.., block//2]`` byte block into ``[.., block]`` codes
+    with a single concat of its two nibble halves.
+    """
+    n = q.shape[-1]
+    block = n if block is None else block
+    if block % 2 or n % block:
+        raise ValueError(f"last axis {n} must split into even blocks of "
+                         f"{block} to pack int4 pairs")
+    half = block // 2
+    b = jnp.asarray(q, jnp.int32).reshape(*q.shape[:-1], n // block, 2, half)
+    lo = b[..., 0, :] & 0xF
+    hi = b[..., 1, :] & 0xF
+    return ((hi << 4) | lo).astype(jnp.int8).reshape(*q.shape[:-1], n // 2)
 
 
-def unpack_int4(p: jax.Array) -> jax.Array:
-    """Inverse of ``pack_int4`` (sign-extended)."""
-    b = jnp.asarray(p, jnp.int8)
-    lo = jnp.left_shift(b, 4) >> 4          # arithmetic shift sign-extends
+def unpack_int4(p: jax.Array, block: int | None = None) -> jax.Array:
+    """Inverse of ``pack_int4`` (sign-extended), with the same ``block``."""
+    nb = p.shape[-1]
+    half = nb if block is None else block // 2
+    b = jnp.asarray(p, jnp.int8).astype(jnp.int32)
+    b = b.reshape(*p.shape[:-1], nb // half, half)
+    lo = (b << 28) >> 28                    # arithmetic shift sign-extends
     hi = b >> 4
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(*p.shape[:-1], p.shape[-1] * 2).astype(jnp.int8)
+    out = jnp.concatenate([lo, hi], axis=-1)
+    return out.reshape(*p.shape[:-1], nb * 2).astype(jnp.int8)
 
 
 # ---------------------------------------------------------------------------
@@ -122,14 +138,14 @@ def int4_grouped_gemm_ref(x_col: jax.Array, w_q: jax.Array,
     return acc.astype(jnp.float32) * w_scale[None, :]
 
 
-def int4_gemm_ref(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array
-                  ) -> jax.Array:
+def int4_gemm_ref(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
+                  block: int | None = None) -> jax.Array:
     """Packed-int4 GEMM oracle.
 
-    x: [M, K] int8; w_packed: [K, N//2] int8 (pack_int4 layout);
-    w_scale: [N] fp32. Returns fp32 [M, N].
+    x: [M, K] int8; w_packed: [K, N//2] int8 (``pack_int4`` layout with
+    the same ``block``); w_scale: [N] fp32. Returns fp32 [M, N].
     """
-    w = unpack_int4(w_packed)                              # [K, N] int8
+    w = unpack_int4(w_packed, block)                       # [K, N] int8
     acc = jax.lax.dot(x.astype(jnp.int8), w,
                       preferred_element_type=jnp.int32)
     return acc.astype(jnp.float32) * w_scale[None, :]

@@ -80,6 +80,19 @@ class AdmissionError(FleetError):
     program-cache budget exceeded)."""
 
 
+def _refuse_subprocess_on_tpu(modes) -> None:
+    """A TPU belongs to one process at a time. A worker child started
+    from a process that holds the chip cannot open it, and would only
+    fail to register after the load timeout, so refuse up front."""
+    if "subprocess" not in modes:
+        return
+    import jax
+    if jax.default_backend() == "tpu":
+        raise FleetError(
+            "subprocess workers cannot open the TPU this process holds "
+            "(one process per chip); use thread workers")
+
+
 @dataclasses.dataclass(frozen=True)
 class TenantPolicy:
     """Admission budget for one tenant: concurrent in-flight requests
@@ -166,6 +179,7 @@ class FleetServer:
             raise ValueError(f"unknown scheduling policy {policy!r}")
         self.arch = arch
         self.worker_specs = [tuple(w) for w in workers]
+        _refuse_subprocess_on_tpu([mode for _, _, mode in self.worker_specs])
         self.slots = int(batch_slots)
         self.max_seq = int(max_seq)
         self.policy = policy
@@ -720,6 +734,7 @@ class BundleFleet:
                  timeout_s: float = 300.0):
         from repro.compiler import asm
         from repro.compiler.runtime.multi import global_layers
+        _refuse_subprocess_on_tpu([worker_mode])
         self.meta, self.sections = split_bundle_image(image)
         self.bundle = asm.from_bundle_binary(image)
         self.glayers = global_layers(self.bundle)

@@ -114,7 +114,8 @@ def test_fused_kernel_interpret_equals_ref(bits, n_lut, n_dsp):
 
 
 @pytest.mark.parametrize("kernel,stride,pad", [(3, 1, 1), (3, 2, 0),
-                                               (1, 1, 0), (5, 2, 2)])
+                                               (1, 1, 0), (5, 2, 2),
+                                               (3, 2, 1), (1, 2, 0)])
 def test_fused_conv_kernel_in_kernel_im2col_equals_staged(kernel, stride,
                                                           pad):
     """In-kernel patch generation == out-of-kernel staging + dense
@@ -131,7 +132,7 @@ def test_fused_conv_kernel_in_kernel_im2col_equals_staged(kernel, stride,
     want = ref.fused_hetero_gemm_ref(col, w_lut, s_lut, bits, w_dsp, s_dsp)
 
     planes = ops._pad_to(ref.bitplane_decompose(w_lut, bits), 2, bn)
-    packed = ops._pad_to(ref.pack_int4(w_dsp), 1, bn // 2)
+    packed = ref.pack_int4(ops._pad_to(w_dsp, 1, bn), block=bn)
     sp = jnp.concatenate([ops._pad_to(s_lut, 0, bn),
                           ops._pad_to(s_dsp, 0, bn)])
     xp = jnp.pad(x_sp, ((pad, pad), (pad, pad), (0, 0)))
@@ -286,6 +287,39 @@ def test_prestaged_input_still_works_under_fused():
     want = np.asarray(golden.run_layer(0, x_sp))
     assert (want == np.asarray(fused.run_layer(0, x_col))).all()
     assert (want == np.asarray(fused.run_layer(0, x_sp))).all()
+
+
+@pytest.mark.parametrize("kw,want", [
+    (dict(depthwise=True, mode="kernel"), "xla_depthwise"),
+    (dict(depthwise=True, mode="ref"), "xla_depthwise"),
+    (dict(mode="ref"), "ref"),
+    (dict(mode="auto"), "ref"),                 # off the TPU
+    (dict(mode="kernel"), "interpret"),         # off the TPU
+    (dict(mode="kernel", vmem_budget=1), "xla_vmem"),
+])
+def test_conv_path_names_where_a_layer_runs(kw, want):
+    assert ops.conv_path(28, 16, 3, 1, 28, 4, **kw) == want
+
+
+def test_executor_reports_each_layer_path():
+    """Every executed layer records where it ran and bumps its
+    ``pallas.layer.<path>`` counter: no layer leaves the kernel
+    silently."""
+    from repro.obs import METRICS
+    layers = _cnn_layers("mobilenet_v2")
+    prog = lower_network("mb2", layers, LUT, DSP, XC7Z020)
+    ex = _bound(PallasExecutor, prog, mode="kernel")
+    before = {p: METRICS.counter(f"pallas.layer.{p}")
+              for p in ("interpret", "xla_depthwise", "ref")}
+    ex.run(np.zeros(layers[0].geometry.in_shape, np.int8))
+    dw = {lp.name for lp in prog.layers if lp.depthwise}
+    assert dw and ex.layer_paths == {
+        lp.name: "xla_depthwise" if lp.name in dw else "interpret"
+        for lp in prog.layers}
+    delta = {p: METRICS.counter(f"pallas.layer.{p}") - n
+             for p, n in before.items()}
+    assert delta == {"interpret": len(prog.layers) - len(dw),
+                     "xla_depthwise": len(dw), "ref": 0}
 
 
 # ---------------------------------------------------------------------------

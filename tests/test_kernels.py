@@ -78,11 +78,12 @@ def test_bitserial_exact_integer_semantics():
 def test_int4_kernel_vs_oracle(m, k, n):
     x = RNG.integers(-8, 8, (m, k)).astype(np.int8)
     wq = RNG.integers(-8, 8, (k, n)).astype(np.int32)
-    packed = ref.pack_int4(jnp.asarray(wq))
+    packed = ref.pack_int4(jnp.asarray(wq), block=64)
     scale = RNG.uniform(0.01, 0.2, n).astype(np.float32)
     out = int4_gemm(jnp.asarray(x), packed, jnp.asarray(scale),
                     bm=64, bn=64, bk=64, interpret=True)
-    want = ref.int4_gemm_ref(jnp.asarray(x), packed, jnp.asarray(scale))
+    want = ref.int4_gemm_ref(jnp.asarray(x), packed, jnp.asarray(scale),
+                             block=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
 
 

@@ -47,6 +47,7 @@ from repro.serve.engine import greedy_generate_compiled
 from repro.serve.fleet import (
     AdmissionError,
     BundleFleet,
+    FleetError,
     FleetServer,
     RequestFailed,
     TenantPolicy,
@@ -345,6 +346,22 @@ def test_worker_crash_fails_request_server_stays_up():
         assert _wait_until(lambda: server.live_workers() == [])
         with pytest.raises(RequestFailed):
             server.submit([1], 1)           # no live workers left
+
+
+@pytest.mark.parametrize("make", [
+    lambda: FleetServer(ARCH, [("w0", "pallas", "subprocess")],
+                        batch_slots=SLOTS, max_seq=MAX_SEQ, seed=SEED),
+    lambda: BundleFleet(to_bundle_binary(_chain_bundle("filter")),
+                        worker_mode="subprocess"),
+], ids=["FleetServer", "BundleFleet"])
+def test_subprocess_workers_refused_when_parent_holds_tpu(make,
+                                                          monkeypatch):
+    """One process per chip: with a TPU as the default backend, a
+    subprocess worker is refused up front instead of timing out."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(FleetError, match="one process per chip"):
+        make()
 
 
 def test_step_timeout_fails_request_server_stays_up():

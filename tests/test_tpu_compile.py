@@ -1,0 +1,102 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode (every other kernel test) runs a kernel body on the CPU
+but never through Mosaic, so it misses tiling, layout and VMEM
+refusals. These tests hand the kernels' shapes to the TPU compiler for
+a described v5e chip; nothing runs, so they say nothing about results
+or times. The ``ops.py`` wrappers ask the backend they run on and take
+their CPU branch here, so the tests compile the kernel functions
+themselves, at the shapes the wrappers give them.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bitserial_gemm import bitserial_gemm
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused_hetero_gemm import fused_conv_gemm, fused_hetero_gemm
+from repro.kernels.int4_gemm import int4_gemm
+
+BN = 128
+I8, F32 = jnp.int8, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described (not attached) v5e:2x2 host. The
+    persistent compilation cache is off meanwhile: an executable for a
+    described chip cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _dense(bits):
+    """Both split sides, two column blocks each (the packed-int4 side
+    in per-block slabs), K over several blocks."""
+    m, k, n_lut, n_dsp = 256, 1152, 2 * BN, 2 * BN
+    fn = functools.partial(fused_hetero_gemm, bits=bits,
+                           n_lut_blocks=n_lut // BN)
+    return fn, [((m, k), I8), ((bits, k, n_lut), I8),
+                ((k, n_dsp // 2), I8), ((n_lut + n_dsp,), F32)]
+
+
+def _conv(in_hw, c_in, kernel, stride, pad, out_hw, n_lut_blocks,
+          n_dsp_blocks, bits=4):
+    """``fused_conv_gemm`` on the zero-padded spatial block, as the
+    ``ops.fused_conv_matmul`` wrapper hands it over."""
+    k = kernel * kernel * c_in
+    hp = in_hw + 2 * pad
+    fn = functools.partial(
+        fused_conv_gemm, bits=bits, n_lut_blocks=n_lut_blocks,
+        n_dsp_blocks=n_dsp_blocks, kernel=kernel, stride=stride,
+        out_hw=out_hw)
+    return fn, [((hp, hp, c_in), I8), ((bits, k, n_lut_blocks * BN), I8),
+                ((k, n_dsp_blocks * BN // 2), I8),
+                (((n_lut_blocks + n_dsp_blocks) * BN,), F32)]
+
+
+CASES = {
+    "fused_hetero_gemm_bits4": lambda: _dense(4),
+    "fused_hetero_gemm_bits8": lambda: _dense(8),
+    "int4_gemm_n256": lambda: (
+        int4_gemm, [((128, 512), I8), ((512, 128), I8), ((256,), F32)]),
+    "bitserial_gemm_n256": lambda: (
+        functools.partial(bitserial_gemm, bits=4),
+        [((128, 512), I8), ((4, 512, 256), I8), ((256,), F32)]),
+    # resnet18 conv2: 56x56x64, 3x3 stride 1
+    "conv_resnet18_conv2": lambda: _conv(56, 64, 3, 1, 1, 56, 1, 1),
+    # resnet18 conv6: 3x3 stride 2, 56 -> 28 (stride phases)
+    "conv_resnet18_conv6_s2": lambda: _conv(56, 64, 3, 2, 1, 28, 1, 1),
+    # mobilenet_v2 b6_pw: 14x14x192 1x1 (out_hw and C off the tiling)
+    "conv_mobilenet_v2_b6_pw": lambda: _conv(14, 192, 1, 1, 0, 14, 1, 1),
+    # resnet18 fc as a 1x1 conv on a 1x1 map: several DSP slabs
+    "conv_resnet18_fc": lambda: _conv(1, 512, 1, 1, 0, 1, 6, 3),
+    "flash_attention": lambda: (
+        flash_attention, [((1, 4, 256, 128), F32)] * 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
