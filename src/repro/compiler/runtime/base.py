@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.scheduler import simulate
+from repro.obs import spans
+from repro.obs.spans import span
 from repro.quant.uniform import _inv_hi, fit_scale, qrange
 from repro.compiler.program import CORE_NAMES, ConvGeometry, CoreProgram, \
     LayerProgram, Program
@@ -112,16 +114,10 @@ class ExecutorBackend:
     #: registry key; subclasses override ("golden", "pallas", ...)
     name = "base"
 
-    def __init__(self, program: Program, check_timing: bool = True,
-                 tracer=None):
+    def __init__(self, program: Program, check_timing: bool = True):
         self.program = program
         self.check_timing = check_timing
-        # measured (wall-clock) timeline sink; the null tracer keeps
-        # every hook free when observability is off
-        if tracer is None:
-            from repro.obs import NULL_TRACER
-            tracer = NULL_TRACER
-        self.tracer = tracer
+        self._layer_spans = spans.layer_spans(program.layers)
         self._weights: dict[int, LayerWeights] = {}
 
     # -- weight binding ----------------------------------------------------
@@ -193,18 +189,13 @@ class ExecutorBackend:
         outs = []
         if lp.lut is not None:
             self._check_stream(lp, lp.lut)
-            with self.tracer.measure(f"exec.{self.name}.lut", lp.name,
-                                     layer=lp.index, n=lp.n_lut):
-                outs.append(self._run_core(lp, lp.lut, _slice(0, lp.n_lut),
-                                           wts.w_lut, wts.s_lut))
+            outs.append(self._run_core(lp, lp.lut, _slice(0, lp.n_lut),
+                                       wts.w_lut, wts.s_lut))
         if lp.dsp is not None:
             self._check_stream(lp, lp.dsp)
-            with self.tracer.measure(f"exec.{self.name}.dsp", lp.name,
-                                     layer=lp.index,
-                                     n=lp.dims.n - lp.n_lut):
-                outs.append(self._run_core(lp, lp.dsp,
-                                           _slice(lp.n_lut, lp.dims.n),
-                                           wts.w_dsp, wts.s_dsp))
+            outs.append(self._run_core(lp, lp.dsp,
+                                       _slice(lp.n_lut, lp.dims.n),
+                                       wts.w_dsp, wts.s_dsp))
         return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
     def _staged_activations(self, lp: LayerProgram,
@@ -259,7 +250,8 @@ class ExecutorBackend:
         """
         return chain_layers(self.program.layers, self.run_layer, x_q,
                             x_scale=x_scale,
-                            tail_factory=self._elementwise_tail)
+                            tail_factory=self._elementwise_tail,
+                            layer_spans=self._layer_spans)
 
     def _elementwise_tail(self, lp: LayerProgram):
         """Tail callable for one conv layer — overridable: the Pallas
@@ -362,7 +354,7 @@ def requantize_rows(x: jnp.ndarray, bits: int) -> jnp.ndarray:
 
 
 def chain_layers(layers, run_layer, x_q, x_scale: float = 1.0,
-                 tail_factory=None):
+                 tail_factory=None, *, layer_spans):
     """Chain ``layers`` through ``run_layer(index, x_q)`` with the
     inter-layer requantization the hardware applies on write-back.
 
@@ -370,37 +362,48 @@ def chain_layers(layers, run_layer, x_q, x_scale: float = 1.0,
     ``ExecutorBackend.run`` drives it over one program's layers,
     ``MultiDeviceExecutor.run`` over a bundle's global layers — so the
     multi-device hand-off requantizes exactly like the single-device
-    chain. ``layers`` items need ``.index``, ``.dims``, ``.bits_a``,
-    ``.geometry`` and ``.elementwise``; when every layer carries a
-    geometry the chain is spatial (NHWC reshape + the in-program fused
-    elementwise tail + im2col staging, shortcut layers reading
-    ``src_offset`` producers), otherwise the FC rule n_i == k_{i+1}
-    applies (the LM sessions own that glue). ``tail_factory(lp)``
-    overrides how a layer's elementwise tail callable is built (the
-    Pallas backend supplies jitted fused epilogues); the default is
-    the eager :func:`elementwise_tail`.
+    chain. ``layers`` items need ``.index``, ``.name``, ``.dims``,
+    ``.bits_a``, ``.geometry`` and ``.elementwise``; when every layer
+    carries a geometry the chain is spatial (NHWC reshape + the
+    in-program fused elementwise tail + im2col staging, shortcut layers
+    reading ``src_offset`` producers), otherwise the FC rule
+    n_i == k_{i+1} applies (the LM sessions own that glue).
+    ``tail_factory(lp)`` overrides how a layer's elementwise tail
+    callable is built (the Pallas backend supplies jitted fused
+    epilogues); the default is the eager :func:`elementwise_tail`.
+
+    The chain opens the ``n3h.run`` and per-layer ``n3h.layer*`` host
+    spans (``repro.obs.spans``); ``layer_spans`` are the layers'
+    ``n3h.layer`` names, which executors build once, when they are
+    built (``spans.layer_spans``).
     """
     layers = list(layers)
-    if layers and all(getattr(lp, "geometry", None) is not None
-                      for lp in layers):
-        return _chain_spatial(layers, run_layer, x_q, x_scale,
-                              tail_factory)
-    out = None
-    for lp in layers:
-        if out is not None:
-            if out.shape[1] != lp.dims.k or out.shape[0] != lp.dims.m:
-                raise ExecutionError(
-                    f"layer {lp.index} expects [{lp.dims.m},{lp.dims.k}] "
-                    f"activations but layer {lp.index - 1} produced "
-                    f"{tuple(out.shape)}; run_layer() drives "
-                    f"non-chaining programs layer by layer")
-            x_q = requantize(out, lp.bits_a)
-        out = run_layer(lp.index, x_q)
-    return out
+    with span(spans.RUN):
+        if layers and all(getattr(lp, "geometry", None) is not None
+                          for lp in layers):
+            return _chain_spatial(layers, run_layer, x_q, x_scale,
+                                  tail_factory, layer_spans)
+        out = None
+        for lp, layer_span in zip(layers, layer_spans):
+            with span(layer_span):
+                if out is not None:
+                    if out.shape[1] != lp.dims.k or \
+                            out.shape[0] != lp.dims.m:
+                        raise ExecutionError(
+                            f"layer {lp.index} expects "
+                            f"[{lp.dims.m},{lp.dims.k}] activations but "
+                            f"layer {lp.index - 1} produced "
+                            f"{tuple(out.shape)}; run_layer() drives "
+                            f"non-chaining programs layer by layer")
+                    with span(spans.LAYER_GLUE):
+                        x_q = requantize(out, lp.bits_a)
+                with span(spans.LAYER_RUN):
+                    out = run_layer(lp.index, x_q)
+        return out
 
 
 def _chain_spatial(layers, run_layer, x_q, x_scale: float,
-                   tail_factory=None) -> jnp.ndarray:
+                   tail_factory, layer_spans) -> jnp.ndarray:
     """Spatial NHWC chain over conv layers (resnet18/mobilenet_v2).
 
     Layer ``pos`` consumes the stored post-tail codes of layer
@@ -433,48 +436,55 @@ def _chain_spatial(layers, run_layer, x_q, x_scale: float,
             stored[pos][1:] = [codes, scale]
         return codes, scale
 
-    for pos, lp in enumerate(layers):
-        geom = lp.geometry
-        ew = tuple(getattr(lp, "elementwise", ()) or ())
-        if pos == 0:
-            x_sp = jnp.asarray(x_q, jnp.int8)
-            if x_sp.shape != geom.in_shape:
-                raise ExecutionError(
-                    f"conv chain input must be spatial "
-                    f"{geom.in_shape}, got {tuple(x_sp.shape)}")
-            s_in = jnp.float32(x_scale)
-        else:
-            src = pos - geom.src_offset
-            if src < 0:
-                raise ExecutionError(
-                    f"layer {lp.index} reads producer {src}, which "
-                    f"precedes the chain")
-            x_sp, s_in = _stage(src, lp.bits_a)
-            if x_sp.shape != geom.in_shape:
-                raise ExecutionError(
-                    f"layer {lp.index} expects spatial {geom.in_shape} "
-                    f"but producer {src} yields {tuple(x_sp.shape)}")
-        y = spatialize(run_layer(lp.index, x_sp), geom) * s_in
-        residual = None
-        for op in ew:
-            if op.kind != "add":
-                continue
-            r = pos - op.src_offset
-            if r < 0:
-                raise ExecutionError(
-                    f"layer {lp.index} adds producer {r}, which "
-                    f"precedes the chain")
-            r_codes, r_scale = _stage(r, lp.bits_a)
-            if r_codes.shape != y.shape:
-                raise ExecutionError(
-                    f"layer {lp.index} residual add expects "
-                    f"{tuple(y.shape)} but producer {r} yields "
-                    f"{tuple(r_codes.shape)}")
-            residual = r_codes.astype(jnp.float32) * r_scale
-        y, codes, scale = tail_factory(lp)(y, residual)
-        stored.append([y, codes, scale])
+    for pos, (lp, layer_span) in enumerate(zip(layers, layer_spans)):
+        with span(layer_span):
+            geom = lp.geometry
+            with span(spans.LAYER_GLUE):
+                if pos == 0:
+                    x_sp = jnp.asarray(x_q, jnp.int8)
+                    if x_sp.shape != geom.in_shape:
+                        raise ExecutionError(
+                            f"conv chain input must be spatial "
+                            f"{geom.in_shape}, got {tuple(x_sp.shape)}")
+                    s_in = jnp.float32(x_scale)
+                else:
+                    src = pos - geom.src_offset
+                    if src < 0:
+                        raise ExecutionError(
+                            f"layer {lp.index} reads producer {src}, "
+                            f"which precedes the chain")
+                    x_sp, s_in = _stage(src, lp.bits_a)
+                    if x_sp.shape != geom.in_shape:
+                        raise ExecutionError(
+                            f"layer {lp.index} expects spatial "
+                            f"{geom.in_shape} but producer {src} yields "
+                            f"{tuple(x_sp.shape)}")
+            with span(spans.LAYER_RUN):
+                out = run_layer(lp.index, x_sp)
+            with span(spans.LAYER_GLUE):
+                y = spatialize(out, geom) * s_in
+                residual = None
+                for op in tuple(getattr(lp, "elementwise", ()) or ()):
+                    if op.kind != "add":
+                        continue
+                    r = pos - op.src_offset
+                    if r < 0:
+                        raise ExecutionError(
+                            f"layer {lp.index} adds producer {r}, which "
+                            f"precedes the chain")
+                    r_codes, r_scale = _stage(r, lp.bits_a)
+                    if r_codes.shape != y.shape:
+                        raise ExecutionError(
+                            f"layer {lp.index} residual add expects "
+                            f"{tuple(y.shape)} but producer {r} yields "
+                            f"{tuple(r_codes.shape)}")
+                    residual = r_codes.astype(jnp.float32) * r_scale
+            with span(spans.LAYER_TAIL):
+                y, codes, scale = tail_factory(lp)(y, residual)
+            stored.append([y, codes, scale])
     # final layer: absolute fp32 logits in GEMM [rows, c_out] form
-    return stored[-1][0].reshape(-1, layers[-1].geometry.c_out)
+    with span(spans.LAYER_GLUE):
+        return stored[-1][0].reshape(-1, layers[-1].geometry.c_out)
 
 
 def synthetic_weights(index: int, k: int, n_lut: int, n_dsp: int,

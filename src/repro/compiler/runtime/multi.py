@@ -27,6 +27,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from repro.core.scheduler import GemmDims
+from repro.obs import spans
 from repro.compiler.program import ConvGeometry
 from repro.compiler.runtime.base import (
     ExecutorBackend,
@@ -102,20 +103,15 @@ class MultiDeviceExecutor:
     """Functional executor over a compiled multi-device bundle."""
 
     def __init__(self, bundle, backend: str | type[ExecutorBackend]
-                 = "golden", tracer=None, **backend_kwargs):
+                 = "golden", **backend_kwargs):
         from repro.compiler.partition import validate_bundle
         from repro.compiler.runtime import get_backend
         validate_bundle(bundle)
         self.bundle = bundle
-        if tracer is None:
-            from repro.obs import NULL_TRACER
-            tracer = NULL_TRACER
-        self.tracer = tracer
         cls = get_backend(backend) if isinstance(backend, str) else backend
-        # per-device executors share the bundle's measured timeline
-        self.executors = [cls(p, tracer=tracer, **backend_kwargs)
-                          for p in bundle.devices]
+        self.executors = [cls(p, **backend_kwargs) for p in bundle.devices]
         self.layers = global_layers(bundle)
+        self._layer_spans = spans.layer_spans(self.layers)
 
     # -- weight binding ------------------------------------------------------
 
@@ -178,21 +174,19 @@ class MultiDeviceExecutor:
         gl = self.layers[index]
         x_q = jnp.asarray(x_q, jnp.int8)
         outs = []
-        with self.tracer.measure("exec.multi", gl.name, layer=index,
-                                 shards=len(gl.placements)):
-            for d, li, lo, hi in gl.placements:
-                if hi <= lo:
-                    continue
-                x_d = x_q
-                if gl.depthwise and hi - lo != gl.dims.n:
-                    # a filter shard of a depthwise layer only consumes
-                    # its own channels' input slices — split column
-                    # order is the natural channel order for depthwise
-                    # (LUT columns are the first n_lut channels), so
-                    # channel bounds slice both the spatial [h, w, C]
-                    # and staged [m, k, N] forms
-                    x_d = x_q[..., lo:hi]
-                outs.append(self.executors[d].run_layer(li, x_d))
+        for d, li, lo, hi in gl.placements:
+            if hi <= lo:
+                continue
+            x_d = x_q
+            if gl.depthwise and hi - lo != gl.dims.n:
+                # a filter shard of a depthwise layer only consumes its
+                # own channels' input slices — split column order is
+                # the natural channel order for depthwise (LUT columns
+                # are the first n_lut channels), so channel bounds
+                # slice both the spatial [h, w, C] and staged [m, k, N]
+                # forms
+                x_d = x_q[..., lo:hi]
+            outs.append(self.executors[d].run_layer(li, x_d))
         return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
     def run(self, x_q, x_scale: float = 1.0) -> jnp.ndarray:
@@ -202,4 +196,4 @@ class MultiDeviceExecutor:
         the cross-device hand-off (pipeline boundary or filter gather)
         carries exactly what the single-device chain would."""
         return chain_layers(self.layers, self.run_layer, x_q,
-                            x_scale=x_scale)
+                            x_scale=x_scale, layer_spans=self._layer_spans)

@@ -35,6 +35,14 @@ executed layer records where it ran (``kernels.ops.conv_path`` /
 ``pallas.layer.<path>`` counter in ``obs.metrics.METRICS``, so no layer
 leaves the kernel silently.
 
+Every jitted callable is named by its role, so the profiler's ``XLA
+Modules`` line says what ran: ``n3h_conv_<path>`` (spatial fused
+calls), ``n3h_gemm_<path>`` (pre-staged fused calls), ``n3h_tail``
+(elementwise epilogues), ``n3h_lut`` / ``n3h_dsp`` (the per-partition
+path); the chain's eager glue keeps jnp's names. ``run_layer`` opens
+the ``n3h.layer.launch`` host span (``repro.obs.spans``) around the
+enqueue of the layer's call.
+
 Per-program JIT cache: every distinct ``(program fingerprint, mode)``
 gets one *complete* table of jitted callables (split and fused
 entries), built atomically under the cache lock at construction and
@@ -62,7 +70,9 @@ import jax.numpy as jnp
 
 from repro.core import isa
 from repro.kernels import ops as kops
+from repro.obs import spans
 from repro.obs.metrics import METRICS
+from repro.obs.spans import span
 from repro.compiler.program import CoreProgram, LayerProgram
 from repro.compiler.runtime.base import (
     ExecutionError,
@@ -71,50 +81,66 @@ from repro.compiler.runtime.base import (
 )
 
 
+#: the ``n3h.layer.launch`` span of each path a layer can run on
+_LAUNCH_SPANS = {p: spans.encode(spans.LAYER_LAUNCH, path=p)
+                 for p in kops.PATHS}
+
+
+def _named_jit(f, name: str):
+    """``jax.jit`` of ``f`` under ``name``: the executable is then
+    ``jit_<name>`` in the profiler's ``XLA Modules`` line."""
+    f.__name__ = f.__qualname__ = name
+    return jax.jit(f)
+
+
 def _make_lut_fn(bits: int, mode: str):
     def f(x_q, w_codes, w_scales):
         return kops.bitserial_matmul(x_q, w_codes, w_scales, bits,
                                      mode=mode)
-    return jax.jit(f)
+    return _named_jit(f, "n3h_lut")
 
 
 def _make_dsp_fn(mode: str):
     def f(x_q, w_codes, w_scales):
         return kops.int4_matmul(x_q, w_codes, w_scales, mode=mode)
-    return jax.jit(f)
+    return _named_jit(f, "n3h_dsp")
 
 
 def _make_lut_dw_fn(bits: int, mode: str):
     def f(x_col, w_codes, w_scales):
         return kops.bitserial_grouped_matmul(x_col, w_codes, w_scales,
                                              bits, mode=mode)
-    return jax.jit(f)
+    return _named_jit(f, "n3h_lut")
 
 
 def _make_dsp_dw_fn(mode: str):
     def f(x_col, w_codes, w_scales):
         return kops.int4_grouped_matmul(x_col, w_codes, w_scales,
                                         mode=mode)
-    return jax.jit(f)
+    return _named_jit(f, "n3h_dsp")
 
 
 def _make_fused_fn(bits: int, depthwise: bool, mode: str):
     """One launch over the whole split: pre-staged [m, k] (dense) or
-    [m, k, n] (depthwise) activations, both weight partitions in."""
+    [m, k, n] (depthwise) activations, both weight partitions in.
+    Named ``n3h_gemm_<path>`` by where it runs."""
     if depthwise:
         def f(x_col, w_lut, s_lut, w_dsp, s_dsp):
             return kops.fused_grouped_matmul(x_col, w_lut, s_lut, bits,
                                              w_dsp, s_dsp, mode=mode)
+        path = "xla_depthwise"
     else:
         def f(x_q, w_lut, s_lut, w_dsp, s_dsp):
             return kops.fused_matmul(x_q, w_lut, s_lut, bits,
                                      w_dsp, s_dsp, mode=mode)
-    return jax.jit(f)
+        path = kops.kernel_path(mode)
+    return _named_jit(f, f"n3h_gemm_{path}")
 
 
 def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str):
     """One launch from the raw spatial NHWC block: im2col happens
-    inside the call (in-kernel on TPU, in-jit on CPU)."""
+    inside the call (in-kernel on TPU, in-jit on CPU). Named
+    ``n3h_conv_<path>`` by where it runs (``kops.conv_path``)."""
     kk, st, p, oh = geom.kernel, geom.stride, geom.pad, geom.out_hw
     if depthwise:
         def f(x_sp, w_lut, s_lut, w_dsp, s_dsp):
@@ -126,7 +152,9 @@ def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str):
             return kops.fused_conv_matmul(x_sp, kk, st, p, oh,
                                           w_lut, s_lut, bits,
                                           w_dsp, s_dsp, mode=mode)
-    return jax.jit(f)
+    path = kops.conv_path(geom.in_shape[0], geom.in_shape[2], kk, p, oh,
+                          bits, depthwise=depthwise, mode=mode)
+    return _named_jit(f, f"n3h_conv_{path}")
 
 
 class PallasExecutor(ExecutorBackend):
@@ -145,9 +173,9 @@ class PallasExecutor(ExecutorBackend):
     _cache_misses = 0
 
     def __init__(self, program, check_timing: bool = False,
-                 mode: str = "auto", tracer=None, fused: bool = True,
+                 mode: str = "auto", fused: bool = True,
                  jit_cache_max: int | None = None):
-        super().__init__(program, check_timing=check_timing, tracer=tracer)
+        super().__init__(program, check_timing=check_timing)
         self.mode = mode
         self.fused = fused
         #: layer name -> where its last execution ran (see layer_path)
@@ -194,8 +222,8 @@ class PallasExecutor(ExecutorBackend):
                     # (the exact jnp tail the golden chain runs eagerly)
                     key = ("ew", lp.elementwise, lp.geometry.pool)
                     if key not in fns:
-                        fns[key] = jax.jit(elementwise_tail(
-                            lp.elementwise, lp.geometry.pool))
+                        fns[key] = _named_jit(elementwise_tail(
+                            lp.elementwise, lp.geometry.pool), "n3h_tail")
         return fns
 
     @classmethod
@@ -274,9 +302,7 @@ class PallasExecutor(ExecutorBackend):
         else:
             x_q = self._staged_activations(lp, x_q)
             fn = self._fns[("fused", lp.bits_w_lut, lp.depthwise)]
-        with self.tracer.measure(f"exec.{self.name}.fused", lp.name,
-                                 layer=lp.index, n=lp.dims.n,
-                                 n_lut=lp.n_lut):
+        with span(_LAUNCH_SPANS[path]):
             return fn(x_q, wts.w_lut, wts.s_lut, wts.w_dsp, wts.s_dsp)
 
     def _elementwise_tail(self, lp: LayerProgram):

@@ -47,6 +47,8 @@ import numpy as np
 from repro.quant.uniform import _inv_hi, fit_scale, qrange
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.obs import spans
+from repro.obs.spans import span
 from repro.compiler.runtime.base import (
     ExecutionError,
     LayerWeights,
@@ -60,6 +62,12 @@ from repro.compiler.runtime.base import (
 #: updates one device-side buffer instead of allocating per step.
 _donated_append = jax.jit(lambda cache, row, pos: cache.at[pos].set(row),
                           donate_argnums=(0,))
+
+#: (span, warmed) -> the decode step's span name, ``phase`` encoded
+_STEP_SPANS = {(name, warmed): spans.encode(
+    name, phase="steady" if warmed else "warmup")
+    for name in (spans.DECODE_STEP, spans.DECODE_STEP_SLOTS)
+    for warmed in (False, True)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,18 +167,14 @@ class DecodeSession:
     padded_vocab]; caches/state advance in place.
     """
 
-    #: subclass tag used in tracer span names ("ref", "golden", ...)
+    #: subclass tag ("ref", "golden", "pallas", "multi.<backend>")
     session_name = "base"
 
-    def __init__(self, layers, spec, name: str, tracer=None):
+    def __init__(self, layers, spec, name: str):
         if spec is None:
             raise ExecutionError(
                 f"{name}: program carries no StepSpec — compile it in "
                 f"decode mode (lower_network(step=...))")
-        if tracer is None:
-            from repro.obs import NULL_TRACER
-            tracer = NULL_TRACER
-        self.tracer = tracer
         self.layers = list(layers)
         self.spec = spec
         self.program_name = name
@@ -549,10 +553,9 @@ class ReferenceSession(DecodeSession):
 
     session_name = "ref"
 
-    def __init__(self, program, tracer=None):
+    def __init__(self, program):
         self._weights: dict[int, LayerWeights] = {}
-        super().__init__(program.layers, program.step, program.name,
-                         tracer)
+        super().__init__(program.layers, program.step, program.name)
 
     def bind_layer(self, index, w_lut=None, s_lut=None,
                    w_dsp=None, s_dsp=None) -> None:
@@ -576,9 +579,9 @@ class ReferenceSession(DecodeSession):
         return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
 
-def decode_step_ref(program, tracer=None) -> ReferenceSession:
+def decode_step_ref(program) -> ReferenceSession:
     """Convenience constructor for the plain-jax decode reference."""
-    return ReferenceSession(program, tracer=tracer)
+    return ReferenceSession(program)
 
 
 class ExecutorSession(DecodeSession):
@@ -594,13 +597,14 @@ class ExecutorSession(DecodeSession):
     weight fetches are elided — on the golden backend the contract
     checks verify the steady program touches no weight segment.
 
-    Each step is measured as an ``exec.<backend>.step`` tracer span
-    tagged ``phase=warmup|steady``, so ``--profile`` separates the two
+    Each step opens an ``n3h.decode.step`` (or ``.step_slots``) host
+    span (``repro.obs.spans``) with the argument
+    ``phase=warmup|steady``, so a profiler trace separates the two
     regimes; ``serve.decode.tokens`` counts steps in ``obs.METRICS``.
     """
 
     def __init__(self, program, backend: str | type = "golden",
-                 tracer=None, **backend_kwargs):
+                 **backend_kwargs):
         from repro.compiler.partition import (MultiDeviceProgram,
                                               steady_bundle)
         from repro.compiler.lower import steady_program
@@ -613,10 +617,9 @@ class ExecutorSession(DecodeSession):
                     f"(partition.decorate_decode_bundle)")
             self.steady = steady_bundle(program)
             self._warm_ex = MultiDeviceExecutor(
-                program, backend=backend, tracer=tracer, **backend_kwargs)
+                program, backend=backend, **backend_kwargs)
             self._steady_ex = MultiDeviceExecutor(
-                self.steady, backend=backend, tracer=tracer,
-                **backend_kwargs)
+                self.steady, backend=backend, **backend_kwargs)
             bname = backend if isinstance(backend, str) else backend.name
             self.session_name = f"multi.{bname}"
             layers = self._warm_ex.layers
@@ -626,14 +629,13 @@ class ExecutorSession(DecodeSession):
             self.steady = steady_program(program)
             cls = get_backend(backend) if isinstance(backend, str) \
                 else backend
-            self._warm_ex = cls(program, tracer=tracer, **backend_kwargs)
-            self._steady_ex = cls(self.steady, tracer=tracer,
-                                  **backend_kwargs)
+            self._warm_ex = cls(program, **backend_kwargs)
+            self._steady_ex = cls(self.steady, **backend_kwargs)
             self.session_name = self._warm_ex.name
             layers = program.layers
         self.warm = program
         self._warmed = False
-        super().__init__(layers, spec, program.name, tracer)
+        super().__init__(layers, spec, program.name)
 
     def bind_layer(self, index, w_lut=None, s_lut=None,
                    w_dsp=None, s_dsp=None) -> None:
@@ -646,9 +648,7 @@ class ExecutorSession(DecodeSession):
     def step(self, token, pos: int | None = None) -> jnp.ndarray:
         from repro.obs import METRICS
         pos = self.pos if pos is None else int(pos)
-        phase = "steady" if self._warmed else "warmup"
-        with self.tracer.measure(f"exec.{self.session_name}.step",
-                                 self.program_name, pos=pos, phase=phase):
+        with span(_STEP_SPANS[spans.DECODE_STEP, self._warmed]):
             logits = super().step(token, pos)
         self._warmed = True
         METRICS.incr("serve.decode.tokens")
@@ -656,9 +656,7 @@ class ExecutorSession(DecodeSession):
 
     def step_slots(self, tokens, pos) -> jnp.ndarray:
         from repro.obs import METRICS
-        phase = "steady" if self._warmed else "warmup"
-        with self.tracer.measure(f"exec.{self.session_name}.step_slots",
-                                 self.program_name, phase=phase):
+        with span(_STEP_SPANS[spans.DECODE_STEP_SLOTS, self._warmed]):
             logits = super().step_slots(tokens, pos)
         self._warmed = True
         METRICS.incr("serve.decode.tokens", self.spec.batch)

@@ -43,6 +43,10 @@ def _use_ref(mode: str) -> bool:
     return mode == "ref" or (mode == "auto" and not _on_tpu())
 
 
+#: every name :func:`kernel_path` and :func:`conv_path` return
+PATHS = ("kernel", "interpret", "ref", "xla_vmem", "xla_depthwise")
+
+
 def kernel_path(mode: str = "auto") -> str:
     """Where a dense GEMM under ``mode`` runs: "kernel" (Pallas,
     compiled for the TPU), "interpret" (the kernel body in interpret

@@ -3,8 +3,8 @@
 Three pieces, one contract:
 
 * :class:`Tracer` / :data:`NULL_TRACER` — Chrome trace-event (Perfetto)
-  span collection from the cycle-accurate simulator and wall-clock
-  executor/driver timings; off by default via the null-object fast
+  span collection from the cycle-accurate simulator (simulated FPGA
+  cycles, not chip time); off by default via the null-object fast
   path.
 * :class:`Counters` — derived per-core cycle accounting whose
   decomposition must *close*: busy + sync + stall + idle == the
@@ -12,6 +12,10 @@ Three pieces, one contract:
 * :class:`MetricsRegistry` / :data:`METRICS` — structured
   counters/gauges/observations for serving and DSE with CSV/JSON
   export.
+
+What the executors do on the chip is traced by the JAX profiler:
+``repro.obs.spans`` (which, alone here, imports JAX) names the host
+spans the executor chain and decode sessions open on its clock.
 
 See ``docs/observability.md`` for usage.
 """

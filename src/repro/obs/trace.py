@@ -1,13 +1,11 @@
 """Chrome trace-event tracer for the N3H-Core stack (Perfetto-loadable).
 
-``Tracer`` is the single sink every layer of the stack writes into:
-
-* the event-driven simulator records per-instruction spans in *cycles*
-  (:meth:`record_layer` consumes one ``(SimResult, SimTrace)`` pair per
-  core placement window);
-* executor backends and the serving/DSE drivers record wall-clock
-  spans via :meth:`measure`, so simulated and measured timelines land
-  in one file side by side.
+``Tracer`` is the simulator's cycle tracer: the event-driven
+simulator records per-instruction spans in *cycles*
+(:meth:`record_layer` consumes one ``(SimResult, SimTrace)`` pair per
+core placement window). It times no execution: what the executors do
+on the chip is traced by the JAX profiler, through the host spans of
+``repro.obs.spans``.
 
 The export format is the Chrome trace-event JSON object form
 (``{"traceEvents": [...], ...}``) using only ``"X"`` complete events
@@ -15,14 +13,12 @@ and ``"M"`` metadata events — the subset every trace viewer
 (Perfetto, ``chrome://tracing``) accepts. Track mapping:
 
 * ``pid`` = accelerator device index (one process group per FPGA);
-  wall-clock measurements live in the reserved ``pid`` 901 and
-  inter-device links in 900;
+  inter-device links live in the reserved ``pid`` 900;
 * ``tid`` = ``core_index * 3 + engine_index`` so each device shows six
   rows: lut/fetch, lut/execute, lut/result, dsp/fetch, … — one track
   per engine per core per device;
-* ``ts``/``dur`` are raw simulator cycles for simulated tracks
-  (open Perfetto with "µs" read as "cycles") and microseconds for
-  measured tracks.
+* ``ts``/``dur`` are raw simulator cycles (open Perfetto with "µs"
+  read as "cycles").
 
 Determinism: span records are kept in issue order, the JSON is dumped
 with ``sort_keys=True`` and no timestamps or ids beyond the cycle
@@ -35,21 +31,19 @@ an attribute check.
 """
 from __future__ import annotations
 
-import contextlib
 import json
-import time
 
 from .counters import CORES, ENGINES, Counters
 
-#: reserved track groups (outside any plausible device count)
-LINK_PID = 900       # inter-device channel transfers (pipeline edges)
-MEASURED_PID = 901   # wall-clock executor / driver spans
+#: reserved track group (outside any plausible device count) of the
+#: inter-device channel transfers (pipeline edges)
+LINK_PID = 900
 
 _SPAN_CAT = {"busy": "busy", "sync": "sync", "stall": "stall"}
 
 
 class Tracer:
-    """Collects simulator cycle spans + wall-clock spans, aggregates
+    """Collects simulator cycle spans, aggregates
     :class:`~repro.obs.counters.Counters`, exports Chrome trace JSON."""
 
     enabled = True
@@ -68,9 +62,7 @@ class Tracer:
         # — span lists are lazy replay handles consumed by to_chrome()
         self._layer_records: list[tuple] = []
         self._link_records: list[dict] = []
-        self._measured: list[dict] = []
         self._device_names: dict[int, str] = {}
-        self._t0 = time.perf_counter()
 
     @property
     def counters(self) -> Counters:
@@ -157,26 +149,6 @@ class Tracer:
             else:   # "pad"
                 self._counters.pad_idle(*op[1:])
 
-    # -- wall-clock side (executors, serving, DSE) --------------------------
-
-    @contextlib.contextmanager
-    def measure(self, track: str, name: str, **args):
-        """Wall-clock span on the measured timeline (µs resolution)."""
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            end = time.perf_counter()
-            self._measured.append({
-                "track": track, "name": name,
-                "ts_us": (start - self._t0) * 1e6,
-                "dur_us": (end - start) * 1e6,
-                "args": dict(args)})
-
-    @property
-    def measured_spans(self) -> list[dict]:
-        return list(self._measured)
-
     # -- export -------------------------------------------------------------
 
     def to_chrome(self) -> dict:
@@ -232,23 +204,6 @@ class Tracer:
                              "dst_device": rec["dst"],
                              "nbytes": rec["nbytes"]}})
 
-        if self._measured:
-            meta(MEASURED_PID, "measured")
-            tracks = sorted({m["track"] for m in self._measured})
-            tid_of = {t: i for i, t in enumerate(tracks)}
-            for t in tracks:
-                events.append({"ph": "M", "pid": MEASURED_PID,
-                               "tid": tid_of[t], "name": "thread_name",
-                               "args": {"name": t}})
-            for m in self._measured:
-                events.append({
-                    "ph": "X", "pid": MEASURED_PID,
-                    "tid": tid_of[m["track"]], "cat": "measured",
-                    "name": m["name"],
-                    "ts": round(m["ts_us"], 3),
-                    "dur": round(m["dur_us"], 3),
-                    "args": dict(m["args"])})
-
         return {"traceEvents": events,
                 "displayTimeUnit": "ns",
                 "otherData": {"generator": "repro.obs",
@@ -298,12 +253,6 @@ class NullTracer:
 
     def finalize(self):
         pass
-
-    @contextlib.contextmanager
-    def measure(self, track, name, **args):
-        yield
-
-    measured_spans = ()
 
 
 #: shared singleton — ``tracer=NULL_TRACER`` default keeps hooks alive

@@ -140,7 +140,7 @@ def make_compiled_session(arch_id: str, *, backend: str = "golden",
                           batch: int = 1, max_seq: int = 64,
                           bits_w: int = 4, bits_a: int = 4,
                           opt_level: int = 1, device: str = "XC7Z020",
-                          seed: int | None = None, tracer=None):
+                          seed: int | None = None):
     """Build a decode-resident :class:`~repro.compiler.runtime.session.
     ExecutorSession` for a registry arch: compile the decode step
     program (weights resident, KV/state persistent), bind synthetic
@@ -158,7 +158,7 @@ def make_compiled_session(arch_id: str, *, backend: str = "golden",
     ds = simulate_program(prog)
     METRICS.gauge("serve.decode.warmup_cycles", ds.warmup_cycles)
     METRICS.gauge("serve.decode.steady_cycles", ds.steady_cycles)
-    session = ExecutorSession(prog, backend=backend, tracer=tracer)
+    session = ExecutorSession(prog, backend=backend)
     session.bind_synthetic_all(seed=seed)
     return session
 

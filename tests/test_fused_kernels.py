@@ -17,9 +17,7 @@ The contract under test:
   * the per-program JIT cache builds fn tables atomically (threaded
     regression for the old lazy-mutation race), its capacity is
     configurable (constructor / env), and hits/misses land in
-    ``obs.metrics.METRICS`` as ``pallas.jit_cache.*``;
-  * fused layer executions appear as ``exec.pallas.fused`` tracer
-    spans.
+    ``obs.metrics.METRICS`` as ``pallas.jit_cache.*``.
 """
 import threading
 
@@ -400,20 +398,3 @@ def test_jit_cache_metrics_published():
     assert after.get("pallas.jit_cache.hit", 0) \
         - before.get("pallas.jit_cache.hit", 0) == 1
     assert METRICS.snapshot()["gauges"]["pallas.jit_cache.programs"] >= 1
-
-
-def test_fused_layer_emits_tracer_span():
-    from repro.obs import Tracer
-    tr = Tracer()
-    gl = GemmLayer.from_conv(ConvSpec("c", 5, 16, 3, 1, 8))
-    prog = lower_network("one", [gl], LUT, DSP, XC7Z020, n_luts=[8])
-    ex = PallasExecutor(prog, tracer=tr)
-    bind_synthetic(ex, prog.layers[0], seed=0)
-    x = np.random.default_rng(0).integers(
-        -8, 8, gl.geometry.in_shape).astype(np.int8)
-    ex.run_layer(0, x)
-    spans = tr.measured_spans
-    fused = [s for s in spans if s["track"] == "exec.pallas.fused"]
-    assert fused and fused[0]["name"] == "c"
-    # no per-core lut/dsp spans on the fused path — one span per layer
-    assert not any(s["track"].endswith((".lut", ".dsp")) for s in spans)

@@ -9,10 +9,9 @@ and on 2-device pipeline/filter bundles, at -O0 and -O1.
 """
 import json
 
-import numpy as np
 import pytest
 
-from repro.compiler import GoldenExecutor, bind_synthetic, compile_network
+from repro.compiler import compile_network
 from repro.core.scheduler import simulate_program
 from repro.obs import (
     METRICS,
@@ -172,19 +171,16 @@ def test_validate_rejects_malformed():
 
 
 # ---------------------------------------------------------------------------
-# null tracer / profile report / executor timing
+# null tracer / profile report
 # ---------------------------------------------------------------------------
 
 
 def test_null_tracer_is_noop(single_prog):
     assert NULL_TRACER.enabled is False
-    # every hook swallows; measure yields
+    # every hook swallows
     NULL_TRACER.record_layer(0, 0, "x", 0, 1, {})
     NULL_TRACER.set_makespan(5)
     NULL_TRACER.finalize()
-    with NULL_TRACER.measure("t", "n"):
-        pass
-    assert list(NULL_TRACER.measured_spans) == []
     # simulate_program treats it as tracing-off (same result object)
     ps = simulate_program(single_prog, tracer=NULL_TRACER)
     assert ps.total_cycles == simulate_program(single_prog).total_cycles
@@ -198,20 +194,6 @@ def test_profile_report_renders(single_prog):
     assert "dev0 lut/execute" in text
     assert "top stall causes" in text
     assert profile_report(NULL_TRACER).startswith("profile: no trace data")
-
-
-def test_executor_measured_spans(single_prog):
-    tracer = Tracer()
-    ex = GoldenExecutor(single_prog, tracer=tracer)
-    lp = single_prog.layers[0]
-    bind_synthetic(ex, lp)
-    x = np.zeros((lp.dims.m, lp.dims.k), np.int8)
-    ex.run_layer(lp.index, x)
-    tracks = {s["track"] for s in tracer.measured_spans}
-    assert "exec.golden.lut" in tracks and "exec.golden.dsp" in tracks
-    obj = tracer.to_chrome()
-    assert validate_chrome_trace(obj) == []
-    assert any(e["pid"] == 901 for e in obj["traceEvents"])
 
 
 # ---------------------------------------------------------------------------
