@@ -12,9 +12,11 @@ on the same chip:
   2. cnn     resnet18 and mobilenet_v2 at 224 and width 1.0, compiled
              by ``compile_network`` and run through
              ``PallasExecutor(mode="auto")`` on one synthetic image.
-             The logits must equal ``mode="ref"`` bit for bit. Prints
-             where each layer ran and one warmed-up image time, which
-             is a smoke timing and not a metric.
+             The logits must equal ``mode="ref"`` bit for bit, and the
+             one-executable chain's must equal the eager chain's
+             (``check_timing=True``). Prints where each layer ran and
+             one warmed-up image time, which is a smoke timing and not
+             a metric.
   3. decode  a registry LM with attention in its step, at its smoke
              config (the only size the decode path supports), through
              ``ExecutorSession(backend="pallas")``: tokens and logits
@@ -61,7 +63,8 @@ def _bitwise_equal(a, b) -> bool:
 def _layer_counters() -> dict[str, int]:
     from repro.obs import METRICS
     snap = METRICS.snapshot()["counters"]
-    return {k: v for k, v in snap.items() if k.startswith("pallas.layer.")}
+    return {k: v for k, v in snap.items()
+            if k.startswith(("pallas.layer.", "pallas.run."))}
 
 
 def _counter_delta(before: dict, after: dict) -> dict[str, int]:
@@ -85,8 +88,9 @@ def device_phase() -> dict:
 
 def cnn_phase(name: str, seed: int, *, in_hw: int | None = None,
               width: float | None = None, mode: str = "auto") -> dict:
-    """One synthetic image through ``PallasExecutor(mode=mode)`` and
-    through ``mode="ref"``; the logits must agree bit for bit."""
+    """One synthetic image through ``PallasExecutor(mode=mode)``, its
+    eager chain and ``mode="ref"``; the logits must agree bit for
+    bit."""
     import jax
     from repro.compiler import PallasExecutor, bind_synthetic, compile_network
     from repro.quant.uniform import qrange
@@ -96,13 +100,18 @@ def cnn_phase(name: str, seed: int, *, in_hw: int | None = None,
     x_q = np.random.default_rng(seed).integers(
         lo, hi + 1, lp0.geometry.in_shape).astype(np.int8)
     out = {}
-    for m in (mode, "ref"):
-        ex = PallasExecutor(prog, mode=m)
+    for m, eager in ((mode, False), ("ref", False), (mode, True)):
+        ex = PallasExecutor(prog, mode=m, check_timing=eager)
         for lp in prog.layers:
             bind_synthetic(ex, lp, seed=seed + lp.index)
-        out[m] = (ex, np.asarray(jax.block_until_ready(ex.run(x_q))))
-    ex, got = out[mode]
-    want = out["ref"][1]
+        out[m, eager] = (ex, np.asarray(jax.block_until_ready(ex.run(x_q))))
+    ex, got = out[mode, False]
+    want = out["ref", False][1]
+    if not _bitwise_equal(got, out[mode, True][1]):
+        bad = int((got != out[mode, True][1]).sum())
+        raise SmokeError(f"{name}: the chain executable's logits differ "
+                         f"from the eager chain's in {bad} of {got.size} "
+                         f"entries")
 
     by_path = collections.defaultdict(list)
     for lp in prog.layers:
@@ -136,7 +145,7 @@ def cnn_phase(name: str, seed: int, *, in_hw: int | None = None,
         raise SmokeError(f"{name}: layers left the kernel path for "
                          f"{oracle}")
     print(f"{name}: logits {list(got.shape)} bitwise equal to "
-          f"mode='ref'", flush=True)
+          f"mode='ref' and to the eager chain", flush=True)
     return dict(by_path)
 
 

@@ -75,6 +75,21 @@ def spatialize(out: jnp.ndarray, geom: ConvGeometry) -> jnp.ndarray:
     return jnp.asarray(out).reshape(geom.out_hw, geom.out_hw, geom.c_out)
 
 
+def settle(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` unchanged, rounded to fp32 before any consumer sees it.
+
+    Compilers contract a multiply into the add that consumes it (one
+    fused multiply-add, one rounding), as XLA's CPU backend does once
+    the chain is traced into one executable: the residual add of two
+    dequantized operands would then round differently from the eager
+    chain, which rounds each product on its own. A select on the
+    product's own NaN test stands between the two, so no backend can
+    fuse them, and is an identity on every value (a NaN stays a NaN).
+    The spatial chain settles both operands of its residual add, and
+    the global average pool its input."""
+    return jnp.where(jnp.isnan(x), jnp.nan, x)
+
+
 def apply_pool(x_sp: jnp.ndarray, pool: str) -> jnp.ndarray:
     """Spatial pooling glue between conv layers: ``"max"`` is the
     ResNet stem's 3x3 stride-2 SAME max pool, ``"gap"`` the global
@@ -87,8 +102,43 @@ def apply_pool(x_sp: jnp.ndarray, pool: str) -> jnp.ndarray:
         return jax.lax.reduce_window(x_sp, -jnp.inf, jax.lax.max,
                                      (3, 3, 1), (2, 2, 1), "SAME")
     if pool == "gap":
-        return jnp.mean(x_sp, axis=(0, 1), keepdims=True)
+        # A fixed pairwise order of elementwise adds, not a reduction:
+        # a compiler keeps the order of the adds it is given, but picks
+        # a reduction's order from its operand's shape and layout, which
+        # differ once the chain is one executable (the TPU reduced the
+        # same [7, 7, 1280] map as [49, 1280] there and rounded apart).
+        v = settle(x_sp.reshape(-1, x_sp.shape[-1]))
+        n = v.shape[0]
+        while v.shape[0] > 1:
+            half = v.shape[0] // 2
+            pair = v[:half] + v[half:2 * half]
+            v = jnp.concatenate([pair, v[2 * half:]]) \
+                if v.shape[0] % 2 else pair
+        return (v * np.float32(1.0 / n)).reshape(1, 1, -1)
     return x_sp
+
+
+def stage_activations(lp: LayerProgram, x_q: jnp.ndarray) -> jnp.ndarray:
+    """Normalize a layer's input to the staged im2col form: [m, k] for
+    dense layers, [m, k, n] per-channel slices for depthwise."""
+    m, k, n = lp.dims.m, lp.dims.k, lp.dims.n
+    geom = lp.geometry
+    if geom is not None and x_q.shape == geom.in_shape:
+        pat = im2col_patches(x_q, geom)
+        return pat if lp.depthwise else pat.reshape(m, k)
+    if lp.depthwise:
+        if x_q.shape != (m, k, n):
+            want = (f"{geom.in_shape} spatial or " if geom else "")
+            raise ExecutionError(
+                f"depthwise layer {lp.index} activations must be "
+                f"{want}[{m},{k},{n}] staged, got {tuple(x_q.shape)}")
+        return x_q
+    if x_q.shape != (m, k):
+        want = (f"{geom.in_shape} spatial or " if geom else "")
+        raise ExecutionError(
+            f"layer {lp.index} activations must be {want}"
+            f"[{m},{k}], got {tuple(x_q.shape)}")
+    return x_q
 
 
 @dataclasses.dataclass
@@ -178,7 +228,7 @@ class ExecutorBackend:
         lp = self.program.layers[index]
         if index not in self._weights:
             raise ExecutionError(f"layer {index} has no bound weights")
-        x_q = self._staged_activations(lp, jnp.asarray(x_q, jnp.int8))
+        x_q = stage_activations(lp, jnp.asarray(x_q, jnp.int8))
         wts = self._weights[index]
 
         def _slice(lo, hi):
@@ -197,29 +247,6 @@ class ExecutorBackend:
                                        _slice(lp.n_lut, lp.dims.n),
                                        wts.w_dsp, wts.s_dsp))
         return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
-
-    def _staged_activations(self, lp: LayerProgram,
-                            x_q: jnp.ndarray) -> jnp.ndarray:
-        """Normalize layer input to the staged im2col form: [m, k] for
-        dense layers, [m, k, n] per-channel slices for depthwise."""
-        m, k, n = lp.dims.m, lp.dims.k, lp.dims.n
-        geom = lp.geometry
-        if geom is not None and x_q.shape == geom.in_shape:
-            pat = im2col_patches(x_q, geom)
-            return pat if lp.depthwise else pat.reshape(m, k)
-        if lp.depthwise:
-            if x_q.shape != (m, k, n):
-                want = (f"{geom.in_shape} spatial or " if geom else "")
-                raise ExecutionError(
-                    f"depthwise layer {lp.index} activations must be "
-                    f"{want}[{m},{k},{n}] staged, got {tuple(x_q.shape)}")
-            return x_q
-        if x_q.shape != (m, k):
-            want = (f"{geom.in_shape} spatial or " if geom else "")
-            raise ExecutionError(
-                f"layer {lp.index} activations must be {want}"
-                f"[{m},{k}], got {tuple(x_q.shape)}")
-        return x_q
 
     def _check_stream(self, lp: LayerProgram, cp: CoreProgram) -> None:
         """Validate the sync-token protocol (when ``check_timing``) by
@@ -248,10 +275,11 @@ class ExecutorBackend:
         chains; ``x_scale`` is the input's dequant scale (conv chains
         return absolute fp32 logits for the final layer).
         """
-        return chain_layers(self.program.layers, self.run_layer, x_q,
-                            x_scale=x_scale,
-                            tail_factory=self._elementwise_tail,
-                            layer_spans=self._layer_spans)
+        with span(spans.RUN):
+            return chain_layers(self.program.layers, self.run_layer, x_q,
+                                x_scale=x_scale,
+                                tail_factory=self._elementwise_tail,
+                                layer_spans=self._layer_spans)
 
     def _elementwise_tail(self, lp: LayerProgram):
         """Tail callable for one conv layer — overridable: the Pallas
@@ -372,34 +400,34 @@ def chain_layers(layers, run_layer, x_q, x_scale: float = 1.0,
     callable is built (the Pallas backend supplies jitted fused
     epilogues); the default is the eager :func:`elementwise_tail`.
 
-    The chain opens the ``n3h.run`` and per-layer ``n3h.layer*`` host
-    spans (``repro.obs.spans``); ``layer_spans`` are the layers'
-    ``n3h.layer`` names, which executors build once, when they are
-    built (``spans.layer_spans``).
+    The chain opens the per-layer ``n3h.layer*`` host spans
+    (``repro.obs.spans``) inside the caller's ``n3h.run``;
+    ``layer_spans`` are the layers' ``n3h.layer`` names, which
+    executors build once, when they are built (``spans.layer_spans``).
+    The chain's code is the same whether it runs eagerly or is traced
+    into one executable (``PallasExecutor``'s ``n3h_chain``).
     """
     layers = list(layers)
-    with span(spans.RUN):
-        if layers and all(getattr(lp, "geometry", None) is not None
-                          for lp in layers):
-            return _chain_spatial(layers, run_layer, x_q, x_scale,
-                                  tail_factory, layer_spans)
-        out = None
-        for lp, layer_span in zip(layers, layer_spans):
-            with span(layer_span):
-                if out is not None:
-                    if out.shape[1] != lp.dims.k or \
-                            out.shape[0] != lp.dims.m:
-                        raise ExecutionError(
-                            f"layer {lp.index} expects "
-                            f"[{lp.dims.m},{lp.dims.k}] activations but "
-                            f"layer {lp.index - 1} produced "
-                            f"{tuple(out.shape)}; run_layer() drives "
-                            f"non-chaining programs layer by layer")
-                    with span(spans.LAYER_GLUE):
-                        x_q = requantize(out, lp.bits_a)
-                with span(spans.LAYER_RUN):
-                    out = run_layer(lp.index, x_q)
-        return out
+    if layers and all(getattr(lp, "geometry", None) is not None
+                      for lp in layers):
+        return _chain_spatial(layers, run_layer, x_q, x_scale,
+                              tail_factory, layer_spans)
+    out = None
+    for lp, layer_span in zip(layers, layer_spans):
+        with span(layer_span):
+            if out is not None:
+                if out.shape[1] != lp.dims.k or out.shape[0] != lp.dims.m:
+                    raise ExecutionError(
+                        f"layer {lp.index} expects "
+                        f"[{lp.dims.m},{lp.dims.k}] activations but "
+                        f"layer {lp.index - 1} produced "
+                        f"{tuple(out.shape)}; run_layer() drives "
+                        f"non-chaining programs layer by layer")
+                with span(spans.LAYER_GLUE):
+                    x_q = requantize(out, lp.bits_a)
+            with span(spans.LAYER_RUN):
+                out = run_layer(lp.index, x_q)
+    return out
 
 
 def _chain_spatial(layers, run_layer, x_q, x_scale: float,
@@ -462,7 +490,7 @@ def _chain_spatial(layers, run_layer, x_q, x_scale: float,
             with span(spans.LAYER_RUN):
                 out = run_layer(lp.index, x_sp)
             with span(spans.LAYER_GLUE):
-                y = spatialize(out, geom) * s_in
+                y = settle(spatialize(out, geom) * s_in)
                 residual = None
                 for op in tuple(getattr(lp, "elementwise", ()) or ()):
                     if op.kind != "add":
@@ -478,7 +506,7 @@ def _chain_spatial(layers, run_layer, x_q, x_scale: float,
                             f"layer {lp.index} residual add expects "
                             f"{tuple(y.shape)} but producer {r} yields "
                             f"{tuple(r_codes.shape)}")
-                    residual = r_codes.astype(jnp.float32) * r_scale
+                    residual = settle(r_codes.astype(jnp.float32) * r_scale)
             with span(spans.LAYER_TAIL):
                 y, codes, scale = tail_factory(lp)(y, residual)
             stored.append([y, codes, scale])

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.core.scheduler import GemmDims
 from repro.obs import spans
+from repro.obs.spans import span
 from repro.compiler.program import ConvGeometry
 from repro.compiler.runtime.base import (
     ExecutorBackend,
@@ -195,5 +196,7 @@ class MultiDeviceExecutor:
         programs, spatial NHWC staging) as ``ExecutorBackend.run`` —
         the cross-device hand-off (pipeline boundary or filter gather)
         carries exactly what the single-device chain would."""
-        return chain_layers(self.layers, self.run_layer, x_q,
-                            x_scale=x_scale, layer_spans=self._layer_spans)
+        with span(spans.RUN):
+            return chain_layers(self.layers, self.run_layer, x_q,
+                                x_scale=x_scale,
+                                layer_spans=self._layer_spans)

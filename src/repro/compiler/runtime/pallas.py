@@ -39,9 +39,20 @@ Every jitted callable is named by its role, so the profiler's ``XLA
 Modules`` line says what ran: ``n3h_conv_<path>`` (spatial fused
 calls), ``n3h_gemm_<path>`` (pre-staged fused calls), ``n3h_tail``
 (elementwise epilogues), ``n3h_lut`` / ``n3h_dsp`` (the per-partition
-path); the chain's eager glue keeps jnp's names. ``run_layer`` opens
-the ``n3h.layer.launch`` host span (``repro.obs.spans``) around the
-enqueue of the layer's call.
+path), ``n3h_chain`` (the whole chain); the eager chain's glue keeps
+jnp's names. ``run_layer`` opens the ``n3h.layer.launch`` host span
+(``repro.obs.spans``) around the enqueue of the layer's call.
+
+One executable per program: the table also holds ``n3h_chain``, the
+shared ``chain_layers`` traced once over the program with the table's
+callables inlined, the bound weights as one pytree argument and the
+input scale as a traced scalar. ``run`` calls it, inside the
+``n3h.run`` and ``n3h.run.launch`` spans, when the executor is
+``fused``, runs without ``check_timing`` and keeps its own
+``run_layer``; otherwise ``run`` drives the eager chain through
+``run_layer``. Either way each run records ``layer_paths``, bumps
+``pallas.layer.<path>`` once per layer, and counts itself as
+``pallas.run.chain`` or ``pallas.run.eager``.
 
 Per-program JIT cache: every distinct ``(program fingerprint, mode)``
 gets one *complete* table of jitted callables (split and fused
@@ -67,6 +78,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import isa
 from repro.kernels import ops as kops
@@ -77,7 +89,9 @@ from repro.compiler.program import CoreProgram, LayerProgram
 from repro.compiler.runtime.base import (
     ExecutionError,
     ExecutorBackend,
+    chain_layers,
     elementwise_tail,
+    stage_activations,
 )
 
 
@@ -157,6 +171,70 @@ def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str):
     return _named_jit(f, f"n3h_conv_{path}")
 
 
+def _layer_path(lp: LayerProgram, spatial: bool, fused: bool,
+                mode: str) -> str:
+    """Where ``lp`` runs: a ``kops.conv_path`` name for a spatial conv
+    input on the fused path, else "xla_depthwise" or a
+    ``kops.kernel_path`` name."""
+    geom = lp.geometry
+    if spatial and fused:
+        return kops.conv_path(geom.in_shape[0], geom.in_shape[2],
+                              geom.kernel, geom.pad, geom.out_hw,
+                              lp.bits_w_lut, depthwise=lp.depthwise,
+                              mode=mode)
+    return "xla_depthwise" if lp.depthwise else kops.kernel_path(mode)
+
+
+def _is_spatial(lp: LayerProgram, x_q) -> bool:
+    return lp.geometry is not None and x_q.shape == lp.geometry.in_shape
+
+
+def _launch(fns: dict, lp: LayerProgram, x_q, wts: tuple):
+    """The layer's one fused call on int8 ``x_q`` (spatial NHWC, or
+    staged here) with its ``(w_lut, s_lut, w_dsp, s_dsp)``."""
+    if _is_spatial(lp, x_q):
+        # spatial input: im2col happens inside the fused call
+        fn = fns[("fused-sp", lp.bits_w_lut, lp.depthwise, lp.geometry)]
+    else:
+        x_q = stage_activations(lp, x_q)
+        fn = fns[("fused", lp.bits_w_lut, lp.depthwise)]
+    return fn(x_q, *wts)
+
+
+def _tail(fns: dict, lp: LayerProgram):
+    """The layer's jitted elementwise epilogue from the table, or the
+    eager shared tail for a layer without one."""
+    if lp.geometry is not None and lp.elementwise:
+        fn = fns.get(("ew", lp.elementwise, lp.geometry.pool))
+        if fn is not None:
+            return fn
+    return elementwise_tail(tuple(lp.elementwise),
+                            lp.geometry.pool if lp.geometry else "")
+
+
+def _make_chain_fn(program, fns: dict, mode: str):
+    """The whole chain (``chain_layers``) as one executable,
+    ``n3h_chain(weights, x_q, x_scale)``: ``weights`` holds each
+    layer's ``(w_lut, s_lut, w_dsp, s_dsp)`` in layer order, so the
+    codes are arguments and never constants of the program, and
+    ``x_scale`` is a traced f32 scalar. The per-layer calls are the
+    table's role-named jitted callables, inlined by the trace."""
+    layers = program.layers
+    layer_spans = spans.layer_spans(layers)
+
+    def n3h_chain(weights, x_q, x_scale):
+        def run_layer(index, x):
+            lp = layers[index]
+            x = jnp.asarray(x, jnp.int8)
+            path = _layer_path(lp, _is_spatial(lp, x), True, mode)
+            with span(_LAUNCH_SPANS[path]):
+                return _launch(fns, lp, x, weights[index])
+        return chain_layers(layers, run_layer, x_q, x_scale,
+                            tail_factory=lambda lp: _tail(fns, lp),
+                            layer_spans=layer_spans)
+    return _named_jit(n3h_chain, "n3h_chain")
+
+
 class PallasExecutor(ExecutorBackend):
     """One fused (jitted, program-cached) kernel call per layer."""
 
@@ -187,6 +265,15 @@ class PallasExecutor(ExecutorBackend):
                         PallasExecutor._jit_cache_max:
                     PallasExecutor._jit_cache.popitem(last=False)
         self._fns = self._program_fns(program, mode)
+        # what one run of the chain executable records on the host: the
+        # path of each layer and the pallas.layer.<path> counts
+        spatial = all(lp.geometry is not None for lp in program.layers)
+        self._chain_paths = {lp.name: self.layer_path(lp.index, spatial)
+                             for lp in program.layers}
+        self._chain_counts = tuple(
+            (f"pallas.layer.{path}", n) for path, n in
+            collections.Counter(self._chain_paths.values()).items())
+        self._chain_weights = None
 
     @classmethod
     def _build_fns(cls, program, mode: str) -> dict:
@@ -224,6 +311,7 @@ class PallasExecutor(ExecutorBackend):
                     if key not in fns:
                         fns[key] = _named_jit(elementwise_tail(
                             lp.elementwise, lp.geometry.pool), "n3h_tail")
+        fns["chain",] = _make_chain_fn(program, fns, mode)
         return fns
 
     @classmethod
@@ -267,15 +355,52 @@ class PallasExecutor(ExecutorBackend):
         """Where layer ``index`` runs under this executor's mode: a
         ``kops.conv_path`` name for a spatial conv input on the fused
         path, else "xla_depthwise" or a ``kops.kernel_path`` name."""
-        lp = self.program.layers[index]
-        geom = lp.geometry
-        if spatial and self.fused:
-            return kops.conv_path(geom.in_shape[0], geom.in_shape[2],
-                                  geom.kernel, geom.pad, geom.out_hw,
-                                  lp.bits_w_lut, depthwise=lp.depthwise,
-                                  mode=self.mode)
-        return "xla_depthwise" if lp.depthwise \
-            else kops.kernel_path(self.mode)
+        return _layer_path(self.program.layers[index], spatial,
+                           self.fused, self.mode)
+
+    def bind_layer(self, index: int, *args, **kwargs) -> None:
+        super().bind_layer(index, *args, **kwargs)
+        self._chain_weights = None
+
+    def run(self, x_q, x_scale: float = 1.0) -> jnp.ndarray:
+        """Chain all layers end to end (``ExecutorBackend.run``). With
+        ``fused``, without ``check_timing`` and with this class's own
+        ``run_layer``, the whole chain is one call into the program's
+        ``n3h_chain`` executable; otherwise the chain runs eagerly,
+        one call per step of every layer, through ``self.run_layer``
+        (so a ``run_layer`` replaced on a subclass or an instance is
+        the one that runs)."""
+        if not self.fused or self.check_timing \
+                or "run_layer" in vars(self) \
+                or type(self).run_layer is not PallasExecutor.run_layer:
+            METRICS.incr("pallas.run.eager")
+            return super().run(x_q, x_scale)
+        with span(spans.RUN):
+            weights = self._chain_weights or self._bound_weights()
+            # an input of another shape is traced anew, and the chain
+            # rejects it there, on the host, before anything runs
+            if not isinstance(x_scale, jax.Array):
+                # strong f32, as a scale array is: one trace for both
+                x_scale = np.float32(x_scale)
+            with span(spans.RUN_LAUNCH):
+                out = self._fns["chain",](weights, x_q, x_scale)
+        for name, n in self._chain_counts:
+            METRICS.incr(name, n)
+        METRICS.incr("pallas.run.chain")
+        self.layer_paths.update(self._chain_paths)
+        return out
+
+    def _bound_weights(self) -> tuple:
+        """Every layer's bound weights as the chain's pytree argument,
+        built once per binding."""
+        weights = []
+        for lp in self.program.layers:
+            w = self._weights.get(lp.index)
+            if w is None:
+                raise ExecutionError(f"layer {lp.index} has no bound weights")
+            weights.append((w.w_lut, w.s_lut, w.w_dsp, w.s_dsp))
+        self._chain_weights = tuple(weights)
+        return self._chain_weights
 
     def run_layer(self, index: int, x_q) -> jnp.ndarray:
         """One fused kernel call for the whole layer (both split
@@ -285,35 +410,24 @@ class PallasExecutor(ExecutorBackend):
         if index not in self._weights:
             raise ExecutionError(f"layer {index} has no bound weights")
         x_q = jnp.asarray(x_q, jnp.int8)
-        geom = lp.geometry
-        spatial = geom is not None and x_q.shape == geom.in_shape
-        path = self.layer_path(index, spatial)
+        path = self.layer_path(index, _is_spatial(lp, x_q))
         self.layer_paths[lp.name] = path
         METRICS.incr(f"pallas.layer.{path}")
         if not self.fused:
             return super().run_layer(index, x_q)
-        wts = self._weights[index]
         for cp in (lp.lut, lp.dsp):
             if cp is not None:
                 self._check_stream(lp, cp)
-        if spatial:
-            # spatial input: im2col happens inside the fused call
-            fn = self._fns[("fused-sp", lp.bits_w_lut, lp.depthwise, geom)]
-        else:
-            x_q = self._staged_activations(lp, x_q)
-            fn = self._fns[("fused", lp.bits_w_lut, lp.depthwise)]
+        w = self._weights[index]
         with span(_LAUNCH_SPANS[path]):
-            return fn(x_q, wts.w_lut, wts.s_lut, wts.w_dsp, wts.s_dsp)
+            return _launch(self._fns, lp, x_q,
+                           (w.w_lut, w.s_lut, w.w_dsp, w.s_dsp))
 
     def _elementwise_tail(self, lp: LayerProgram):
         """The layer's fused (jitted, program-cached) elementwise
         epilogue — falls back to the eager shared tail for layers
         without one in the table."""
-        if lp.geometry is not None and lp.elementwise:
-            fn = self._fns.get(("ew", lp.elementwise, lp.geometry.pool))
-            if fn is not None:
-                return fn
-        return super()._elementwise_tail(lp)
+        return _tail(self._fns, lp)
 
     def _run_core(self, lp: LayerProgram, cp: CoreProgram, x_q,
                   w_codes, w_scales) -> jnp.ndarray:

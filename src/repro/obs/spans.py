@@ -13,9 +13,12 @@ executor is built, not on every call.
 
 The spans, outermost first (see ``docs/observability.md``):
 
-* ``n3h.run`` — one input through the whole chain
+* ``n3h.run`` — one input through the whole chain (an executor's
+  ``run``);
+* ``n3h.run.launch`` — ``PallasExecutor.run``'s enqueue of the
+  program's one ``n3h_chain`` executable;
+* ``n3h.layer`` (arg ``layer``) — all work for one layer of the chain
   (``runtime/base.py`` ``chain_layers``);
-* ``n3h.layer`` (arg ``layer``) — all work for one layer of the chain;
 * ``n3h.layer.run`` — the chain's call to the backend's ``run_layer``;
 * ``n3h.layer.launch`` (arg ``path``) — the enqueue of the layer's
   jitted kernel or fallback (``PallasExecutor.run_layer``);
@@ -24,6 +27,12 @@ The spans, outermost first (see ``docs/observability.md``):
 * ``n3h.layer.tail`` — the enqueue of the layer's elementwise tail;
 * ``n3h.decode.step`` / ``n3h.decode.step_slots`` (arg ``phase``) —
   one decode step of an ``ExecutorSession``.
+
+The per-layer ``n3h.layer*`` spans open on the eager chains (golden,
+multi-device, ``PallasExecutor`` with ``fused=False`` or
+``check_timing=True``), and once, inside ``n3h.run.launch``, while a
+program's chain executable is traced; a warm ``PallasExecutor.run``
+opens ``n3h.run`` and ``n3h.run.launch`` alone.
 
 Spans of one name never overlap each other, and all are opened on the
 caller's thread.
@@ -35,6 +44,7 @@ import contextlib
 from jax.profiler import TraceAnnotation
 
 RUN = "n3h.run"
+RUN_LAUNCH = "n3h.run.launch"
 LAYER = "n3h.layer"
 LAYER_RUN = "n3h.layer.run"
 LAYER_LAUNCH = "n3h.layer.launch"

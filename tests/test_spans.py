@@ -3,8 +3,10 @@
 The chain opens ``n3h.*`` spans (``repro.obs.spans``) that land, under
 ``jax.profiler``, on the ``/host:CPU`` plane of the same trace as the
 device's operations; ``PallasExecutor`` names each jitted callable by
-its role (``n3h_conv_<path>``, ``n3h_gemm_<path>``, ``n3h_tail``,
-``n3h_lut``, ``n3h_dsp``), so its executable is ``jit_<name>``. These
+its role (``n3h_chain``, ``n3h_conv_<path>``, ``n3h_gemm_<path>``,
+``n3h_tail``, ``n3h_lut``, ``n3h_dsp``), so its executable is
+``jit_<name>``. A warm ``PallasExecutor.run`` is one launch of
+``n3h_chain``; the per-layer spans open on the eager chains. These
 tests record a trace on the CPU and read the spans back from the
 ``.xplane.pb``, as the chip benchmark's reduction does.
 """
@@ -94,11 +96,23 @@ def _executor(backend, layers):
                                    LUT, DSP, XC7Z020)
         return _bind_all(MultiDeviceExecutor(bundle, backend="pallas"),
                          layers)
+    if backend == "pallas-eager":
+        return _bind_all(PallasExecutor(prog, check_timing=True), layers)
     cls = PallasExecutor if backend == "pallas" else GoldenExecutor
     return _bind_all(cls(prog), layers)
 
 
-@pytest.mark.parametrize("backend", ["pallas", "golden", "multi"])
+def _one_launch(events):
+    """The warm chain executable's spans: ``n3h.run`` around exactly
+    one ``n3h.run.launch``, and no per-layer span."""
+    run, = [ev for ev in events if ev[0] == spans.RUN]
+    launch, = [ev for ev in events if ev[0] == spans.RUN_LAUNCH]
+    assert _inside(launch, run)
+    assert [ev[0] for ev in events] == [spans.RUN, spans.RUN_LAUNCH]
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas-eager", "golden",
+                                     "multi"])
 def test_conv_chain_spans(backend, tmp_path):
     layers = _residual_chain()
     ex = _executor(backend, layers)
@@ -107,6 +121,9 @@ def test_conv_chain_spans(backend, tmp_path):
     ex.run(x)  # compile outside the trace
     events = _traced(lambda: ex.run(x), tmp_path)
     _no_overlap(events)
+    if backend == "pallas":
+        _one_launch(events)
+        return
     run, = [ev for ev in events if ev[0] == spans.RUN]
     assert all(_inside(ev, run) for ev in events)
     per_layer = [ev for ev in events if ev[0] == spans.LAYER]
@@ -122,7 +139,7 @@ def test_conv_chain_spans(backend, tmp_path):
         assert all(_inside(ev, layer_run) for ev in launches)
         if backend == "golden":
             assert not launches
-        elif backend == "pallas":
+        elif backend == "pallas-eager":
             want = ex.layer_paths[lay[3]["layer"]]
             assert [ev[3]["path"] for ev in launches] == [want]
         else:  # one launch per filter shard
@@ -137,7 +154,11 @@ def test_fc_chain_glue_is_the_hand_off_requant(tmp_path):
     ex = _executor("pallas", layers)
     x = np.random.default_rng(1).integers(-8, 8, (8, 16)).astype(np.int8)
     ex.run(x)
-    events = _traced(lambda: ex.run(x), tmp_path)
+    _one_launch(_traced(lambda: ex.run(x), tmp_path / "chain"))
+    # the eager chain: one launch a layer, the hand-off requant as glue
+    ex = _executor("pallas-eager", layers)
+    ex.run(x)
+    events = _traced(lambda: ex.run(x), tmp_path / "eager")
     names = [ev[0] for ev in events]
     assert names.count(spans.RUN) == 1
     assert names.count(spans.LAYER) == names.count(spans.LAYER_RUN) == 2
@@ -167,6 +188,23 @@ def test_jitted_callables_named_by_role(mode):
     assert (kinds["ew"], kinds["lut"], kinds["dsp"], kinds["lut-dw"],
             kinds["dsp-dw"]) == ("n3h_tail", "n3h_lut", "n3h_dsp",
                                  "n3h_lut", "n3h_dsp")
+    assert kinds["chain"] == "n3h_chain"
+
+
+def test_chain_traces_the_layer_spans_once(tmp_path):
+    """The first run traces the chain, so the per-layer spans open
+    once, inside ``n3h.run.launch``; the warm run opens none."""
+    PallasExecutor.cache_clear()
+    layers = _residual_chain()
+    ex = _executor("pallas", layers)
+    x = np.zeros(layers[0].geometry.in_shape, np.int8)
+    events = _traced(lambda: ex.run(x), tmp_path)
+    launch, = [ev for ev in events if ev[0] == spans.RUN_LAUNCH]
+    per_layer = [ev for ev in events if ev[0] == spans.LAYER]
+    assert [ev[3]["layer"] for ev in per_layer] == [gl.name for gl in layers]
+    assert all(_inside(ev, launch) for ev in events
+               if ev[0].startswith(spans.LAYER))
+    assert sum(ev[0] == spans.LAYER_LAUNCH for ev in events) == len(layers)
 
 
 def test_executable_is_jit_of_the_role_name():
