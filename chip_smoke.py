@@ -9,7 +9,7 @@ on the same chip:
 
   1. device  JAX must report a TPU. There is no CPU fallback, and
              ``JAX_PLATFORMS`` is left as the caller set it.
-  2. cnn     resnet18 and mobilenet_v2 at 224 and width 1.0, compiled
+  2. cnn     resnet18, mobilenet_v2 and resnet50 at 224 and width 1.0, compiled
              by ``compile_network`` and run through
              ``PallasExecutor(mode="auto")`` on one synthetic image.
              The logits must equal ``mode="ref"`` bit for bit, and the
@@ -43,7 +43,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
-CNNS = ("resnet18", "mobilenet_v2")
+CNNS = ("resnet18", "mobilenet_v2", "resnet50")
 DECODE_ARCH = "qwen3-8b"
 MAX_SEQ = 16
 N_TOKENS = 4

@@ -51,7 +51,7 @@ def build_parser() -> argparse.ArgumentParser:
         prog="python -m repro.compiler",
         description="Compile a network to unified-ISA instruction streams.")
     p.add_argument("network", nargs="?",
-                   help="resnet18 | mobilenet_v2 | any registered arch id")
+                   help="resnet18 | mobilenet_v2 | resnet50 | any registered arch id")
     p.add_argument("--list", action="store_true",
                    help="list compilable networks and exit")
     p.add_argument("--device", default="XC7Z020", choices=sorted(DEVICES))

@@ -3,7 +3,7 @@
 Two sources:
 
   * the CNN workload zoo (``core/workloads.py``): resnet18 /
-    mobilenet_v2, lowered via im2col exactly as the latency models see
+    mobilenet_v2 / resnet50, lowered via im2col exactly as the latency models see
     them;
   * the LM architecture registry (``configs/registry.py``): every
     registered arch's *smoke* config is walked block by block and each
@@ -158,7 +158,8 @@ def network_layers(name: str, seq_len: int = 64, smoke: bool = True,
                    width: float | None = None) -> list[GemmLayer]:
     """GEMM layer list for a named network.
 
-    ``name`` is a CNN workload (``resnet18``/``mobilenet_v2``) or any
+    ``name`` is a CNN workload (``resnet18``/``mobilenet_v2``/
+    ``resnet50``) or any
     registered arch id; registry archs use their smoke config unless
     ``smoke=False``. CNNs accept ``in_hw``/``width`` to compile the
     geometry-consistent reduced variants of ``models/cnn.py``

@@ -182,10 +182,11 @@ class ConvGeometry:
 
     ``src_offset`` names the layer whose output this layer consumes as
     its input — this layer's index minus ``src_offset`` (1 for the
-    plain sequential chain, 3 for the ResNet downsample shortcuts that
-    read the block input). A source falling before the program start
-    reads the program input segment (``act.in``). ``pool`` is spatial
-    glue applied to *this* layer's output before the consumer reads it:
+    plain sequential chain; the ResNet downsample shortcuts read the
+    block input, 3 back in resnet18 and 4 in resnet50). A source
+    falling before the program start reads the program input segment
+    (``act.in``). ``pool`` is spatial glue applied to *this* layer's
+    output before the consumer reads it:
     ``"max"`` (3x3 stride-2 SAME max pool, the ResNet stem) or
     ``"gap"`` (global average pool before the classifier).
     """
@@ -493,15 +494,15 @@ class GemmLayer:
     @staticmethod
     def from_conv(spec) -> "GemmLayer":
         """Lower a ``core/workloads.py`` ConvSpec to its GEMM view,
-        keeping the spatial geometry (the downsample shortcuts read the
-        block input, three layers back in the zoo's layer order) and the
-        spec's residual/activation glue as elementwise tail ops."""
+        keeping the spatial geometry (the input producer's distance is
+        the spec's ``in_src``) and the spec's residual/activation glue
+        as elementwise tail ops."""
         geom = ConvGeometry(
             kernel=spec.kernel, stride=spec.stride, pad=spec.kernel // 2,
             in_hw=spec.in_hw, out_hw=spec.out_hw,
             c_in=spec.c_out if spec.depthwise else spec.c_in,
             c_out=spec.c_out,
-            src_offset=3 if spec.shortcut else 1,
+            src_offset=spec.in_src,
             pool=getattr(spec, "pool", ""))
         ew = []
         if getattr(spec, "res_src", 0):

@@ -519,21 +519,26 @@ def synthetic_weights(index: int, k: int, n_lut: int, n_dsp: int,
                       bits_w_lut: int, seed: int | None = None):
     """Deterministic synthetic (w_lut, s_lut, w_dsp, s_dsp) for a layer.
 
-    Codes span each partition's full quantized range; scales are a
-    0.5..1.5 ramp so column mixups cannot cancel out. The generation
-    depends only on (index-or-seed, k, n_lut, n_dsp, bits), so a
-    multi-device executor sharding these full-layer weights sees
-    exactly what a single-device executor binds (bit-exactness tests).
+    Codes span each partition's full quantized range, uniformly.
+    Scales are a 0.5..1.5 ramp, so column mixups cannot cancel out,
+    times the He scale of a layer with ``k`` inputs, sqrt(2 / (k *
+    var(code))): activations then keep their size from layer to layer,
+    as in a trained network with folded batch norm, so a deep chain
+    (resnet50's 54 layers) stays finite. The generation depends only
+    on (index-or-seed, k, n_lut, n_dsp, bits), so a multi-device
+    executor sharding these full-layer weights sees exactly what a
+    single-device executor binds (bit-exactness tests).
     """
     rng = np.random.default_rng(index if seed is None else seed)
-    lo_w, hi_w = qrange(bits_w_lut)
-    lo_d, hi_d = qrange(4)
-    return (
-        rng.integers(lo_w, hi_w + 1, (k, n_lut)) if n_lut else None,
-        np.linspace(0.5, 1.5, n_lut, dtype=np.float32) if n_lut else None,
-        rng.integers(lo_d, hi_d + 1, (k, n_dsp)) if n_dsp else None,
-        np.linspace(0.5, 1.5, n_dsp, dtype=np.float32) if n_dsp else None,
-    )
+
+    def side(bits: int, n: int):
+        if not n:
+            return None, None
+        lo, hi = qrange(bits)
+        he = np.sqrt(2.0 / (k * ((hi - lo + 1) ** 2 - 1) / 12.0))
+        return (rng.integers(lo, hi + 1, (k, n)),
+                (np.linspace(0.5, 1.5, n) * he).astype(np.float32))
+    return side(bits_w_lut, n_lut) + side(4, n_dsp)
 
 
 def bind_synthetic(ex: ExecutorBackend, lp: LayerProgram,

@@ -40,8 +40,14 @@ Modules`` line says what ran: ``n3h_conv_<path>`` (spatial fused
 calls), ``n3h_gemm_<path>`` (pre-staged fused calls), ``n3h_tail``
 (elementwise epilogues), ``n3h_lut`` / ``n3h_dsp`` (the per-partition
 path), ``n3h_chain`` (the whole chain); the eager chain's glue keeps
-jnp's names. ``run_layer`` opens the ``n3h.layer.launch`` host span
-(``repro.obs.spans``) around the enqueue of the layer's call.
+jnp's names. Inside them the fused conv kernel is launched under a
+name that gives its window and the indices in the program of the
+layers that launch it, ``fused_conv_gemm_<k>x<k>_L<i>[_<j>...]`` (the
+layers of one geometry share one traced launch): the kernel's
+instruction name in the compiled HLO and the device trace.
+``run_layer`` opens the
+``n3h.layer.launch`` host span (``repro.obs.spans``) around the enqueue
+of the layer's call.
 
 One executable per program: the table also holds ``n3h_chain``, the
 shared ``chain_layers`` traced once over the program with the table's
@@ -151,10 +157,27 @@ def _make_fused_fn(bits: int, depthwise: bool, mode: str):
     return _named_jit(f, f"n3h_gemm_{path}")
 
 
-def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str):
+def _launch_name(lps: list, mode: str) -> str:
+    """The name of the fused conv kernel launch that the layers ``lps``
+    (one bit-width and geometry) share: their window and their indices
+    in the program, ``fused_conv_gemm_<k>x<k>_L<i>[_<j>...]``, where
+    they run the Pallas kernel; the kernel's default name where they
+    do not (the name is then never emitted). One name for the group
+    keeps one trace and lowering of the kernel for it, as before the
+    launches were named."""
+    if _layer_path(lps[0], True, True, mode) not in ("kernel", "interpret"):
+        return "fused_conv_gemm"
+    k = lps[0].geometry.kernel
+    return f"fused_conv_gemm_{k}x{k}_L" + "_".join(str(lp.index)
+                                                   for lp in lps)
+
+
+def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str,
+                      name: str):
     """One launch from the raw spatial NHWC block: im2col happens
-    inside the call (in-kernel on TPU, in-jit on CPU). Named
-    ``n3h_conv_<path>`` by where it runs (``kops.conv_path``)."""
+    inside the call (in-kernel on TPU, in-jit on CPU), the kernel
+    launched as ``name``. Named ``n3h_conv_<path>`` by where it runs
+    (``kops.conv_path``)."""
     kk, st, p, oh = geom.kernel, geom.stride, geom.pad, geom.out_hw
     if depthwise:
         def f(x_sp, w_lut, s_lut, w_dsp, s_dsp):
@@ -165,7 +188,8 @@ def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str):
         def f(x_sp, w_lut, s_lut, w_dsp, s_dsp):
             return kops.fused_conv_matmul(x_sp, kk, st, p, oh,
                                           w_lut, s_lut, bits,
-                                          w_dsp, s_dsp, mode=mode)
+                                          w_dsp, s_dsp, mode=mode,
+                                          name=name)
     path = kops.conv_path(geom.in_shape[0], geom.in_shape[2], kk, p, oh,
                           bits, depthwise=depthwise, mode=mode)
     return _named_jit(f, f"n3h_conv_{path}")
@@ -194,7 +218,7 @@ def _launch(fns: dict, lp: LayerProgram, x_q, wts: tuple):
     staged here) with its ``(w_lut, s_lut, w_dsp, s_dsp)``."""
     if _is_spatial(lp, x_q):
         # spatial input: im2col happens inside the fused call
-        fn = fns[("fused-sp", lp.bits_w_lut, lp.depthwise, lp.geometry)]
+        fn = fns["fused-sp", lp.index]
     else:
         x_q = stage_activations(lp, x_q)
         fn = fns[("fused", lp.bits_w_lut, lp.depthwise)]
@@ -280,8 +304,10 @@ class PallasExecutor(ExecutorBackend):
         """The complete jit table for one program: split entries (the
         per-partition path) and fused entries (the one-launch-per-layer
         path), keyed so layers sharing (core, bits[, geometry]) share a
-        traced executable."""
+        traced executable; a spatial layer's fused entry is under its
+        index."""
         fns: dict = {}
+        spatial = collections.defaultdict(list)
         for lp in program.layers:
             dw = lp.depthwise
             bits = lp.bits_w_lut
@@ -299,10 +325,7 @@ class PallasExecutor(ExecutorBackend):
             if key not in fns:
                 fns[key] = _make_fused_fn(bits, dw, mode)
             if lp.geometry is not None:
-                key = ("fused-sp", bits, dw, lp.geometry)
-                if key not in fns:
-                    fns[key] = _make_fused_sp_fn(bits, lp.geometry, dw,
-                                                 mode)
+                spatial[bits, dw, lp.geometry].append(lp)
                 if lp.elementwise:
                     # fused elementwise epilogue: one jitted call
                     # applying the layer's add/act/pool/requant tail
@@ -311,6 +334,11 @@ class PallasExecutor(ExecutorBackend):
                     if key not in fns:
                         fns[key] = _named_jit(elementwise_tail(
                             lp.elementwise, lp.geometry.pool), "n3h_tail")
+        for (bits, dw, geom), lps in spatial.items():
+            fn = _make_fused_sp_fn(bits, geom, dw, mode,
+                                   _launch_name(lps, mode))
+            for lp in lps:
+                fns["fused-sp", lp.index] = fn
         fns["chain",] = _make_chain_fn(program, fns, mode)
         return fns
 

@@ -13,7 +13,9 @@ with only one active output column — this is what makes the LUT-core
 reproduces it structurally.
 
 Workload zoo: ResNet-18 and MobileNet-V2 at 224x224 (the paper's two
-evaluation networks) plus helpers to derive layer lists for the LM
+evaluation networks), ResNet-50 v1.5 at 224x224 (torchvision's
+``resnet50``, the headline network of FPGA accelerators such as
+Xilinx's DPU) plus helpers to derive layer lists for the LM
 architectures (used by the TPU-side cost model).
 """
 from __future__ import annotations
@@ -49,6 +51,11 @@ class ConvSpec:
     is_first: bool = False
     is_last: bool = False
     shortcut: bool = False      # 1x1 downsample projection (ResNet)
+    # Distance back to the producer of this layer's input: 1 for the
+    # plain chain; a ResNet projection reads the block input, 3 layers
+    # back in resnet18 (conv_a, conv_b before it) and 4 in resnet50
+    # (conv_a, conv_b, conv_c before it).
+    in_src: int = 1
     # Spatial glue applied to this layer's *output* before the next
     # layer reads it: "" (none), "max" (3x3 stride-2 SAME max pool, the
     # ResNet stem) or "gap" (global average pool before the classifier).
@@ -56,7 +63,8 @@ class ConvSpec:
     # Elementwise tail the layer applies to its own output: activation
     # kind ("", "relu", "relu6", "hswish") and, for residual layers,
     # the distance back to the add operand's producer (0 = no residual;
-    # ResNet conv_b adds 2 back, MobileNet pw adds 3 back). These lower
+    # ResNet-18 conv_b adds 2 back, ResNet-50 conv_c and MobileNet pw
+    # add 3 back, a projection adds the conv before it). These lower
     # into the program's fused elementwise stage.
     act: str = ""
     res_src: int = 0
@@ -118,17 +126,17 @@ def resnet18_specs() -> list[ConvSpec]:
     # layer2: 64 -> 128, stride 2; downsample at index 8
     specs += block(6, 64, 128, 2, 56, ds=True)
     specs.append(ConvSpec("conv8_ds", 64, 128, 1, 2, 56, shortcut=True,
-                          act="relu", res_src=1))
+                          in_src=3, act="relu", res_src=1))
     specs += block(9, 128, 128, 1, 28)
     # layer3: 128 -> 256; downsample at index 13
     specs += block(11, 128, 256, 2, 28, ds=True)
     specs.append(ConvSpec("conv13_ds", 128, 256, 1, 2, 28, shortcut=True,
-                          act="relu", res_src=1))
+                          in_src=3, act="relu", res_src=1))
     specs += block(14, 256, 256, 1, 14)
     # layer4: 256 -> 512; downsample at index 18
     specs += block(16, 256, 512, 2, 14, ds=True)
     specs.append(ConvSpec("conv18_ds", 256, 512, 1, 2, 14, shortcut=True,
-                          act="relu", res_src=1))
+                          in_src=3, act="relu", res_src=1))
     specs += block(19, 512, 512, 1, 7)
     # global average pool feeds the classifier, a 1x1 "conv" on a 1x1 map
     specs[-1] = dataclasses.replace(specs[-1], pool="gap")
@@ -180,9 +188,52 @@ def mobilenet_v2_specs() -> list[ConvSpec]:
     return specs
 
 
+def resnet50_specs() -> list[ConvSpec]:
+    """ResNet-50 v1.5 @224 (torchvision ``resnet50``: Bottleneck blocks
+    [3, 4, 6, 3], the stride on the 3x3 conv): 53 convs + classifier.
+
+    Layer order per block, as resnet18's: conv_a (1x1, relu), conv_b
+    (3x3 at the block's stride, relu), conv_c (1x1 expand by 4). An
+    identity block's conv_c adds the block input (3 back) and applies
+    relu. A block that changes shape (the first of each stage, in
+    ``layer1`` at stride 1) ends with its 1x1 projection: conv_c writes
+    its raw output, and the projection reads the block input (4 back),
+    adds conv_c (1 back) and applies relu."""
+    specs: list[ConvSpec] = [
+        ConvSpec("conv1", 3, 64, 7, 2, 224, is_first=True, pool="max",
+                 act="relu"),
+    ]
+    hw, c_in = 56, 64
+    for width, n_blocks, stride0 in ((64, 3, 1), (128, 4, 2), (256, 6, 2),
+                                     (512, 3, 2)):
+        c_out = 4 * width
+        for b in range(n_blocks):
+            stride = stride0 if b == 0 else 1
+            proj = b == 0
+            n = len(specs) + 1
+            specs += [
+                ConvSpec(f"conv{n}", c_in, width, 1, 1, hw, act="relu"),
+                ConvSpec(f"conv{n + 1}", width, width, 3, stride, hw,
+                         act="relu"),
+                ConvSpec(f"conv{n + 2}", width, c_out, 1, 1, hw // stride,
+                         act="" if proj else "relu",
+                         res_src=0 if proj else 3),
+            ]
+            if proj:
+                specs.append(ConvSpec(f"conv{n + 3}_ds", c_in, c_out, 1,
+                                      stride, hw, shortcut=True, in_src=4,
+                                      act="relu", res_src=1))
+            hw //= stride
+            c_in = c_out
+    specs[-1] = dataclasses.replace(specs[-1], pool="gap")
+    specs.append(ConvSpec("fc", c_in, 1000, 1, 1, 1, is_last=True))
+    return specs
+
+
 WORKLOADS = {
     "resnet18": resnet18_specs,
     "mobilenet_v2": mobilenet_v2_specs,
+    "resnet50": resnet50_specs,
 }
 
 

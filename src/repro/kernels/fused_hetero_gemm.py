@@ -256,14 +256,26 @@ def _fused_conv_kernel(x_ref, planes_ref, packed_ref, scale_ref, out_ref, *,
         out_ref[...] = acc.astype(jnp.float32) * scale_ref[...]
 
 
+def _named(f, name: str):
+    """``f`` under a jit named ``name``. XLA names what it emits for the
+    innermost jit after that jit, so a kernel launched here is the
+    instruction ``%<name>`` in the compiled HLO and in the profiler's
+    device ops."""
+    def call(*args):
+        return f(*args)
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "bits", "n_lut_blocks", "n_dsp_blocks", "kernel", "stride", "out_hw",
-    "bn", "interpret"))
+    "bn", "interpret", "name"))
 def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
                     w_scale: jax.Array, bits: int, n_lut_blocks: int,
                     n_dsp_blocks: int, kernel: int, stride: int,
                     out_hw: int, *, bn: int = DEFAULT_BN,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    name: str = "fused_conv_gemm") -> jax.Array:
     """Single-launch im2col-free conv GEMM.
 
     x_sp: [H+2p, W+2p, C] int8 — the *already zero-padded* spatial
@@ -278,6 +290,8 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
     BlockSpec stays in-bounds. Before the launch, a strided window is
     split into its stride phases and a 1x1 conv is gathered into its
     [out_hw**2, C] pixel rows, so the kernel slices with unit strides.
+    ``name`` names the launch (the executor names it by its window
+    and its layers, ``fused_conv_gemm_<k>x<k>_L<index>[_<index>...]``).
     Returns fp32 [out_hw**2, N] in split column order.
     """
     c_in = x_sp.shape[2]
@@ -305,7 +319,7 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
 
     nl = n_lut_blocks
     zeros = (0,) * x.ndim
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(
             _fused_conv_kernel, bits=bits, nn_lut=nl, kernel=kernel,
             stride=stride, out_hw=out_hw, c_in=c_in),
@@ -322,5 +336,8 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
         out_specs=pl.BlockSpec((m, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name=name,
         **kwargs,
-    )(x, planes, dsp_blocks(packed, bn), w_scale[:n].reshape(1, n))
+    )
+    return _named(call, name)(x, planes, dsp_blocks(packed, bn),
+                              w_scale[:n].reshape(1, n))

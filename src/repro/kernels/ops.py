@@ -240,7 +240,8 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
                       w_dsp: jax.Array | None, s_dsp: jax.Array | None, *,
                       block: tuple[int, int, int] = (128, 128, 128),
                       mode: str = "auto",
-                      vmem_budget: int | None = None) -> jax.Array:
+                      vmem_budget: int | None = None,
+                      name: str = "fused_conv_gemm") -> jax.Array:
     """Fused im2col-free conv GEMM: one launch from the raw spatial
     activation block — patches are generated inside the kernel, so no
     column matrix is staged in DDR or materialized on host.
@@ -249,7 +250,8 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
     happens here); weights/scales as :func:`fused_matmul` with K =
     ``kernel**2 * C`` rows in (kh, kw, c) order. Runs the vectorized
     jnp path (still a single fused jit call) where :func:`conv_path`
-    says "ref" or "xla_vmem".
+    says "ref" or "xla_vmem"; elsewhere ``name`` names the kernel's
+    launch (``fused_hetero_gemm.fused_conv_gemm``).
     """
     w_lut, s_lut = _norm_side(w_lut, s_lut)
     w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
@@ -285,7 +287,7 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
     out = _fused_conv_kernel(xp, planes, packed, sp, bits,
                              n_lut_pad // bn, n_dsp_pad // bn, kernel,
                              stride, out_hw, bn=bn,
-                             interpret=not _on_tpu())
+                             interpret=not _on_tpu(), name=name)
     if n_lut_pad == n_lut:
         return out[:, :n_lut + n_dsp]
     return jnp.concatenate(

@@ -1,4 +1,5 @@
-"""ResNet-18 / MobileNet-V2 in JAX — the paper's evaluation workloads.
+"""ResNet-18 / MobileNet-V2 / ResNet-50 in JAX — the paper's evaluation
+workloads and the bottleneck ResNet of FPGA accelerator comparisons.
 
 Every parametric layer maps 1:1 onto a ``ConvSpec`` in
 ``repro.core.workloads`` (same names, same order), so the DSE framework
@@ -27,14 +28,14 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.workloads import ConvSpec, mobilenet_v2_specs, resnet18_specs
+from repro.core.workloads import WORKLOADS, ConvSpec
 from repro.quant.hybrid import LayerQuantConfig, hybrid_fake_quant_weight
 from repro.quant.uniform import fake_quant_per_channel, fit_scale, qrange
 
 
 @dataclasses.dataclass(frozen=True)
 class CNNConfig:
-    arch: str = "resnet18"              # resnet18 | mobilenet_v2
+    arch: str = "resnet18"              # a core.workloads.WORKLOADS key
     n_classes: int = 1000
     in_hw: int = 224
     width: float = 1.0                  # channel multiplier (reduced smoke)
@@ -55,13 +56,17 @@ def specs_for(cfg: CNNConfig) -> list[ConvSpec]:
     """ConvSpec list matching this config (width/input-size scaled).
 
     Spatial sizes are *propagated* through the layer graph — each
-    layer's ``in_hw`` is its producer's (pooled) ``out_hw``, with the
-    downsample shortcuts reading the block input three layers back —
-    so the scaled specs chain exactly like the full-size network and
-    the compiled program's im2col geometry stays executable at any
-    input size.
+    layer's ``in_hw`` is its producer's (pooled) ``out_hw``, the
+    producer ``in_src`` layers back (the downsample shortcuts read the
+    block input) — so the scaled specs chain exactly like the
+    full-size network and the compiled program's im2col geometry stays
+    executable at any input size. An arch outside
+    ``core.workloads.WORKLOADS`` is refused.
     """
-    base = resnet18_specs() if cfg.arch == "resnet18" else mobilenet_v2_specs()
+    if cfg.arch not in WORKLOADS:
+        raise ValueError(f"unknown CNN arch {cfg.arch!r}; known: "
+                         f"{', '.join(sorted(WORKLOADS))}")
+    base = WORKLOADS[cfg.arch]()
     if cfg.width >= 1.0 and cfg.in_hw == 224 and cfg.n_classes == 1000:
         return base
     out: list[ConvSpec] = []
@@ -74,7 +79,7 @@ def specs_for(cfg: CNNConfig) -> list[ConvSpec]:
         if s.is_first:
             in_hw = cfg.in_hw
         else:
-            src = out[i - (3 if s.shortcut else 1)]
+            src = out[i - s.in_src]
             in_hw = src.pooled_out_hw
         out.append(dataclasses.replace(s, c_in=c_in, c_out=c_out,
                                        in_hw=in_hw))
@@ -261,6 +266,46 @@ def mobilenet_v2_forward(params: dict, x: jax.Array, cfg: CNNConfig,
     return x[:, 0, 0, :]
 
 
+# ---------------------------------------------------------------------------
+# ResNet-50 v1.5 forward
+# ---------------------------------------------------------------------------
+
+
+def resnet50_forward(params: dict, x: jax.Array, cfg: CNNConfig,
+                     quant_cfgs: Sequence[LayerQuantConfig] | None = None,
+                     norms: dict | None = None,
+                     capture: dict | None = None) -> jax.Array:
+    """Bottleneck blocks in the specs' layer order: conv_a, conv_b,
+    conv_c, then the projection (``shortcut``) where the block changes
+    shape; relu(conv_c(x) + shortcut(x))."""
+    all_specs = specs_for(cfg)
+    specs = {s.name: s for s in all_specs}
+    qi = {s.name: i for i, s in enumerate(all_specs)}
+
+    def conv(name, x, relu=True):
+        return conv_layer(params[name], x, specs[name],
+                          _qc(quant_cfgs, qi[name]), relu,
+                          norm=None if norms is None else norms[name],
+                          capture=capture)
+
+    x = conv(all_specs[0].name, x)
+    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
+                              (1, 2, 2, 1), "SAME")
+    i = 1
+    while not all_specs[i].is_last:
+        a, b, c = (s.name for s in all_specs[i:i + 3])
+        ds = all_specs[i + 3] if all_specs[i + 3].shortcut else None
+        h = conv(b, conv(a, x))
+        h = conv(c, h, relu=False)
+        sc = x if ds is None else conv(ds.name, x, relu=False)
+        x = jax.nn.relu(h + sc)
+        i += 3 if ds is None else 4
+
+    x = jnp.mean(x, axis=(1, 2), keepdims=True)
+    x = conv(all_specs[i].name, x, relu=False)
+    return x[:, 0, 0, :]
+
+
 def forward(params: dict, x: jax.Array, cfg: CNNConfig,
             quant_cfgs: Sequence[LayerQuantConfig] | None = None,
             norms: dict | None = None,
@@ -270,6 +315,8 @@ def forward(params: dict, x: jax.Array, cfg: CNNConfig,
     if cfg.arch == "mobilenet_v2":
         return mobilenet_v2_forward(params, x, cfg, quant_cfgs, norms,
                                     capture)
+    if cfg.arch == "resnet50":
+        return resnet50_forward(params, x, cfg, quant_cfgs, norms, capture)
     raise ValueError(f"unknown CNN arch {cfg.arch!r}")
 
 

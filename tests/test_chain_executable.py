@@ -82,7 +82,8 @@ def _delta(before: dict, after: dict) -> dict:
 
 @pytest.mark.parametrize("arch,in_hw", [("resnet18", 28), ("resnet18", 32),
                                         ("mobilenet_v2", 28),
-                                        ("mobilenet_v2", 32)])
+                                        ("mobilenet_v2", 32),
+                                        ("resnet50", 32)])
 def test_chain_bit_exact_vs_golden_and_eager(arch, in_hw):
     """In-hw 28 is ``test_conv_exec``'s golden comparison, which runs
     the chain executable too; here the chain also meets the eager

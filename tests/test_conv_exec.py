@@ -5,7 +5,7 @@ The contract under test (ISSUE 4 acceptance surface):
     golden executor's im2col-staged GEMM equals ``cnn.conv2d`` (the
     network's ``lax.conv_general_dilated`` primitive) exactly, in the
     integer code domain, for dense and depthwise layers;
-  * whole-CNN inference: resnet18 and mobilenet_v2 programs (reduced
+  * whole-CNN inference: resnet18, mobilenet_v2 and resnet50 programs (reduced
     geometry-consistent variants) run end to end through the spatial
     chain — shortcut sources, max-pool/GAP glue, inter-layer requant —
     with pallas bit-identical to golden;
@@ -133,9 +133,10 @@ def test_im2col_patch_order_matches_hwio_flattening():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch", ["resnet18", "mobilenet_v2"])
+@pytest.mark.parametrize("arch", ["resnet18", "mobilenet_v2", "resnet50"])
 def test_cnn_end_to_end_pallas_bit_exact_vs_golden(arch):
-    layers = _cnn_layers(arch)
+    # resnet50's 54 golden layers at in-hw 32 (a 1x1 map in layer4)
+    layers = _cnn_layers(arch, in_hw=32 if arch == "resnet50" else 28)
     prog = lower_network(arch, layers, LUT, DSP, XC7Z020)
     x = _image(layers[0])
     out_g = np.asarray(_bound(GoldenExecutor, prog).run(x))

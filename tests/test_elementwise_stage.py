@@ -91,7 +91,7 @@ def _image(gl: GemmLayer, seed: int = 0) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch", ["resnet18", "mobilenet_v2"])
+@pytest.mark.parametrize("arch", ["resnet18", "mobilenet_v2", "resnet50"])
 def test_workload_tails_lowered_into_program(arch):
     cfg = CNNConfig(arch=arch, n_classes=10, in_hw=28, width=0.25)
     layers = [GemmLayer.from_conv(s) for s in specs_for(cfg)]

@@ -180,7 +180,7 @@ def test_jitted_callables_named_by_role(mode):
     assert all(fn.__name__.startswith("n3h_") for fn in fns.values())
     for lp in prog.layers:
         dw, bits = lp.depthwise, lp.bits_w_lut
-        assert fns["fused-sp", bits, dw, lp.geometry].__name__ == \
+        assert fns["fused-sp", lp.index].__name__ == \
             f"n3h_conv_{ex.layer_path(lp.index, spatial=True)}"
         assert fns["fused", bits, dw].__name__ == \
             f"n3h_gemm_{ex.layer_path(lp.index, spatial=False)}"
@@ -211,7 +211,7 @@ def test_executable_is_jit_of_the_role_name():
     layers = _residual_chain()
     ex = _executor("pallas", layers)
     lp = ex.program.layers[0]
-    fn = ex._fns["fused-sp", lp.bits_w_lut, lp.depthwise, lp.geometry]
+    fn = ex._fns["fused-sp", lp.index]
     w = ex._weights[0]
     x = np.zeros(lp.geometry.in_shape, np.int8)
     text = fn.lower(x, w.w_lut, w.s_lut, w.w_dsp, w.s_dsp).as_text()

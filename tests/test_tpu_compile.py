@@ -10,6 +10,7 @@ themselves, at the shapes the wrappers give them.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +89,14 @@ CASES = {
     "conv_mobilenet_v2_b6_pw": lambda: _conv(14, 192, 1, 1, 0, 14, 1, 1),
     # resnet18 fc as a 1x1 conv on a 1x1 map: several DSP slabs
     "conv_resnet18_fc": lambda: _conv(1, 512, 1, 1, 0, 1, 6, 3),
+    # resnet50 layer4 conv_c: 1x1, k 512 -> n 2048 at m 49 (its XC7Z020
+    # split: 1696 LUT columns in 14 blocks, 352 DSP columns in 3)
+    "conv_resnet50_layer4_conv_c": lambda: _conv(7, 512, 1, 1, 0, 7, 14, 3),
+    # resnet50 layer4 projection: 1x1 stride 2, 14 -> 7, k 1024, n 2048
+    "conv_resnet50_layer4_proj_s2": lambda: _conv(14, 1024, 1, 2, 0, 7,
+                                                  14, 3),
+    # resnet50 layer1 conv_c: 1x1 at m 3136, n 256
+    "conv_resnet50_layer1_conv_c": lambda: _conv(56, 64, 1, 1, 0, 56, 2, 1),
     "flash_attention": lambda: (
         flash_attention, [((1, 4, 256, 128), F32)] * 3),
 }
@@ -100,3 +109,38 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_launch_is_named_by_window_and_layers(one_chip):
+    """The executor's name for a fused conv launch (its window and the
+    indices of the layers that share it) is the kernel's instruction
+    name in the compiled HLO, which the device trace carries."""
+    from repro.compiler import PallasExecutor, compile_network
+    from repro.compiler.runtime.pallas import _launch_name
+    prog = compile_network("resnet50", in_hw=32, width=0.25)
+    fns = PallasExecutor._build_fns(prog, "kernel")
+    groups: dict = {}
+    for lp in prog.layers:
+        groups.setdefault(fns["fused-sp", lp.index], []).append(lp)
+    for lps in groups.values():   # one launch: one bit-width and geometry
+        assert len({(lp.bits_w_lut, lp.geometry) for lp in lps}) == 1
+    # layer3's identity blocks share their launches; the last conv_c
+    # (1x1, the only 512 -> 2048 at this size) has its own
+    shared = max(groups.values(), key=len)
+    k = shared[0].geometry.kernel
+    assert len(shared) >= 5 and _launch_name(shared, "kernel") == \
+        f"fused_conv_gemm_{k}x{k}_L" + "_".join(str(lp.index)
+                                                for lp in shared)
+    name = _launch_name([prog.layers[52]], "kernel")
+    assert name == "fused_conv_gemm_1x1_L52"
+    assert [lp.index for lp in groups[fns["fused-sp", 52]]] == [52]
+    fn, shapes = _conv(7, 512, 1, 1, 0, 7, 14, 3)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(functools.partial(fn, name=name)).lower(
+        *args).compile().as_text()
+    launches = [re.match(r"\s*(?:ROOT )?%(\S+) = ", line).group(1)
+                for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line]
+    assert launches and all(re.fullmatch(rf"{name}(\.\d+)?", instr)
+                            for instr in launches), launches
