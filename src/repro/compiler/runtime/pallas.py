@@ -28,8 +28,9 @@ pass-invariance suite and ``tests/test_fused_kernels.py`` pin this.
 "ref"). Under "auto" on a TPU, dense layers and in-budget convs run
 the compiled Pallas kernels (``kernels/fused_hetero_gemm.py``);
 depthwise convs run an exact int32 einsum under XLA, and convs whose
-whole-spatial working set is over the VMEM budget run the jnp oracle
-under XLA. Off the TPU, "auto" runs the jnp oracles everywhere. Each
+whole-spatial working set is over the VMEM budget run XLA's int8
+convolution from the spatial block (a 1x1 window: the oracle's dot).
+Off the TPU, "auto" runs the jnp oracles everywhere. Each
 executed layer records where it ran (``kernels.ops.conv_path`` /
 ``kernel_path``) in :attr:`PallasExecutor.layer_paths` and bumps the
 ``pallas.layer.<path>`` counter in ``obs.metrics.METRICS``, so no layer

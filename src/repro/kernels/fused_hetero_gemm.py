@@ -25,8 +25,10 @@ each tap is a [M, C] x [C, bn] matmul against the matching weight
 rows). No column matrix is ever materialized — not in DDR (the
 ``L{i}.col`` staging copy is gone from compiled programs) and not in
 VMEM. The whole spatial input must fit on chip; where it does not
-(``fused_conv_vmem_bytes``), the ``ops.py`` wrapper runs the jnp
-oracle under XLA instead and ``ops.conv_path`` reports "xla_vmem".
+(``fused_conv_vmem_bytes``), the ``ops.py`` wrapper runs XLA's int8
+convolution from the spatial block instead (a 1x1 window: the oracle's
+dot on the block; both bit-identical) and ``ops.conv_path`` reports
+"xla_vmem".
 
 Both kernels compile for the TPU (``tests/test_tpu_compile.py``) and
 are validated in interpret mode against the pure-jnp oracles
@@ -188,7 +190,7 @@ def fused_conv_vmem_bytes(in_hw: int, c_in: int, kernel: int, pad: int,
     """Rough VMEM working set of one ``fused_conv_gemm`` grid step: the
     padded spatial block, the per-column-block weight stack (planes are
     the worst case), the int32 accumulator and the fp32 output tile.
-    The ops.py wrapper falls back to the vectorized jnp path when this
+    The ops.py wrapper falls back to XLA's int8 convolution when this
     exceeds the budget."""
     hp = in_hw + 2 * pad
     x_bytes = hp * hp * c_in

@@ -9,7 +9,10 @@ Responsibilities the raw kernels don't take:
   * two conv cases that never reach a kernel, whatever the mode
     (:func:`conv_path` names them): depthwise layers are an exact
     int32 einsum under XLA, and a conv whose whole-spatial working set
-    is over :data:`FUSED_CONV_VMEM_BUDGET` runs the oracle under XLA;
+    is over :data:`FUSED_CONV_VMEM_BUDGET` is XLA's int8 convolution
+    from the spatial block (no patch tensor; a 1x1 window, whose patch
+    matrix is the block itself, keeps the oracle's dot), bit-identical
+    to the oracle;
   * GQA head broadcasting for flash attention.
 
 These wrappers are the only entry points the model zoo uses.
@@ -30,8 +33,8 @@ from repro.kernels.fused_hetero_gemm import (
 )
 
 #: VMEM working-set ceiling (bytes) above which the fused conv kernel
-#: falls back to the vectorized jnp path (whole spatial input must fit
-#: on chip for in-kernel im2col).
+#: falls back to XLA (whole spatial input must fit on chip for
+#: in-kernel im2col).
 FUSED_CONV_VMEM_BUDGET = 12 * 1024 * 1024
 
 
@@ -61,8 +64,8 @@ def conv_path(in_hw: int, c_in: int, kernel: int, pad: int, out_hw: int,
               vmem_budget: int | None = None) -> str:
     """Where a conv layer's GEMM runs: a :func:`kernel_path` name, or
     "xla_depthwise" (depthwise layers, on every backend) or "xla_vmem"
-    (the in-kernel im2col working set is over the VMEM budget, so the
-    oracle runs under XLA instead of the kernel)."""
+    (the in-kernel im2col working set is over the VMEM budget, so XLA's
+    int8 convolution runs instead of the kernel)."""
     if depthwise:
         return "xla_depthwise"
     path = kernel_path(mode)
@@ -234,6 +237,44 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
         [out[:m, :n_lut], out[:m, n_lut_pad:n_lut_pad + n_dsp]], axis=1)
 
 
+def _conv_xla(x_sp: jax.Array, kernel: int, stride: int, pad: int,
+              out_hw: int, w_lut: jax.Array | None,
+              s_lut: jax.Array | None, bits: int,
+              w_dsp: jax.Array | None, s_dsp: jax.Array | None
+              ) -> jax.Array:
+    """The split conv as one XLA int8 convolution from the spatial
+    block (NHWC x HWIO -> int32), with no patch tensor: its output
+    features are the LUT side's ``bits`` binary planes, plane-major,
+    then the DSP side's int4 codes. The planes' sums are weighted in
+    int32 as :func:`ref.fused_hetero_gemm_ref` does, so the result is
+    bit-identical to the oracle on the staged patches."""
+    c = x_sp.shape[2]
+    k = kernel * kernel * c
+    feats, scales = [], []
+    n_lut = 0
+    if w_lut is not None:
+        n_lut = w_lut.shape[1]
+        planes = ref.bitplane_decompose(w_lut, bits)     # [bits, k, n_lut]
+        feats.append(planes.transpose(1, 0, 2).reshape(k, bits * n_lut))
+        scales.append(s_lut)
+    if w_dsp is not None:
+        feats.append(jnp.asarray(w_dsp, jnp.int8))
+        scales.append(s_dsp)
+    w = jnp.concatenate(feats, axis=1).reshape(kernel, kernel, c, -1)
+    acc = jax.lax.conv_general_dilated(
+        x_sp[None], w, (stride, stride), [(pad, pad), (pad, pad)],
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32)
+    acc = acc[0, :out_hw, :out_hw].reshape(out_hw * out_hw, -1)
+    if n_lut:
+        s = ref.plane_scales(bits)
+        lut = sum(s[b] * acc[:, b * n_lut:(b + 1) * n_lut]
+                  for b in range(bits))
+        acc = jnp.concatenate([lut, acc[:, bits * n_lut:]], axis=1)
+    sc = scales[0] if len(scales) == 1 else jnp.concatenate(scales)
+    return acc.astype(jnp.float32) * sc[None, :]
+
+
 def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
                       out_hw: int, w_lut: jax.Array | None,
                       s_lut: jax.Array | None, bits: int,
@@ -248,10 +289,13 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
 
     x_sp: [H, W, C] int8 spatial activations (*unpadded*; zero padding
     happens here); weights/scales as :func:`fused_matmul` with K =
-    ``kernel**2 * C`` rows in (kh, kw, c) order. Runs the vectorized
-    jnp path (still a single fused jit call) where :func:`conv_path`
-    says "ref" or "xla_vmem"; elsewhere ``name`` names the kernel's
-    launch (``fused_hetero_gemm.fused_conv_gemm``).
+    ``kernel**2 * C`` rows in (kh, kw, c) order. Where
+    :func:`conv_path` says "ref", or "xla_vmem" for a 1x1 window, the
+    oracle stages patches (``ref.conv_patches_ref``); where it says
+    "xla_vmem" for a wider window XLA's int8 convolution contracts the
+    spatial block (:func:`_conv_xla`); elsewhere ``name`` names the
+    kernel's launch (``fused_hetero_gemm.fused_conv_gemm``). All give
+    the same bits.
     """
     w_lut, s_lut = _norm_side(w_lut, s_lut)
     w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
@@ -261,7 +305,12 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
     k = kernel * kernel * x_sp.shape[2]
     path = conv_path(x_sp.shape[0], x_sp.shape[2], kernel, pad, out_hw,
                      bits, mode=mode, vmem_budget=vmem_budget)
+    if path == "xla_vmem" and kernel > 1:
+        return _conv_xla(x_sp, kernel, stride, pad, out_hw, w_lut, s_lut,
+                         bits, w_dsp, s_dsp)
     if path in ("ref", "xla_vmem"):
+        # a 1x1 window's patches are the block itself; on the v5e XLA's
+        # convolution of it added relayout copies that its dot does not
         x_col = ref.conv_patches_ref(x_sp, kernel, stride, pad, out_hw)
         return ref.fused_hetero_gemm_ref(x_col.reshape(m, k), w_lut, s_lut,
                                          bits, w_dsp, s_dsp)

@@ -34,6 +34,8 @@ from repro.compiler import (
 from repro.compiler.runtime import ExecutionError
 from repro.core.scheduler import XC7Z020, DspCoreConfig, GemmDims, \
     LutCoreConfig
+from repro.kernels import ops
+from repro.kernels.fused_hetero_gemm import fused_conv_vmem_bytes
 from repro.models.cnn import CNNConfig, specs_for
 from repro.obs import METRICS
 
@@ -97,6 +99,45 @@ def test_chain_bit_exact_vs_golden_and_eager(arch, in_hw):
     if in_hw != 28:
         golden = np.asarray(_bound(prog, GoldenExecutor).run(x))
         assert chain.tobytes() == golden.tobytes()
+
+
+@pytest.mark.parametrize("arch", ["resnet18", "mobilenet_v2"])
+def test_over_budget_layers_run_xla_conv_in_the_chain(arch, monkeypatch):
+    """With the VMEM budget just under the stem's working set, the stem
+    (and any other layer over it) takes ``xla_vmem``, XLA's int8
+    convolution from the spatial block, inside ``n3h_chain``: the
+    logits equal the eager chain's and ``mode="ref"``'s bit for bit,
+    ``layer_paths`` names exactly the over-budget layers, and
+    ``pallas.layer.xla_vmem`` rises by their number per run."""
+    prog = _cnn_prog(arch, 32)
+
+    def vmem_bytes(lp):
+        g = lp.geometry
+        return fused_conv_vmem_bytes(g.in_shape[0], g.in_shape[2], g.kernel,
+                                     g.pad, g.out_hw ** 2, lp.dims.k,
+                                     lp.bits_w_lut)
+    budget = vmem_bytes(prog.layers[0]) - 1
+    over = {lp.name for lp in prog.layers
+            if not lp.depthwise and vmem_bytes(lp) > budget}
+    assert prog.layers[0].name in over and len(over) < len(prog.layers) // 2
+    monkeypatch.setattr(ops, "FUSED_CONV_VMEM_BUDGET", budget)
+    PallasExecutor.cache_clear()
+    try:
+        x = _input(prog, seed=4)
+        ex = _bound(prog, mode="kernel")
+        chain = np.asarray(ex.run(x))
+        eager = np.asarray(_bound(prog, mode="kernel",
+                                  check_timing=True).run(x))
+        want = np.asarray(_bound(prog, mode="ref").run(x))
+        assert np.abs(chain).sum() > 0
+        assert chain.tobytes() == eager.tobytes() == want.tobytes()
+        assert {n for n, p in ex.layer_paths.items()
+                if p == "xla_vmem"} == over
+        before = METRICS.counter("pallas.layer.xla_vmem")
+        ex.run(x)
+        assert METRICS.counter("pallas.layer.xla_vmem") - before == len(over)
+    finally:
+        PallasExecutor.cache_clear()
 
 
 def test_fc_chain_bit_exact_vs_golden_and_eager():

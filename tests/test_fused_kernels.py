@@ -158,8 +158,8 @@ def test_fused_grouped_ref_equals_per_partition():
 
 
 def test_fused_conv_vmem_fallback_is_bit_exact():
-    """Over-budget spatial inputs fall back to the jnp path — same
-    bits, still one fused jit call."""
+    """Over-budget spatial inputs fall back to XLA's int8 convolution
+    — same bits, still one fused jit call."""
     bits, n_lut, n_dsp, in_hw, c_in = 4, 8, 8, 6, 3
     kernel = stride = 1
     out_hw = in_hw
@@ -174,6 +174,45 @@ def test_fused_conv_vmem_fallback_is_bit_exact():
                               s_lut, bits, w_dsp, s_dsp, mode="kernel",
                               vmem_budget=1)     # force the fallback
     assert (np.asarray(a) == np.asarray(b)).all()
+
+
+#: (kernel, stride, pad, C, in_hw): the over-budget layers of the
+#: networks — resnet's 7x7 s2 stem, mobilenet_v2's 3x3 s2 stem and its
+#: 1x1 ``b0_pw`` (the oracle's dot), and a 3x3 s1 window — at CPU-cheap
+#: sizes
+XLA_VMEM_GEOMETRIES = [(7, 2, 3, 3, 32), (3, 2, 1, 3, 24), (1, 1, 0, 32, 16),
+                       (3, 1, 1, 16, 16)]
+#: (n_lut, n_dsp): both sides, LUT only, DSP only, and an n_lut that is
+#: not a multiple of the 128-column block
+XLA_VMEM_SPLITS = [(49, 15), (24, 0), (0, 20), (130, 6)]
+
+
+@pytest.mark.parametrize("geom", XLA_VMEM_GEOMETRIES,
+                         ids=lambda g: "k{}s{}p{}c{}".format(*g[:4]))
+@pytest.mark.parametrize("split", XLA_VMEM_SPLITS,
+                         ids=lambda s: "lut{}dsp{}".format(*s))
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_xla_vmem_conv_is_bitwise_the_oracle(geom, split, bits):
+    """The over-budget route (XLA's int8 convolution from the spatial
+    block, no patch tensor, for windows wider than 1x1) gives the bits
+    of ``mode="ref"`` (patches staged by ``ref.conv_patches_ref``)."""
+    kernel, stride, pad, c_in, in_hw = geom
+    n_lut, n_dsp = split
+    out_hw = (in_hw + 2 * pad - kernel) // stride + 1
+    assert ops.conv_path(in_hw, c_in, kernel, pad, out_hw, bits,
+                         mode="kernel", vmem_budget=1) == "xla_vmem"
+    rng = np.random.default_rng(bits * 1000 + n_lut + kernel)
+    x_sp = jnp.asarray(rng.integers(-128, 128, (in_hw, in_hw, c_in)),
+                       jnp.int8)
+    w_lut, s_lut, w_dsp, s_dsp = _split_weights(
+        rng, kernel * kernel * c_in, n_lut, n_dsp, bits)
+    args = (x_sp, kernel, stride, pad, out_hw, w_lut, s_lut, bits,
+            w_dsp, s_dsp)
+    want = np.asarray(ops.fused_conv_matmul(*args, mode="ref"))
+    got = np.asarray(ops.fused_conv_matmul(*args, mode="kernel",
+                                           vmem_budget=1))
+    assert got.shape == (out_hw * out_hw, n_lut + n_dsp)
+    assert got.tobytes() == want.tobytes()
 
 
 # ---------------------------------------------------------------------------

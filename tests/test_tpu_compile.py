@@ -21,6 +21,7 @@ from repro.kernels.bitserial_gemm import bitserial_gemm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_hetero_gemm import fused_conv_gemm, fused_hetero_gemm
 from repro.kernels.int4_gemm import int4_gemm
+from repro.kernels.ops import fused_conv_matmul
 
 BN = 128
 I8, F32 = jnp.int8, jnp.float32
@@ -144,3 +145,22 @@ def test_launch_is_named_by_window_and_layers(one_chip):
                 if 'custom_call_target="tpu_custom_call"' in line]
     assert launches and all(re.fullmatch(rf"{name}(\.\d+)?", instr)
                             for instr in launches), launches
+
+
+def test_xla_vmem_stem_compiles_as_an_int8_convolution(one_chip):
+    """resnet's 7x7 stride-2 stem at 224 (C 3, m 12544) is over the
+    VMEM budget: the wrapper hands XLA one int8 convolution from the
+    spatial block with int32 sums, which the v5e's compiler takes, with
+    no Pallas launch."""
+    k, n_lut, n_dsp = 7 * 7 * 3, 49, 15
+    fn = functools.partial(fused_conv_matmul, kernel=7, stride=2, pad=3,
+                           out_hw=112, bits=4, mode="kernel")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            [((224, 224, 3), I8), ((k, n_lut), jnp.int32), ((n_lut,), F32),
+             ((k, n_dsp), jnp.int32), ((n_dsp,), F32)]]
+    text = jax.jit(lambda x, wl, sl, wd, sd: fn(
+        x, w_lut=wl, s_lut=sl, w_dsp=wd, s_dsp=sd)).lower(
+        *args).compile().as_text()
+    convs = [line for line in text.splitlines()
+             if re.search(r"= s32\[[0-9,]*\]\{[^}]*\} convolution\(", line)]
+    assert convs and "tpu_custom_call" not in text
