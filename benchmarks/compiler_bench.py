@@ -9,10 +9,6 @@ backends:
   * one registry LM smoke program executed functionally on both
     backends: golden interpreter vs batched Pallas fast path, wall
     clock + speedup + a bit-exactness flag;
-  * ``kernels.fused.*`` rows: the fused one-launch-per-layer path vs
-    the per-partition batched path (launch counts, removed
-    ``L{i}.col`` DDR staging, wall-clock speedup, bit-exactness) with
-    a hard fused-must-not-be-slower regression guard;
   * whole-CNN inference rows: resnet18 and mobilenet_v2 executed end
     to end through the spatial im2col chain (depthwise grouped GEMMs
     included) on the pallas backend, with a golden bit-exactness
@@ -288,90 +284,6 @@ def bench_obs_overhead(seq_len: int = 16,
             json.dumps(bench, sort_keys=True))
 
 
-def bench_fused_kernels(smoke: bool = False) -> list[tuple[str, float, str]]:
-    """``kernels.fused.*`` rows: the one-launch-per-layer fused path vs
-    the per-partition batched path (``PallasExecutor(fused=False)``) —
-    wall clock, launch counts, the DDR traffic the removed ``L{i}.col``
-    staging would have cost, and a bit-exactness flag.
-
-    Regression guard: fused must never be slower than the per-partition
-    path on the ``llama3.2-1b`` program (hard assert), and must keep a
-    wall-clock win on the conv e2e program. Fused/split reps are
-    interleaved and min-of-N timed so a load ramp on a shared CI runner
-    hits both sides alike.
-    """
-    import math
-
-    def _measure(name, prog, drive, repeats=5):
-        fused = PallasExecutor(prog)
-        split = PallasExecutor(prog, fused=False)
-        for lp in prog.layers:
-            bind_synthetic(fused, lp, seed=lp.index)
-            bind_synthetic(split, lp, seed=lp.index)
-        out_f = drive(fused)
-        out_s = drive(split)                   # also warms the jit tables
-        f_times, s_times = [], []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            drive(fused)
-            f_times.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            drive(split)
-            s_times.append(time.perf_counter() - t0)
-        fused_s, split_s = min(f_times), min(s_times)
-        launches_split = sum((lp.lut is not None) + (lp.dsp is not None)
-                             for lp in prog.layers)
-        col_bytes = sum(
-            math.ceil(lp.dims.m * lp.dims.k
-                      * (lp.dims.n if lp.depthwise else 1) * lp.bits_a / 8)
-            for lp in prog.layers if lp.geometry is not None)
-        bench = {
-            "BENCH": "kernels.fused",
-            "network": name,
-            "layers": len(prog.layers),
-            "launches_fused": len(prog.layers),
-            "launches_split": launches_split,
-            "col_staging_bytes_removed": col_bytes,
-            "fused_s": round(fused_s, 5),
-            "split_s": round(split_s, 5),
-            "speedup_x": round(split_s / max(fused_s, 1e-9), 2),
-            "bit_exact": bool((out_f == out_s).all()),
-        }
-        return (f"kernels.fused.{name}", 1e6 * fused_s,
-                json.dumps(bench, sort_keys=True)), fused_s, split_s
-
-    rows = []
-    # per-layer LM drive (the registry smoke program) — the guard case
-    prog = compile_network(EXEC_NETWORK, seq_len=16 if smoke else 64,
-                           opt_level=1)
-    acts = {lp.index: np.random.default_rng(1000 + lp.index).integers(
-        -8, 8, (lp.dims.m, lp.dims.k)).astype(np.int8)
-        for lp in prog.layers}
-
-    def drive_lm(ex):
-        return np.concatenate(
-            [np.asarray(ex.run_layer(i, acts[i])).ravel() for i in acts])
-
-    row, fused_s, split_s = _measure(EXEC_NETWORK, prog, drive_lm)
-    assert fused_s <= split_s, \
-        (f"fused path regressed: {fused_s:.4f}s vs per-partition "
-         f"{split_s:.4f}s on {EXEC_NETWORK}")
-    rows.append(row)
-
-    # conv e2e drive (in-kernel im2col, no L{i}.col staging)
-    kw = {"in_hw": 28, "width": 0.25} if smoke else {}
-    cprog = compile_network("resnet18", opt_level=1, **kw)
-    x_q = np.random.default_rng(0).integers(
-        -8, 8, cprog.layers[0].geometry.in_shape).astype(np.int8)
-    row, fused_s, split_s = _measure(
-        "resnet18_e2e", cprog, lambda ex: np.asarray(ex.run(x_q)))
-    assert fused_s <= split_s, \
-        (f"fused conv path regressed: {fused_s:.4f}s vs per-partition "
-         f"{split_s:.4f}s on resnet18 e2e")
-    rows.append(row)
-    return rows
-
-
 def bench_dse_sim_gap(smoke: bool = False) -> list[tuple[str, float, str]]:
     """``dse.sim_gap.*`` rows: the analytical latency model the DSE
     explores with vs ``simulate_program`` on the compiled ``-O1``
@@ -510,7 +422,6 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
         rows.append(bench_cnn_execute(arch, smoke=smoke))
     rows.append(bench_multi_device(seq_len=16 if smoke else 64))
     rows.append(bench_obs_overhead(seq_len=16 if smoke else 64))
-    rows.extend(bench_fused_kernels(smoke=smoke))
     rows.extend(bench_dse_sim_gap(smoke=smoke))
     rows.append(bench_gather_overlap(smoke=smoke))
     rows.extend(bench_serve_decode(smoke=smoke))

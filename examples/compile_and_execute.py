@@ -92,17 +92,17 @@ def main() -> None:
     x_q = jnp.clip(jnp.round(x / s_a), lo, hi).astype(jnp.int8)
 
     got = np.asarray(ex.run_layer(0, x_q))
-    want = np.asarray(kernels.hetero_matmul(
+    want = np.asarray(kernels.fused_matmul(
         x_q, d.wq_serial, d.s_serial, d.bits_serial,
         d.wq_parallel, d.s_parallel))
     exact = (got == want).all()
-    print(f"[execute] golden model vs hetero_matmul on [{M},{K}]x[{K},{N}] "
+    print(f"[execute] golden model vs fused_matmul on [{M},{K}]x[{K},{N}] "
           f"(n_lut={n_lut}): bit-exact={bool(exact)}")
     assert exact
 
-    # 5. Same layer on the batched Pallas backend: one
-    #    bitserial_matmul/int4_matmul call per partition instead of the
-    #    interpreter's per-tile Python loop — bit-identical output.
+    # 5. Same layer on the batched Pallas backend: one fused_matmul
+    #    call for both partitions instead of the interpreter's per-tile
+    #    Python loop — bit-identical output.
     fast = PallasExecutor(layer_prog)
     fast.bind_deployed(0, d)
     t0 = time.time()

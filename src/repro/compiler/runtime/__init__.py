@@ -7,15 +7,16 @@
   pallas.py  — :class:`PallasExecutor`: fused fast path, one
                split-aware ``kernels`` call per *layer* (im2col-free
                convs; per-program JIT cache keyed on the program
-               fingerprint; ``fused=False`` for the per-partition
-               batched path).
+               fingerprint).
   multi.py   — :class:`MultiDeviceExecutor`: steps a
                ``partition.MultiDeviceProgram`` bundle, one backend
                executor per device, with the cross-device hand-off.
 
 Select by name via :func:`get_backend` (the CLI's ``--backend`` flag
 resolves here). To add a backend: subclass ``ExecutorBackend``,
-implement ``_run_core``, and register it in :data:`BACKENDS`.
+implement ``_run_core`` (one partition's tiles; ``GoldenExecutor``)
+or override ``run_layer`` (the whole layer; ``PallasExecutor``), and
+register it in :data:`BACKENDS`.
 """
 from repro.compiler.runtime.base import (
     ExecutionError,

@@ -10,8 +10,10 @@ against real weight codes and dequant scales. Two implementations ship:
   * ``runtime/pallas.py`` — the fused fast path: one
     ``kernels.fused_matmul`` / ``fused_conv_matmul`` call per *layer*
     covering both sides of the split (bit-identical outputs, orders of
-    magnitude faster, Pallas kernels on TPU; ``fused=False`` restores
-    the per-partition batched path).
+    magnitude faster, Pallas kernels on TPU). It overrides
+    :meth:`ExecutorBackend.run_layer`; the per-partition
+    ``run_layer`` / ``_run_core`` hook here serves the golden
+    interpreter.
 
 This module holds everything backends share: weight binding and
 validation, activation checks and im2col staging (conv layers accept

@@ -35,7 +35,6 @@ import math
 import jax.numpy as jnp
 
 from repro.core import isa
-from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.compiler.lower import EW_STAGE, KV_APPEND_STAGE, KV_READ_STAGE
 from repro.compiler.program import CORE_NAMES, CoreProgram, LayerProgram
@@ -201,9 +200,9 @@ class GoldenExecutor(ExecutorBackend):
                 tile = kref.bitserial_gemm_ref(
                     x_q[r0:r1], w_codes[:, c0:c1], w_scales[c0:c1], bits)
             else:
-                tile = kops.int4_matmul(
-                    x_q[r0:r1], w_codes[:, c0:c1], w_scales[c0:c1],
-                    mode="ref")
+                tile = kref.fused_hetero_gemm_ref(
+                    x_q[r0:r1], None, None, bits, w_codes[:, c0:c1],
+                    w_scales[c0:c1])
             tiles[(j * nt_m + ti) if core_name == "lut"
                   else (ti * nt_n + j)] = tile
             t += 1

@@ -3,23 +3,20 @@
 The golden interpreter loops a Python iteration per tile (plus a
 per-core scheduler validation), which makes large registry LM programs
 unusably slow to execute. This backend exploits that a layer's tile
-grids compute a plain split GEMM, and (by default) dispatches ONE
-fused kernel call per layer: ``kernels.fused_matmul`` consumes both
-sides of the Eq.-12 split — the first ``n_lut`` output columns
+grids compute a plain split GEMM, and dispatches ONE fused kernel call
+per layer: ``kernels.fused_matmul`` consumes both sides of the Eq.-12
+split — the first ``n_lut`` output columns
 bit-serially at the layer's LUT bit-width, the rest as packed int4 —
 accumulating into a single int32 [m, n] tile with per-column fp32
-dequant, so the per-layer concat and the second launch disappear. Conv
+dequant, so no layer needs a concat or a second launch. Conv
 layers go through ``kernels.fused_conv_matmul`` /
 ``fused_depthwise_matmul``, which generate im2col patches *inside* the
 launch from the raw spatial NHWC block (no ``L{i}.col`` staging copy
-exists in compiled programs' DDR maps). ``fused=False`` restores the
-per-partition batched path (one ``bitserial_matmul`` / ``int4_matmul``
-call per core), which is also the fused path's reference in the
-benchmark regression guard.
+exists in compiled programs' DDR maps).
 
 Bit-exactness: every path accumulates in exact int32 (bitplane or
 packed-int4 arithmetic) and applies per-column fp32 scales
-elementwise, so fused == per-partition == the golden interpreter's
+elementwise, so the fused call == the golden interpreter's
 tile-by-tile assembly bit for bit — tiling/fusing an exact integer
 GEMM is associative, and the dequant scale is per output element. The
 pass-invariance suite and ``tests/test_fused_kernels.py`` pin this.
@@ -39,14 +36,13 @@ leaves the kernel silently.
 Every jitted callable is named by its role, so the profiler's ``XLA
 Modules`` line says what ran: ``n3h_conv_<path>`` (spatial fused
 calls), ``n3h_gemm_<path>`` (pre-staged fused calls), ``n3h_tail``
-(elementwise epilogues), ``n3h_lut`` / ``n3h_dsp`` (the per-partition
-path), ``n3h_chain`` (the whole chain); the eager chain's glue keeps
-jnp's names. Inside them the fused conv kernel is launched under a
-name that gives its window and the indices in the program of the
-layers that launch it, ``fused_conv_gemm_<k>x<k>_L<i>[_<j>...]`` (the
-layers of one geometry share one traced launch): the kernel's
-instruction name in the compiled HLO and the device trace.
-``run_layer`` opens the
+(elementwise epilogues), ``n3h_chain`` (the whole chain); the eager
+chain's glue keeps jnp's names. Inside them the fused conv kernel is
+launched under a name that gives its window and the indices in the
+program of the layers that launch it,
+``fused_conv_gemm_<k>x<k>_L<i>[_<j>...]`` (the layers of one geometry
+share one traced launch): the kernel's instruction name in the
+compiled HLO and the device trace. ``run_layer`` opens the
 ``n3h.layer.launch`` host span (``repro.obs.spans``) around the enqueue
 of the layer's call.
 
@@ -54,23 +50,22 @@ One executable per program: the table also holds ``n3h_chain``, the
 shared ``chain_layers`` traced once over the program with the table's
 callables inlined, the bound weights as one pytree argument and the
 input scale as a traced scalar. ``run`` calls it, inside the
-``n3h.run`` and ``n3h.run.launch`` spans, when the executor is
-``fused``, runs without ``check_timing`` and keeps its own
-``run_layer``; otherwise ``run`` drives the eager chain through
-``run_layer``. Either way each run records ``layer_paths``, bumps
-``pallas.layer.<path>`` once per layer, and counts itself as
-``pallas.run.chain`` or ``pallas.run.eager``.
+``n3h.run`` and ``n3h.run.launch`` spans, when the executor runs
+without ``check_timing`` and keeps its own ``run_layer``; otherwise
+``run`` drives the eager chain through ``run_layer``. Either way each
+run records ``layer_paths``, bumps ``pallas.layer.<path>`` once per
+layer, and counts itself as ``pallas.run.chain`` or
+``pallas.run.eager``.
 
 Per-program JIT cache: every distinct ``(program fingerprint, mode)``
-gets one *complete* table of jitted callables (split and fused
-entries), built atomically under the cache lock at construction and
-never mutated afterwards — so concurrent executors can share a table
-without races. The table is shared across executor instances
-(class-level LRU whose capacity comes from the ``jit_cache_max``
-constructor argument or the ``REPRO_PALLAS_JIT_CACHE_MAX`` env var).
-Hits/misses are published to ``obs.metrics.METRICS`` as
-``pallas.jit_cache.*`` so ``launch/serve.py --metrics`` reports
-kernel-cache behavior alongside the program-image cache.
+gets one *complete* table of jitted callables, built atomically under
+the cache lock at construction and never mutated afterwards — so
+concurrent executors can share a table without races. The table is
+shared across executor instances (a class-level LRU of
+``_jit_cache_capacity`` programs). Hits/misses are published to
+``obs.metrics.METRICS`` as ``pallas.jit_cache.*`` so
+``launch/serve.py --metrics`` reports kernel-cache behavior alongside
+the program-image cache.
 
 Timing/contract checks are *off* by default here (that is the golden
 backend's job); pass ``check_timing=True`` to keep the per-core
@@ -80,19 +75,17 @@ path too.
 from __future__ import annotations
 
 import collections
-import os
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import isa
 from repro.kernels import ops as kops
 from repro.obs import spans
 from repro.obs.metrics import METRICS
 from repro.obs.spans import span
-from repro.compiler.program import CoreProgram, LayerProgram
+from repro.compiler.program import LayerProgram
 from repro.compiler.runtime.base import (
     ExecutionError,
     ExecutorBackend,
@@ -112,33 +105,6 @@ def _named_jit(f, name: str):
     ``jit_<name>`` in the profiler's ``XLA Modules`` line."""
     f.__name__ = f.__qualname__ = name
     return jax.jit(f)
-
-
-def _make_lut_fn(bits: int, mode: str):
-    def f(x_q, w_codes, w_scales):
-        return kops.bitserial_matmul(x_q, w_codes, w_scales, bits,
-                                     mode=mode)
-    return _named_jit(f, "n3h_lut")
-
-
-def _make_dsp_fn(mode: str):
-    def f(x_q, w_codes, w_scales):
-        return kops.int4_matmul(x_q, w_codes, w_scales, mode=mode)
-    return _named_jit(f, "n3h_dsp")
-
-
-def _make_lut_dw_fn(bits: int, mode: str):
-    def f(x_col, w_codes, w_scales):
-        return kops.bitserial_grouped_matmul(x_col, w_codes, w_scales,
-                                             bits, mode=mode)
-    return _named_jit(f, "n3h_lut")
-
-
-def _make_dsp_dw_fn(mode: str):
-    def f(x_col, w_codes, w_scales):
-        return kops.int4_grouped_matmul(x_col, w_codes, w_scales,
-                                        mode=mode)
-    return _named_jit(f, "n3h_dsp")
 
 
 def _make_fused_fn(bits: int, depthwise: bool, mode: str):
@@ -166,7 +132,7 @@ def _launch_name(lps: list, mode: str) -> str:
     do not (the name is then never emitted). One name for the group
     keeps one trace and lowering of the kernel for it, as before the
     launches were named."""
-    if _layer_path(lps[0], True, True, mode) not in ("kernel", "interpret"):
+    if _layer_path(lps[0], True, mode) not in ("kernel", "interpret"):
         return "fused_conv_gemm"
     k = lps[0].geometry.kernel
     return f"fused_conv_gemm_{k}x{k}_L" + "_".join(str(lp.index)
@@ -196,13 +162,11 @@ def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str,
     return _named_jit(f, f"n3h_conv_{path}")
 
 
-def _layer_path(lp: LayerProgram, spatial: bool, fused: bool,
-                mode: str) -> str:
+def _layer_path(lp: LayerProgram, spatial: bool, mode: str) -> str:
     """Where ``lp`` runs: a ``kops.conv_path`` name for a spatial conv
-    input on the fused path, else "xla_depthwise" or a
-    ``kops.kernel_path`` name."""
+    input, else "xla_depthwise" or a ``kops.kernel_path`` name."""
     geom = lp.geometry
-    if spatial and fused:
+    if spatial:
         return kops.conv_path(geom.in_shape[0], geom.in_shape[2],
                               geom.kernel, geom.pad, geom.out_hw,
                               lp.bits_w_lut, depthwise=lp.depthwise,
@@ -251,7 +215,7 @@ def _make_chain_fn(program, fns: dict, mode: str):
         def run_layer(index, x):
             lp = layers[index]
             x = jnp.asarray(x, jnp.int8)
-            path = _layer_path(lp, _is_spatial(lp, x), True, mode)
+            path = _layer_path(lp, _is_spatial(lp, x), mode)
             with span(_LAUNCH_SPANS[path]):
                 return _launch(fns, lp, x, weights[index])
         return chain_layers(layers, run_layer, x_q, x_scale,
@@ -270,25 +234,17 @@ class PallasExecutor(ExecutorBackend):
     #: compiled program skips retracing.
     _jit_cache: "collections.OrderedDict[tuple, dict]" = \
         collections.OrderedDict()
-    _jit_cache_max = int(os.environ.get("REPRO_PALLAS_JIT_CACHE_MAX", "16"))
+    _jit_cache_capacity = 16
     _jit_cache_lock = threading.Lock()
     _cache_hits = 0
     _cache_misses = 0
 
     def __init__(self, program, check_timing: bool = False,
-                 mode: str = "auto", fused: bool = True,
-                 jit_cache_max: int | None = None):
+                 mode: str = "auto"):
         super().__init__(program, check_timing=check_timing)
         self.mode = mode
-        self.fused = fused
         #: layer name -> where its last execution ran (see layer_path)
         self.layer_paths: dict[str, str] = {}
-        if jit_cache_max is not None:
-            with PallasExecutor._jit_cache_lock:
-                PallasExecutor._jit_cache_max = int(jit_cache_max)
-                while len(PallasExecutor._jit_cache) > \
-                        PallasExecutor._jit_cache_max:
-                    PallasExecutor._jit_cache.popitem(last=False)
         self._fns = self._program_fns(program, mode)
         # what one run of the chain executable records on the host: the
         # path of each layer and the pallas.layer.<path> counts
@@ -302,26 +258,15 @@ class PallasExecutor(ExecutorBackend):
 
     @classmethod
     def _build_fns(cls, program, mode: str) -> dict:
-        """The complete jit table for one program: split entries (the
-        per-partition path) and fused entries (the one-launch-per-layer
-        path), keyed so layers sharing (core, bits[, geometry]) share a
-        traced executable; a spatial layer's fused entry is under its
-        index."""
+        """The complete jit table for one program: one fused entry per
+        (bits, depthwise) for pre-staged inputs, one per spatial layer
+        (under its index; layers sharing bits and geometry share a
+        traced executable), the elementwise tails and the chain."""
         fns: dict = {}
         spatial = collections.defaultdict(list)
         for lp in program.layers:
             dw = lp.depthwise
             bits = lp.bits_w_lut
-            if lp.lut is not None:
-                key = ("lut-dw" if dw else "lut", bits)
-                if key not in fns:
-                    make = _make_lut_dw_fn if dw else _make_lut_fn
-                    fns[key] = make(bits, mode)
-            if lp.dsp is not None:
-                key = ("dsp-dw" if dw else "dsp", 4)
-                if key not in fns:
-                    make = _make_dsp_dw_fn if dw else _make_dsp_fn
-                    fns[key] = make(mode)
             key = ("fused", bits, dw)
             if key not in fns:
                 fns[key] = _make_fused_fn(bits, dw, mode)
@@ -361,7 +306,7 @@ class PallasExecutor(ExecutorBackend):
             METRICS.incr("pallas.jit_cache.miss")
             fns = cls._build_fns(program, mode)
             cls._jit_cache[key] = fns
-            while len(cls._jit_cache) > cls._jit_cache_max:
+            while len(cls._jit_cache) > cls._jit_cache_capacity:
                 cls._jit_cache.popitem(last=False)
             METRICS.gauge("pallas.jit_cache.programs", len(cls._jit_cache))
             return fns
@@ -372,7 +317,7 @@ class PallasExecutor(ExecutorBackend):
             return {"programs": len(cls._jit_cache),
                     "hits": cls._cache_hits,
                     "misses": cls._cache_misses,
-                    "maxsize": cls._jit_cache_max}
+                    "maxsize": cls._jit_cache_capacity}
 
     @classmethod
     def cache_clear(cls) -> None:
@@ -382,25 +327,23 @@ class PallasExecutor(ExecutorBackend):
 
     def layer_path(self, index: int, spatial: bool) -> str:
         """Where layer ``index`` runs under this executor's mode: a
-        ``kops.conv_path`` name for a spatial conv input on the fused
-        path, else "xla_depthwise" or a ``kops.kernel_path`` name."""
-        return _layer_path(self.program.layers[index], spatial,
-                           self.fused, self.mode)
+        ``kops.conv_path`` name for a spatial conv input, else
+        "xla_depthwise" or a ``kops.kernel_path`` name."""
+        return _layer_path(self.program.layers[index], spatial, self.mode)
 
     def bind_layer(self, index: int, *args, **kwargs) -> None:
         super().bind_layer(index, *args, **kwargs)
         self._chain_weights = None
 
     def run(self, x_q, x_scale: float = 1.0) -> jnp.ndarray:
-        """Chain all layers end to end (``ExecutorBackend.run``). With
-        ``fused``, without ``check_timing`` and with this class's own
-        ``run_layer``, the whole chain is one call into the program's
-        ``n3h_chain`` executable; otherwise the chain runs eagerly,
+        """Chain all layers end to end (``ExecutorBackend.run``). Without
+        ``check_timing`` and with this class's own ``run_layer``, the
+        whole chain is one call into the program's ``n3h_chain``
+        executable; otherwise the chain runs eagerly,
         one call per step of every layer, through ``self.run_layer``
         (so a ``run_layer`` replaced on a subclass or an instance is
         the one that runs)."""
-        if not self.fused or self.check_timing \
-                or "run_layer" in vars(self) \
+        if self.check_timing or "run_layer" in vars(self) \
                 or type(self).run_layer is not PallasExecutor.run_layer:
             METRICS.incr("pallas.run.eager")
             return super().run(x_q, x_scale)
@@ -433,8 +376,7 @@ class PallasExecutor(ExecutorBackend):
 
     def run_layer(self, index: int, x_q) -> jnp.ndarray:
         """One fused kernel call for the whole layer (both split
-        sides); falls back to the per-partition batched path
-        (``ExecutorBackend.run_layer``) when ``fused=False``."""
+        sides)."""
         lp = self.program.layers[index]
         if index not in self._weights:
             raise ExecutionError(f"layer {index} has no bound weights")
@@ -442,8 +384,6 @@ class PallasExecutor(ExecutorBackend):
         path = self.layer_path(index, _is_spatial(lp, x_q))
         self.layer_paths[lp.name] = path
         METRICS.incr(f"pallas.layer.{path}")
-        if not self.fused:
-            return super().run_layer(index, x_q)
         for cp in (lp.lut, lp.dsp):
             if cp is not None:
                 self._check_stream(lp, cp)
@@ -457,16 +397,3 @@ class PallasExecutor(ExecutorBackend):
         epilogue — falls back to the eager shared tail for layers
         without one in the table."""
         return _tail(self._fns, lp)
-
-    def _run_core(self, lp: LayerProgram, cp: CoreProgram, x_q,
-                  w_codes, w_scales) -> jnp.ndarray:
-        # the per-partition path (fused=False): depthwise partitions
-        # batch the whole grouped contraction in one call, like dense
-        # partitions batch their tile grid into one GEMM. Tables are
-        # complete at construction — read-only here (thread-safe).
-        dw = lp.depthwise
-        if cp.core == isa.CoreSel.LUT:
-            fn = self._fns[("lut-dw" if dw else "lut", lp.bits_w_lut)]
-        else:
-            fn = self._fns[("dsp-dw" if dw else "dsp", 4)]
-        return fn(x_q, w_codes, w_scales)

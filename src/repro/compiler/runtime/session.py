@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.quant.uniform import _inv_hi, fit_scale, qrange
-from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.obs import spans
 from repro.obs.spans import span
@@ -568,15 +567,9 @@ class ReferenceSession(DecodeSession):
     def _run_layer(self, index, x_q):
         lp = self.layers[index]
         wts = self._weights[index]
-        x = jnp.asarray(x_q, jnp.int8)
-        outs = []
-        if wts.w_lut is not None:
-            outs.append(kref.bitserial_gemm_ref(
-                x, wts.w_lut, wts.s_lut, lp.bits_w_lut))
-        if wts.w_dsp is not None:
-            outs.append(kops.int4_matmul(
-                x, wts.w_dsp, wts.s_dsp, mode="ref"))
-        return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+        return kref.fused_hetero_gemm_ref(
+            jnp.asarray(x_q, jnp.int8), wts.w_lut, wts.s_lut,
+            lp.bits_w_lut, wts.w_dsp, wts.s_dsp)
 
 
 def decode_step_ref(program) -> ReferenceSession:

@@ -169,7 +169,7 @@ def apply_deploy(d: DeployedHeteroLinear, x: jax.Array,
     lo, hi = qrange(d.a_bits)
     x_q = jnp.clip(jnp.round(x2 / s_a), lo, hi).astype(jnp.int8)
 
-    out = kernels.hetero_matmul(x_q, d.wq_serial, d.s_serial, d.bits_serial,
-                                d.wq_parallel, d.s_parallel, mode=mode)
+    out = kernels.fused_matmul(x_q, d.wq_serial, d.s_serial, d.bits_serial,
+                               d.wq_parallel, d.s_parallel, mode=mode)
     out = out[:, d.inv_perm] * s_a
     return out.reshape(*shape[:-1], out.shape[-1]).astype(x.dtype)

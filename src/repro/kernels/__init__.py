@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the perf-critical compute paths.
 
-  bitserial_gemm — bitplane GEMM (the LUT-core adaptation; latency ∝ bits)
-  int4_gemm      — packed-int4 GEMM (the DSP-core adaptation; fixed latency)
-  fused_hetero_gemm — both sides of the Eq.-12 split in ONE launch
+  fused_hetero_gemm — both sides of the Eq.-12 split in ONE launch:
+                     the LUT-core columns as bitplane GEMMs (latency
+                     ∝ bits), the DSP-core columns as packed int4
                      (dense + im2col-free conv variants)
   flash_attention — online-softmax attention for serving hot paths
 
@@ -13,13 +13,10 @@ broadcast).
 """
 from repro.kernels.ops import (
     attention,
-    bitserial_matmul,
     fused_conv_matmul,
     fused_depthwise_matmul,
     fused_grouped_matmul,
     fused_matmul,
-    hetero_matmul,
-    int4_matmul,
 )
 from repro.kernels.ref import (
     bitplane_decompose,
@@ -31,9 +28,8 @@ from repro.kernels.ref import (
 )
 
 __all__ = [
-    "attention", "bitserial_matmul", "fused_conv_matmul",
-    "fused_depthwise_matmul", "fused_grouped_matmul", "fused_matmul",
-    "hetero_matmul", "int4_matmul",
+    "attention", "fused_conv_matmul", "fused_depthwise_matmul",
+    "fused_grouped_matmul", "fused_matmul",
     "bitplane_decompose", "bitplane_reconstruct", "conv_patches_ref",
     "pack_int4", "plane_scales", "unpack_int4",
 ]

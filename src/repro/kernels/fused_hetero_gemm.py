@@ -11,12 +11,15 @@ consume both sides of the split in a *single* launch.
 LUT-region blocks followed by the DSP-region blocks. Per column block
 the kernel picks its path with ``pl.when`` on the block index: LUT
 blocks accumulate the bitplane decomposition (one int8 MXU matmul per
-plane, shifted partial sums — exactly ``bitserial_gemm``'s scheme), DSP
-blocks unpack two-int4-per-byte weights in-register and issue one int8
-matmul (``int4_gemm``'s scheme). Both paths share one int32 VMEM
-accumulator per output tile and one fp32 per-column dequant epilogue,
-so the per-layer concat disappears: the output lands as a single
-[M, N] tile in split column order.
+plane, shifted partial sums, so the cost stays proportional to the
+weight bits as the paper's L_LUT is), DSP blocks unpack
+two-int4-per-byte weights in-register and issue one int8 matmul (the
+packed layout halves the weights' HBM traffic). Both paths share one
+int32 VMEM accumulator per output tile and one fp32 per-column dequant
+epilogue, so the output lands as a single [M, N] tile in split column
+order. A one-sided split is the same launch with zero blocks in the
+empty region, which is handed one dummy weight block that is never
+read.
 
 ``fused_conv_gemm`` — the im2col-free conv variant: the kernel reads
 the raw zero-padded NHWC activation block and generates im2col patches
@@ -119,37 +122,50 @@ def _fused_kernel(x_ref, planes_ref, packed_ref, scale_ref, out_ref,
         out_ref[...] = acc_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "n_lut_blocks", "bm",
-                                             "bn", "bk", "interpret"))
+def _check_regions(planes: jax.Array, packed: jax.Array,
+                   w_scale: jax.Array, bits: int, nn: int, bn: int) -> None:
+    """The checks both kernels make of their split operands."""
+    if planes.shape[0] != max(bits, 1):
+        raise ValueError(f"planes leading dim {planes.shape[0]} != bits "
+                         f"{bits}")
+    if nn == 0:
+        raise ValueError("grid needs at least one column block")
+    if planes.shape[2] < bn or packed.shape[1] < bn // 2:
+        raise ValueError("each region needs at least one weight block "
+                         "(use a dummy when the region is empty)")
+    if w_scale.shape[0] < nn * bn:
+        raise ValueError(f"scales {w_scale.shape[0]} < grid columns "
+                         f"{nn * bn}")
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "bits", "n_lut_blocks", "n_dsp_blocks", "bm", "bn", "bk", "interpret"))
 def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
-                      w_scale: jax.Array, bits: int, n_lut_blocks: int, *,
-                      bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                      bk: int = DEFAULT_BK,
+                      w_scale: jax.Array, bits: int, n_lut_blocks: int,
+                      n_dsp_blocks: int, *, bm: int = DEFAULT_BM,
+                      bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
                       interpret: bool = False) -> jax.Array:
     """Single-launch split GEMM over pre-padded operands.
 
-    x: [M, K] int8; planes: [bits, K, N_lut] int8 {0, 1} plane stack of
-    the LUT columns; packed: [K, N_dsp//2] int8 ``ref.pack_int4`` bytes
-    (``block=bn``) of the DSP columns; w_scale: [N_lut + N_dsp] fp32,
-    handed to the kernel as a [1, N] row in (1, bn) blocks. N_lut must be
-    ``n_lut_blocks * bn``; every extent must divide by its block (pad at
-    the ops.py layer). Returns fp32 [M, N_lut + N_dsp] in split column
-    order.
+    x: [M, K] int8; planes: [max(bits, 1), K, >=bn] int8 {0, 1} plane
+    stack of the LUT columns; packed: [K, >=bn//2] int8
+    ``ref.pack_int4`` bytes (``block=bn``) of the DSP columns; w_scale:
+    [(n_lut_blocks + n_dsp_blocks) * bn] fp32 in split region order,
+    handed to the kernel as a [1, N] row in (1, bn) blocks. The grid
+    covers ``n_lut_blocks`` LUT column blocks then ``n_dsp_blocks`` DSP
+    blocks; a region with zero blocks still needs one (dummy,
+    never-consumed) weight block so its BlockSpec stays in-bounds. M
+    and K must divide by their blocks (pad at the ops.py layer).
+    Returns fp32 [M, N] in split column order.
     """
     m, k = x.shape
-    _, _, n_lut = planes.shape
-    n_dsp = packed.shape[1] * 2
-    n = n_lut + n_dsp
-    if planes.shape[0] != bits:
-        raise ValueError(
-            f"planes leading dim {planes.shape[0]} != bits {bits}")
-    if n_lut != n_lut_blocks * bn:
-        raise ValueError(f"LUT columns {n_lut} != n_lut_blocks*bn "
-                         f"({n_lut_blocks}x{bn})")
-    if m % bm or k % bk or n_dsp % bn:
-        raise ValueError(f"shape ({m},{k},{n_lut}+{n_dsp}) not divisible "
-                         f"by blocks ({bm},{bk},{bn}); pad first")
-    nm, nn, nk = m // bm, n // bn, k // bk
+    nn = n_lut_blocks + n_dsp_blocks
+    _check_regions(planes, packed, w_scale, bits, nn, bn)
+    if m % bm or k % bk:
+        raise ValueError(f"shape ({m},{k}) not divisible by blocks "
+                         f"({bm},{bk}); pad first")
+    n = nn * bn
+    nk = k // bk
 
     kwargs = {}
     if not interpret:
@@ -159,12 +175,12 @@ def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
     nl = n_lut_blocks
     return pl.pallas_call(
         functools.partial(_fused_kernel, bits=bits, nk=nk, nn_lut=nl),
-        grid=(nm, nn, nk),
+        grid=(m // bm, nn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             # clamp each region's block index so the other region's
             # blocks read a valid (ignored) block instead of OOB
-            pl.BlockSpec((bits, bk, bn),
+            pl.BlockSpec((max(bits, 1), bk, bn),
                          lambda i, j, kk: (0, kk, jnp.minimum(j, nl - 1)
                                            if nl else 0)),
             pl.BlockSpec((None, bk, bn // 2),
@@ -176,7 +192,7 @@ def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         **kwargs,
-    )(x, planes, dsp_blocks(packed, bn), w_scale.reshape(1, -1))
+    )(x, planes, dsp_blocks(packed, bn), w_scale[:n].reshape(1, n))
 
 
 # ---------------------------------------------------------------------------
@@ -298,15 +314,9 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
     """
     c_in = x_sp.shape[2]
     nn = n_lut_blocks + n_dsp_blocks
+    _check_regions(planes, packed, w_scale, bits, nn, bn)
     n = nn * bn
-    if nn == 0:
-        raise ValueError("grid needs at least one column block")
     k = kernel * kernel * c_in
-    if planes.shape[2] < bn or packed.shape[1] < bn // 2:
-        raise ValueError("each region needs at least one weight block "
-                         "(use a dummy when the region is empty)")
-    if w_scale.shape[0] < n:
-        raise ValueError(f"scales {w_scale.shape[0]} < grid columns {n}")
     m = out_hw * out_hw
     if kernel == 1:
         span = stride * (out_hw - 1) + 1

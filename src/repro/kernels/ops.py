@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.bitserial_gemm import bitserial_gemm as _bitserial_kernel
-from repro.kernels.int4_gemm import int4_gemm as _int4_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.fused_hetero_gemm import (
     fused_conv_gemm as _fused_conv_kernel,
@@ -88,75 +86,6 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, pads)
 
 
-def bitserial_matmul(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array,
-                     bits: int, *, block: tuple[int, int, int] = (128, 128, 128),
-                     mode: str = "auto") -> jax.Array:
-    """Bitplane-path GEMM: int8 activations x ``bits``-bit weight codes.
-
-    x_q: [M, K] int8; w_q: [K, N] int32 codes; w_scale: [N] fp32.
-    mode: "auto" (kernel on TPU, oracle elsewhere), "kernel" (interpret
-    off-TPU), or "ref".
-    """
-    if _use_ref(mode):
-        return ref.bitserial_gemm_ref(x_q, w_q, w_scale, bits)
-    bm, bk, bn = block
-    m, k = x_q.shape
-    n = w_q.shape[1]
-    planes = ref.bitplane_decompose(w_q, bits)
-    xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
-    pp = _pad_to(_pad_to(planes, 1, bk), 2, bn)
-    sp = _pad_to(w_scale, 0, bn)
-    out = _bitserial_kernel(xp, pp, sp, bits, bm=bm, bn=bn, bk=bk,
-                            interpret=not _on_tpu())
-    return out[:m, :n]
-
-
-def int4_matmul(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array, *,
-                block: tuple[int, int, int] = (128, 128, 128),
-                mode: str = "auto") -> jax.Array:
-    """Packed-int4-path GEMM: int8 activations x int4 weight codes.
-
-    x_q: [M, K] int8; w_q: [K, N] int32 codes in [-8, 7]; w_scale: [N].
-    """
-    if _use_ref(mode):
-        n = w_q.shape[1]
-        packed = ref.pack_int4(_pad_to(w_q, 1, 2))
-        return ref.int4_gemm_ref(x_q, packed, _pad_to(w_scale, 0, 2))[:, :n]
-    bm, bk, bn = block
-    m, k = x_q.shape
-    n = w_q.shape[1]
-    packed = ref.pack_int4(_pad_to(w_q, 1, bn), block=bn)
-    xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
-    wp = _pad_to(packed, 0, bk)
-    sp = _pad_to(w_scale, 0, bn)
-    out = _int4_kernel(xp, wp, sp, bm=bm, bn=bn, bk=bk,
-                       interpret=not _on_tpu())
-    return out[:m, :n]
-
-
-def bitserial_grouped_matmul(x_col: jax.Array, w_q: jax.Array,
-                             w_scale: jax.Array, bits: int, *,
-                             mode: str = "auto") -> jax.Array:
-    """Depthwise (grouped) bitplane GEMM: each output channel contracts
-    only its own [M, K] im2col slice of ``x_col`` [M, K, N].
-
-    No dedicated Pallas kernel: the per-channel contraction is K=kh*kw
-    taps, far below the MXU tile, so the vectorized jnp path (an exact
-    int32 ``einsum``) is the kernel on every backend. ``mode`` is
-    accepted for interface symmetry with :func:`bitserial_matmul`.
-    """
-    del mode
-    return ref.bitserial_grouped_gemm_ref(x_col, w_q, w_scale, bits)
-
-
-def int4_grouped_matmul(x_col: jax.Array, w_q: jax.Array,
-                        w_scale: jax.Array, *, mode: str = "auto"
-                        ) -> jax.Array:
-    """Depthwise (grouped) int4 GEMM over per-channel im2col slices."""
-    del mode
-    return ref.int4_grouped_gemm_ref(x_col, w_q, w_scale)
-
-
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, kv_offset: int = 0,
               block: tuple[int, int] = (128, 128),
@@ -192,6 +121,51 @@ def _norm_side(w_q: jax.Array | None, w_scale: jax.Array | None
     return w_q, w_scale
 
 
+def _split_operands(w_lut: jax.Array | None, s_lut: jax.Array | None,
+                    bits: int, w_dsp: jax.Array | None,
+                    s_dsp: jax.Array | None, k: int, bn: int, bk: int = 1
+                    ) -> tuple:
+    """The fused kernels' weight operands from the split's codes:
+    ``(planes, packed, scales, n_lut_pad, n_dsp_pad)``. The LUT codes
+    become bit-planes padded to ``bk`` rows and ``bn`` columns, the DSP
+    codes int4 bytes packed per ``bn`` block (rows padded to ``bk``),
+    an empty region one dummy block that the kernel never reads; each
+    region's scales are padded to ``bn`` and concatenated.
+    ``n_*_pad // bn`` is each region's block count."""
+    k_pad = -(-k // bk) * bk
+    if w_lut is None:      # dummy never-consumed block keeps specs in-bounds
+        planes = jnp.zeros((max(bits, 1), k_pad, bn), jnp.int8)
+        n_lut_pad, s_l = 0, None
+    else:
+        planes = _pad_to(_pad_to(ref.bitplane_decompose(w_lut, bits), 1, bk),
+                         2, bn)
+        n_lut_pad = planes.shape[2]
+        s_l = _pad_to(s_lut, 0, bn)
+    if w_dsp is None:
+        packed = jnp.zeros((k_pad, bn // 2), jnp.int8)
+        n_dsp_pad, s_d = 0, None
+    else:
+        packed = _pad_to(ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn),
+                         0, bk)
+        n_dsp_pad = packed.shape[1] * 2
+        s_d = _pad_to(s_dsp, 0, bn)
+    sp = jnp.concatenate([s for s in (s_l, s_d) if s is not None])
+    return planes, packed, sp, n_lut_pad, n_dsp_pad
+
+
+def _unpad_columns(out: jax.Array, m: int, n_lut_pad: int,
+                   w_lut: jax.Array | None, w_dsp: jax.Array | None
+                   ) -> jax.Array:
+    """The kernels' first ``m`` rows in split column order, without the
+    column padding that lands between the regions."""
+    n_lut = 0 if w_lut is None else w_lut.shape[1]
+    n_dsp = 0 if w_dsp is None else w_dsp.shape[1]
+    if n_lut_pad == n_lut:
+        return out[:m, :n_lut + n_dsp]
+    return jnp.concatenate(
+        [out[:m, :n_lut], out[:m, n_lut_pad:n_lut_pad + n_dsp]], axis=1)
+
+
 def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
                  s_lut: jax.Array | None, bits: int,
                  w_dsp: jax.Array | None, s_dsp: jax.Array | None, *,
@@ -203,8 +177,8 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
     LUT partition; None or 0 columns when absent); w_dsp: [K, n_dsp]
     int32 codes in [-8, 7]; s_*: per-column fp32 scales. Returns fp32
     [M, n_lut + n_dsp] in split column order, bit-identical to
-    :func:`hetero_matmul`. A one-sided split still takes a single
-    launch through the matching single-path kernel.
+    ``ref.fused_hetero_gemm_ref``. A one-sided split is the same launch
+    with no block in its empty region.
     """
     w_lut, s_lut = _norm_side(w_lut, s_lut)
     w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
@@ -213,28 +187,15 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
     if _use_ref(mode):
         return ref.fused_hetero_gemm_ref(x_q, w_lut, s_lut, bits,
                                          w_dsp, s_dsp)
-    if w_lut is None:
-        return int4_matmul(x_q, w_dsp, s_dsp, block=block, mode=mode)
-    if w_dsp is None:
-        return bitserial_matmul(x_q, w_lut, s_lut, bits, block=block,
-                                mode=mode)
     bm, bk, bn = block
-    m, _ = x_q.shape
-    n_lut, n_dsp = w_lut.shape[1], w_dsp.shape[1]
-    planes = ref.bitplane_decompose(w_lut, bits)
-    pp = _pad_to(_pad_to(planes, 1, bk), 2, bn)
-    packed = ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn)
-    wp = _pad_to(packed, 0, bk)
-    n_lut_pad = pp.shape[2]
-    sp = jnp.concatenate([_pad_to(s_lut, 0, bn), _pad_to(s_dsp, 0, bn)])
+    m, k = x_q.shape
+    planes, packed, sp, n_lut_pad, n_dsp_pad = _split_operands(
+        w_lut, s_lut, bits, w_dsp, s_dsp, k, bn, bk)
     xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
-    out = _fused_kernel(xp, pp, wp, sp, bits, n_lut_pad // bn,
-                        bm=bm, bn=bn, bk=bk, interpret=not _on_tpu())
-    if n_lut_pad == n_lut:
-        return out[:m, :n_lut + n_dsp]
-    # column padding landed between the regions; splice it out
-    return jnp.concatenate(
-        [out[:m, :n_lut], out[:m, n_lut_pad:n_lut_pad + n_dsp]], axis=1)
+    out = _fused_kernel(xp, planes, packed, sp, bits, n_lut_pad // bn,
+                        n_dsp_pad // bn, bm=bm, bn=bn, bk=bk,
+                        interpret=not _on_tpu())
+    return _unpad_columns(out, m, n_lut_pad, w_lut, w_dsp)
 
 
 def _conv_xla(x_sp: jax.Array, kernel: int, stride: int, pad: int,
@@ -316,31 +277,13 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
                                          bits, w_dsp, s_dsp)
     _, _, bn = block
     xp = jnp.pad(x_sp, ((pad, pad), (pad, pad), (0, 0)))
-    n_lut = 0 if w_lut is None else w_lut.shape[1]
-    n_dsp = 0 if w_dsp is None else w_dsp.shape[1]
-    if w_lut is None:      # dummy never-consumed block keeps specs in-bounds
-        planes = jnp.zeros((max(bits, 1), k, bn), jnp.int8)
-        n_lut_pad, s_l = 0, None
-    else:
-        planes = _pad_to(ref.bitplane_decompose(w_lut, bits), 2, bn)
-        n_lut_pad = planes.shape[2]
-        s_l = _pad_to(s_lut, 0, bn)
-    if w_dsp is None:
-        packed = jnp.zeros((k, bn // 2), jnp.int8)
-        n_dsp_pad, s_d = 0, None
-    else:
-        packed = ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn)
-        n_dsp_pad = packed.shape[1] * 2
-        s_d = _pad_to(s_dsp, 0, bn)
-    sp = jnp.concatenate([s for s in (s_l, s_d) if s is not None])
+    planes, packed, sp, n_lut_pad, n_dsp_pad = _split_operands(
+        w_lut, s_lut, bits, w_dsp, s_dsp, k, bn)
     out = _fused_conv_kernel(xp, planes, packed, sp, bits,
                              n_lut_pad // bn, n_dsp_pad // bn, kernel,
                              stride, out_hw, bn=bn,
                              interpret=not _on_tpu(), name=name)
-    if n_lut_pad == n_lut:
-        return out[:, :n_lut + n_dsp]
-    return jnp.concatenate(
-        [out[:, :n_lut], out[:, n_lut_pad:n_lut_pad + n_dsp]], axis=1)
+    return _unpad_columns(out, m, n_lut_pad, w_lut, w_dsp)
 
 
 def fused_grouped_matmul(x_col: jax.Array, w_lut: jax.Array | None,
@@ -350,9 +293,9 @@ def fused_grouped_matmul(x_col: jax.Array, w_lut: jax.Array | None,
     """Fused depthwise split GEMM over per-channel im2col slices.
 
     x_col: [M, K, N] over *all* N output channels in split order; the
-    first n_lut channels contract bit-serially, the rest as int4. Like
-    the single-path grouped ops, the vectorized jnp contraction is the
-    kernel on every backend (K = kh*kw taps is far below the MXU tile).
+    first n_lut channels contract bit-serially, the rest as int4. The
+    vectorized jnp contraction is the kernel on every backend (K = kh*kw
+    taps is far below the MXU tile).
     """
     del mode
     w_lut, s_lut = _norm_side(w_lut, s_lut)
@@ -373,16 +316,3 @@ def fused_depthwise_matmul(x_sp: jax.Array, kernel: int, stride: int,
     x_col = ref.conv_patches_ref(x_sp, kernel, stride, pad, out_hw)
     return fused_grouped_matmul(x_col, w_lut, s_lut, bits, w_dsp, s_dsp,
                                 mode=mode)
-
-
-def hetero_matmul(x_q: jax.Array, w_q_serial: jax.Array, s_serial: jax.Array,
-                  bits_serial: int, w_q_parallel: jax.Array,
-                  s_parallel: jax.Array, *, mode: str = "auto") -> jax.Array:
-    """The paper's split GEMM: serial-path columns then int4 columns."""
-    outs = []
-    if w_q_serial.shape[1]:
-        outs.append(bitserial_matmul(x_q, w_q_serial, s_serial, bits_serial,
-                                     mode=mode))
-    if w_q_parallel.shape[1]:
-        outs.append(int4_matmul(x_q, w_q_parallel, s_parallel, mode=mode))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)

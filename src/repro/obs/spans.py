@@ -29,8 +29,8 @@ The spans, outermost first (see ``docs/observability.md``):
   one decode step of an ``ExecutorSession``.
 
 The per-layer ``n3h.layer*`` spans open on the eager chains (golden,
-multi-device, ``PallasExecutor`` with ``fused=False`` or
-``check_timing=True``), and once, inside ``n3h.run.launch``, while a
+multi-device, ``PallasExecutor`` with ``check_timing=True`` or a
+replaced ``run_layer``), and once, inside ``n3h.run.launch``, while a
 program's chain executable is traced; a warm ``PallasExecutor.run``
 opens ``n3h.run`` and ``n3h.run.launch`` alone.
 

@@ -1,9 +1,9 @@
 """``PallasExecutor.run`` as one executable per program: ``n3h_chain``.
 
-With ``fused`` and without ``check_timing`` a run is one call into the
-program's ``n3h_chain`` executable, which traces the shared
-``chain_layers`` with the bound weights as one pytree argument and the
-input scale as a traced scalar. The contract under test:
+Without ``check_timing`` a run is one call into the program's
+``n3h_chain`` executable, which traces the shared ``chain_layers``
+with the bound weights as one pytree argument and the input scale as
+a traced scalar. The contract under test:
 
   * its logits equal the golden chain's and the eager Pallas chain's
     bit for bit (conv chains at the shapes where contracting the
@@ -15,8 +15,8 @@ input scale as a traced scalar. The contract under test:
   * each run records on the host what the eager chain records: the
     ``pallas.layer.<path>`` counts, ``layer_paths`` (also for a second
     executor on a cached table) and ``pallas.run.chain`` or
-    ``pallas.run.eager``; ``fused=False``, ``check_timing=True`` and a
-    replaced ``run_layer`` keep the eager chain;
+    ``pallas.run.eager``; ``check_timing=True`` and a replaced
+    ``run_layer`` keep the eager chain;
   * an unbound layer and a wrong input shape raise ``ExecutionError``.
 """
 import re
@@ -257,11 +257,10 @@ def _replace_run_layer(ex):
     return seen
 
 
-@pytest.mark.parametrize("how", ["unfused", "check_timing", "run_layer"])
+@pytest.mark.parametrize("how", ["check_timing", "run_layer"])
 def test_eager_chain_where_the_executable_does_not_engage(how):
     prog = _fc_prog()
-    kw = {"unfused": dict(fused=False), "check_timing":
-          dict(check_timing=True)}.get(how, {})
+    kw = {"check_timing": dict(check_timing=True)}.get(how, {})
     ex = _bound(prog, **kw)
     seen = _replace_run_layer(ex) if how == "run_layer" else None
     x = _input(prog)

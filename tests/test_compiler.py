@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import kernels
+from repro.kernels import ref as kref
 from repro.compiler import (
     GemmLayer,
     GoldenExecutor,
@@ -152,7 +152,7 @@ def test_golden_executor_bit_exact_vs_hetero_linear(ratio, bits_w):
     x_q, s_a = _quantize_acts(x, 4)
 
     got = np.asarray(ex.run_layer(0, x_q))
-    want = np.asarray(kernels.hetero_matmul(
+    want = np.asarray(kref.fused_hetero_gemm_ref(
         x_q, d.wq_serial, d.s_serial, d.bits_serial,
         d.wq_parallel, d.s_parallel))
     assert (got == want).all()

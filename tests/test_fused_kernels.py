@@ -6,8 +6,8 @@ The contract under test:
     including one-sided splits — and the actual Pallas kernel bodies
     (interpret mode) equal the oracles for dense and in-kernel-im2col
     conv variants;
-  * executor-level bit-exactness: ``PallasExecutor`` (fused default)
-    equals ``GoldenExecutor`` per layer (dense conv, depthwise, 1x1 LM
+  * executor-level bit-exactness: ``PallasExecutor`` equals
+    ``GoldenExecutor`` per layer (dense conv, depthwise, 1x1 LM
     GEMMs) and end to end on resnet18 / mobilenet_v2 / llama3.2-1b
     smoke programs, at -O0 and -O1, single- and 2-device
     (filter-parallel bundle);
@@ -15,8 +15,8 @@ The contract under test:
     ``test_conv_exec.py``) and the spatial input path feeds the fused
     conv call directly;
   * the per-program JIT cache builds fn tables atomically (threaded
-    regression for the old lazy-mutation race), its capacity is
-    configurable (constructor / env), and hits/misses land in
+    regression for the old lazy-mutation race), evicts the least
+    recently used program past its capacity, and hits/misses land in
     ``obs.metrics.METRICS`` as ``pallas.jit_cache.*``.
 """
 import threading
@@ -237,7 +237,6 @@ def test_fused_layer_bit_exact_vs_golden(spec, bits):
                          bits_w_lut=bits)
     golden = _bound(GoldenExecutor, prog)
     fused = _bound(PallasExecutor, prog)
-    assert fused.fused
     x = np.random.default_rng(7).integers(
         -8, 8, gl.geometry.in_shape).astype(np.int8)
     assert (np.asarray(golden.run_layer(0, x))
@@ -260,7 +259,7 @@ def test_fused_layer_split_ratio_corners(n_lut_frac):
 @pytest.mark.parametrize("opt_level", [0, 1])
 def test_lm_program_fused_bit_exact_mixed_bits(opt_level):
     """1x1 LM GEMMs at per-layer mixed (bits, split) through -O0/-O1:
-    fused == split == golden, layer by layer."""
+    fused == golden, layer by layer (the DSP-only layers too)."""
     prog = compile_network("llama3.2-1b", seq_len=8)
     bw = [2 + (lp.index % 4) for lp in prog.layers]
     n_luts = [lp.dims.n * (lp.index % 3) // 4 for lp in prog.layers]
@@ -270,13 +269,11 @@ def test_lm_program_fused_bit_exact_mixed_bits(opt_level):
                          opt_level=opt_level)
     golden = _bound(GoldenExecutor, prog)
     fused = _bound(PallasExecutor, prog)
-    split = _bound(PallasExecutor, prog, fused=False)
     for lp in prog.layers:
         x = np.random.default_rng(100 + lp.index).integers(
             -8, 8, (lp.dims.m, lp.dims.k)).astype(np.int8)
         g = np.asarray(golden.run_layer(lp.index, x))
         assert (g == np.asarray(fused.run_layer(lp.index, x))).all()
-        assert (g == np.asarray(split.run_layer(lp.index, x))).all()
 
 
 @pytest.mark.parametrize("arch", ["resnet18", "mobilenet_v2"])
@@ -303,8 +300,7 @@ def test_two_device_filter_bundle_fused_bit_exact():
     mex = MultiDeviceExecutor(mdp, backend="pallas")
     for gi in range(mdp.n_layers):
         mex.bind_synthetic(gi, seed=gi)
-    assert all(isinstance(e, PallasExecutor) and e.fused
-               for e in mex.executors)
+    assert all(isinstance(e, PallasExecutor) for e in mex.executors)
     assert (np.asarray(mex.run(x)) == ref_out).all()
 
 
@@ -360,7 +356,7 @@ def test_executor_reports_each_layer_path():
 
 
 # ---------------------------------------------------------------------------
-# JIT cache: atomic tables, configurable capacity, metrics, spans
+# JIT cache: atomic tables, LRU capacity, metrics, spans
 # ---------------------------------------------------------------------------
 
 
@@ -400,26 +396,20 @@ def test_program_fn_tables_built_atomically_threaded():
     assert info["misses"] >= 1 and info["hits"] + info["misses"] >= 9
 
 
-def test_jit_cache_max_constructor_and_env(monkeypatch):
-    prog = lower_network(
-        "tiny", [GemmLayer.from_conv(ConvSpec("c", 5, 16, 3, 1, 8))],
-        LUT, DSP, XC7Z020, n_luts=[8])
-    old = PallasExecutor._jit_cache_max
-    try:
-        PallasExecutor(prog, jit_cache_max=3)
-        assert PallasExecutor.cache_info()["maxsize"] == 3
-    finally:
-        PallasExecutor._jit_cache_max = old
-    # env var seeds the class default at import time
-    import importlib
-    import repro.compiler.runtime.pallas as rtp
-    monkeypatch.setenv("REPRO_PALLAS_JIT_CACHE_MAX", "5")
-    try:
-        mod = importlib.reload(rtp)
-        assert mod.PallasExecutor._jit_cache_max == 5
-    finally:
-        monkeypatch.delenv("REPRO_PALLAS_JIT_CACHE_MAX")
-        importlib.reload(rtp)
+def test_jit_cache_evicts_least_recently_used(monkeypatch):
+    monkeypatch.setattr(PallasExecutor, "_jit_cache_capacity", 2)
+    PallasExecutor.cache_clear()
+    progs = [lower_network(
+        f"tiny{i}", [GemmLayer.from_conv(ConvSpec("c", 5, 16, 3, 1, 8))],
+        LUT, DSP, XC7Z020, n_luts=[8 * i]) for i in range(3)]
+    PallasExecutor(progs[0])
+    PallasExecutor(progs[1])
+    PallasExecutor(progs[0])            # progs[1] is now the least recent
+    PallasExecutor(progs[2])
+    keys = {key[0] for key in PallasExecutor._jit_cache}
+    assert keys == {progs[0].fingerprint(), progs[2].fingerprint()}
+    assert PallasExecutor.cache_info()["maxsize"] == 2
+    assert PallasExecutor.cache_info()["programs"] == 2
 
 
 def test_jit_cache_metrics_published():

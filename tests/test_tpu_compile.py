@@ -17,10 +17,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.bitserial_gemm import bitserial_gemm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_hetero_gemm import fused_conv_gemm, fused_hetero_gemm
-from repro.kernels.int4_gemm import int4_gemm
 from repro.kernels.ops import fused_conv_matmul
 
 BN = 128
@@ -54,7 +52,8 @@ def _dense(bits):
     in per-block slabs), K over several blocks."""
     m, k, n_lut, n_dsp = 256, 1152, 2 * BN, 2 * BN
     fn = functools.partial(fused_hetero_gemm, bits=bits,
-                           n_lut_blocks=n_lut // BN)
+                           n_lut_blocks=n_lut // BN,
+                           n_dsp_blocks=n_dsp // BN)
     return fn, [((m, k), I8), ((bits, k, n_lut), I8),
                 ((k, n_dsp // 2), I8), ((n_lut + n_dsp,), F32)]
 
@@ -77,11 +76,17 @@ def _conv(in_hw, c_in, kernel, stride, pad, out_hw, n_lut_blocks,
 CASES = {
     "fused_hetero_gemm_bits4": lambda: _dense(4),
     "fused_hetero_gemm_bits8": lambda: _dense(8),
-    "int4_gemm_n256": lambda: (
-        int4_gemm, [((128, 512), I8), ((512, 128), I8), ((256,), F32)]),
-    "bitserial_gemm_n256": lambda: (
-        functools.partial(bitserial_gemm, bits=4),
-        [((128, 512), I8), ((4, 512, 256), I8), ((256,), F32)]),
+    # one-sided splits: the empty region's dummy block is never read
+    "fused_hetero_gemm_dsp_only": lambda: (
+        functools.partial(fused_hetero_gemm, bits=4, n_lut_blocks=0,
+                          n_dsp_blocks=2),
+        [((128, 512), I8), ((4, 512, BN), I8), ((512, 128), I8),
+         ((256,), F32)]),
+    "fused_hetero_gemm_lut_only": lambda: (
+        functools.partial(fused_hetero_gemm, bits=4, n_lut_blocks=2,
+                          n_dsp_blocks=0),
+        [((128, 512), I8), ((4, 512, 256), I8), ((512, BN // 2), I8),
+         ((256,), F32)]),
     # resnet18 conv2: 56x56x64, 3x3 stride 1
     "conv_resnet18_conv2": lambda: _conv(56, 64, 3, 1, 1, 56, 1, 1),
     # resnet18 conv6: 3x3 stride 2, 56 -> 28 (stride phases)
