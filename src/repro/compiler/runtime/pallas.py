@@ -57,6 +57,17 @@ run records ``layer_paths``, bumps ``pallas.layer.<path>`` once per
 layer, and counts itself as ``pallas.run.chain`` or
 ``pallas.run.eager``.
 
+Weights are laid out once, when they are bound: ``bind_layer`` turns
+the codes of every layer whose chain path runs the kernel ("kernel",
+"interpret") into the kernels' operands (``kops.split_operands``:
+bit-planes, int4 bytes, padded scales), and the chain and
+``run_layer`` hand those to the kernel as they are
+(``kops.fused_matmul_split`` / ``fused_conv_matmul_split``), so no
+call rebuilds them. Layers on another path (``xla_vmem``,
+``xla_depthwise``, every layer under ``mode="ref"``) keep their codes.
+``pallas.bind.packed`` and ``pallas.bind.codes`` count the two kinds
+of binding.
+
 Per-program JIT cache: every distinct ``(program fingerprint, mode)``
 gets one *complete* table of jitted callables, built atomically under
 the cache lock at construction and never mutated afterwards — so
@@ -107,17 +118,28 @@ def _named_jit(f, name: str):
     return jax.jit(f)
 
 
+#: the paths on which a layer runs the fused Pallas kernel, and so
+#: binds its weights in the kernels' layout (``kops.split_operands``)
+_KERNEL_PATHS = ("kernel", "interpret")
+
+
 def _make_fused_fn(bits: int, depthwise: bool, mode: str):
     """One launch over the whole split: pre-staged [m, k] (dense) or
-    [m, k, n] (depthwise) activations, both weight partitions in.
-    Named ``n3h_gemm_<path>`` by where it runs."""
+    [m, k, n] (depthwise) activations, both weight partitions in as
+    the layer's bound weights ``w`` (``kops.SplitOperands`` or the
+    ``(w_lut, s_lut, w_dsp, s_dsp)`` codes). Named
+    ``n3h_gemm_<path>`` by where it runs."""
     if depthwise:
-        def f(x_col, w_lut, s_lut, w_dsp, s_dsp):
+        def f(x_col, w):
+            w_lut, s_lut, w_dsp, s_dsp = w
             return kops.fused_grouped_matmul(x_col, w_lut, s_lut, bits,
                                              w_dsp, s_dsp, mode=mode)
         path = "xla_depthwise"
     else:
-        def f(x_q, w_lut, s_lut, w_dsp, s_dsp):
+        def f(x_q, w):
+            if isinstance(w, kops.SplitOperands):
+                return kops.fused_matmul_split(x_q, w)
+            w_lut, s_lut, w_dsp, s_dsp = w
             return kops.fused_matmul(x_q, w_lut, s_lut, bits,
                                      w_dsp, s_dsp, mode=mode)
         path = kops.kernel_path(mode)
@@ -147,12 +169,17 @@ def _make_fused_sp_fn(bits: int, geom, depthwise: bool, mode: str,
     (``kops.conv_path``)."""
     kk, st, p, oh = geom.kernel, geom.stride, geom.pad, geom.out_hw
     if depthwise:
-        def f(x_sp, w_lut, s_lut, w_dsp, s_dsp):
+        def f(x_sp, w):
+            w_lut, s_lut, w_dsp, s_dsp = w
             return kops.fused_depthwise_matmul(x_sp, kk, st, p, oh,
                                                w_lut, s_lut, bits,
                                                w_dsp, s_dsp, mode=mode)
     else:
-        def f(x_sp, w_lut, s_lut, w_dsp, s_dsp):
+        def f(x_sp, w):
+            if isinstance(w, kops.SplitOperands):
+                return kops.fused_conv_matmul_split(x_sp, kk, st, p, oh, w,
+                                                    name=name)
+            w_lut, s_lut, w_dsp, s_dsp = w
             return kops.fused_conv_matmul(x_sp, kk, st, p, oh,
                                           w_lut, s_lut, bits,
                                           w_dsp, s_dsp, mode=mode,
@@ -178,16 +205,18 @@ def _is_spatial(lp: LayerProgram, x_q) -> bool:
     return lp.geometry is not None and x_q.shape == lp.geometry.in_shape
 
 
-def _launch(fns: dict, lp: LayerProgram, x_q, wts: tuple):
+def _launch(fns: dict, lp: LayerProgram, x_q, w):
     """The layer's one fused call on int8 ``x_q`` (spatial NHWC, or
-    staged here) with its ``(w_lut, s_lut, w_dsp, s_dsp)``."""
+    staged here) with its bound weights ``w``: its
+    ``kops.SplitOperands`` where it runs the kernel, else its
+    ``(w_lut, s_lut, w_dsp, s_dsp)`` codes."""
     if _is_spatial(lp, x_q):
         # spatial input: im2col happens inside the fused call
         fn = fns["fused-sp", lp.index]
     else:
         x_q = stage_activations(lp, x_q)
         fn = fns[("fused", lp.bits_w_lut, lp.depthwise)]
-    return fn(x_q, *wts)
+    return fn(x_q, w)
 
 
 def _tail(fns: dict, lp: LayerProgram):
@@ -204,10 +233,12 @@ def _tail(fns: dict, lp: LayerProgram):
 def _make_chain_fn(program, fns: dict, mode: str):
     """The whole chain (``chain_layers``) as one executable,
     ``n3h_chain(weights, x_q, x_scale)``: ``weights`` holds each
-    layer's ``(w_lut, s_lut, w_dsp, s_dsp)`` in layer order, so the
-    codes are arguments and never constants of the program, and
-    ``x_scale`` is a traced f32 scalar. The per-layer calls are the
-    table's role-named jitted callables, inlined by the trace."""
+    layer's bound weights in layer order (its ``kops.SplitOperands``
+    where it runs the kernel, else its ``(w_lut, s_lut, w_dsp,
+    s_dsp)`` codes), so they are arguments and never constants of the
+    program, and ``x_scale`` is a traced f32 scalar. The per-layer
+    calls are the table's role-named jitted callables, inlined by the
+    trace."""
     layers = program.layers
     layer_spans = spans.layer_spans(layers)
 
@@ -251,6 +282,9 @@ class PallasExecutor(ExecutorBackend):
         spatial = all(lp.geometry is not None for lp in program.layers)
         self._chain_paths = {lp.name: self.layer_path(lp.index, spatial)
                              for lp in program.layers}
+        #: layer index -> its weights in the kernels' layout, for the
+        #: layers whose chain path runs the kernel (see bind_layer)
+        self._operands: dict[int, kops.SplitOperands] = {}
         self._chain_counts = tuple(
             (f"pallas.layer.{path}", n) for path, n in
             collections.Counter(self._chain_paths.values()).items())
@@ -332,8 +366,34 @@ class PallasExecutor(ExecutorBackend):
         return _layer_path(self.program.layers[index], spatial, self.mode)
 
     def bind_layer(self, index: int, *args, **kwargs) -> None:
+        """Validate and store the codes (``ExecutorBackend``), then, for
+        a layer whose chain path runs the kernel, lay them out once in
+        the kernels' format (``kops.split_operands``), which every call
+        then takes as it is (``pallas.bind.packed``). A layer on another
+        path keeps its codes alone (``pallas.bind.codes``)."""
         super().bind_layer(index, *args, **kwargs)
         self._chain_weights = None
+        lp = self.program.layers[index]
+        if self._chain_paths[lp.name] in _KERNEL_PATHS:
+            w = self._weights[index]
+            self._operands[index] = kops.split_operands(
+                w.w_lut, w.s_lut, lp.bits_w_lut, w.w_dsp, w.s_dsp)
+            METRICS.incr("pallas.bind.packed")
+        else:
+            self._operands.pop(index, None)
+            METRICS.incr("pallas.bind.codes")
+
+    def _layer_weights(self, index: int, path: str):
+        """What layer ``index`` hands its call on ``path``: its
+        operands laid out at bind where the path runs the kernel, else
+        its codes."""
+        w = self._weights.get(index)
+        if w is None:
+            raise ExecutionError(f"layer {index} has no bound weights")
+        wo = self._operands.get(index)
+        if wo is not None and path in _KERNEL_PATHS:
+            return wo
+        return (w.w_lut, w.s_lut, w.w_dsp, w.s_dsp)
 
     def run(self, x_q, x_scale: float = 1.0) -> jnp.ndarray:
         """Chain all layers end to end (``ExecutorBackend.run``). Without
@@ -364,33 +424,27 @@ class PallasExecutor(ExecutorBackend):
 
     def _bound_weights(self) -> tuple:
         """Every layer's bound weights as the chain's pytree argument,
-        built once per binding."""
-        weights = []
-        for lp in self.program.layers:
-            w = self._weights.get(lp.index)
-            if w is None:
-                raise ExecutionError(f"layer {lp.index} has no bound weights")
-            weights.append((w.w_lut, w.s_lut, w.w_dsp, w.s_dsp))
-        self._chain_weights = tuple(weights)
+        built once per binding: the kernels' layout for the layers that
+        run the kernel, the codes for the rest."""
+        self._chain_weights = tuple(
+            self._layer_weights(lp.index, self._chain_paths[lp.name])
+            for lp in self.program.layers)
         return self._chain_weights
 
     def run_layer(self, index: int, x_q) -> jnp.ndarray:
         """One fused kernel call for the whole layer (both split
         sides)."""
         lp = self.program.layers[index]
-        if index not in self._weights:
-            raise ExecutionError(f"layer {index} has no bound weights")
         x_q = jnp.asarray(x_q, jnp.int8)
         path = self.layer_path(index, _is_spatial(lp, x_q))
+        w = self._layer_weights(index, path)
         self.layer_paths[lp.name] = path
         METRICS.incr(f"pallas.layer.{path}")
         for cp in (lp.lut, lp.dsp):
             if cp is not None:
                 self._check_stream(lp, cp)
-        w = self._weights[index]
         with span(_LAUNCH_SPANS[path]):
-            return _launch(self._fns, lp, x_q,
-                           (w.w_lut, w.s_lut, w.w_dsp, w.s_dsp))
+            return _launch(self._fns, lp, x_q, w)
 
     def _elementwise_tail(self, lp: LayerProgram):
         """The layer's fused (jitted, program-cached) elementwise

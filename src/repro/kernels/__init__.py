@@ -8,15 +8,19 @@
 
 Each kernel has a pure-jnp oracle in ``ref.py`` and is validated against
 it in interpret mode by the test suite. ``ops.py`` holds the public
-wrappers (padding, backend dispatch, split-side normalization, GQA
-broadcast).
+wrappers (padding, backend dispatch, split-side normalization, the
+kernels' weight layout, GQA broadcast).
 """
 from repro.kernels.ops import (
+    SplitOperands,
     attention,
     fused_conv_matmul,
+    fused_conv_matmul_split,
     fused_depthwise_matmul,
     fused_grouped_matmul,
     fused_matmul,
+    fused_matmul_split,
+    split_operands,
 )
 from repro.kernels.ref import (
     bitplane_decompose,
@@ -28,8 +32,10 @@ from repro.kernels.ref import (
 )
 
 __all__ = [
-    "attention", "fused_conv_matmul", "fused_depthwise_matmul",
-    "fused_grouped_matmul", "fused_matmul",
+    "SplitOperands", "attention", "fused_conv_matmul",
+    "fused_conv_matmul_split", "fused_depthwise_matmul",
+    "fused_grouped_matmul", "fused_matmul", "fused_matmul_split",
+    "split_operands",
     "bitplane_decompose", "bitplane_reconstruct", "conv_patches_ref",
     "pack_int4", "plane_scales", "unpack_int4",
 ]

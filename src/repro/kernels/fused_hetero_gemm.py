@@ -76,9 +76,10 @@ def unpack_int4_block(p: jax.Array) -> jax.Array:
 
 def dsp_blocks(packed: jax.Array, bn: int) -> jax.Array:
     """[K, N//2] packed bytes -> [N//bn, K, bn//2]: one contiguous slab
-    per DSP column block. A (bk, bn//2) block of the 2-D layout is only
-    legal on the TPU while it spans the whole array (its 64 lanes are
-    not a multiple of 128); a slab's last dim always does."""
+    per DSP column block, the layout both kernels take. A (bk, bn//2)
+    block of the 2-D layout is only legal on the TPU while it spans the
+    whole array (its 64 lanes are not a multiple of 128); a slab's last
+    dim always does."""
     k, nh = packed.shape
     return packed.reshape(k, nh // (bn // 2), bn // 2).transpose(1, 0, 2)
 
@@ -130,9 +131,12 @@ def _check_regions(planes: jax.Array, packed: jax.Array,
                          f"{bits}")
     if nn == 0:
         raise ValueError("grid needs at least one column block")
-    if planes.shape[2] < bn or packed.shape[1] < bn // 2:
+    if planes.shape[2] < bn or packed.shape[0] < 1:
         raise ValueError("each region needs at least one weight block "
                          "(use a dummy when the region is empty)")
+    if packed.ndim != 3 or packed.shape[2] != bn // 2:
+        raise ValueError(f"packed {packed.shape} is not [blocks, K, "
+                         f"{bn // 2}] slabs (dsp_blocks)")
     if w_scale.shape[0] < nn * bn:
         raise ValueError(f"scales {w_scale.shape[0]} < grid columns "
                          f"{nn * bn}")
@@ -148,8 +152,9 @@ def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
     """Single-launch split GEMM over pre-padded operands.
 
     x: [M, K] int8; planes: [max(bits, 1), K, >=bn] int8 {0, 1} plane
-    stack of the LUT columns; packed: [K, >=bn//2] int8
-    ``ref.pack_int4`` bytes (``block=bn``) of the DSP columns; w_scale:
+    stack of the LUT columns; packed: [>=1, K, bn//2] int8 slabs of
+    the DSP columns' ``ref.pack_int4`` bytes (``block=bn``), one per
+    column block (``dsp_blocks``); w_scale:
     [(n_lut_blocks + n_dsp_blocks) * bn] fp32 in split region order,
     handed to the kernel as a [1, N] row in (1, bn) blocks. The grid
     covers ``n_lut_blocks`` LUT column blocks then ``n_dsp_blocks`` DSP
@@ -192,7 +197,7 @@ def fused_hetero_gemm(x: jax.Array, planes: jax.Array, packed: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         **kwargs,
-    )(x, planes, dsp_blocks(packed, bn), w_scale[:n].reshape(1, n))
+    )(x, planes, packed, w_scale[:n].reshape(1, n))
 
 
 # ---------------------------------------------------------------------------
@@ -300,8 +305,9 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
     activation block (code 0 is real 0.0 under the symmetric
     quantizer); planes: [bits, kernel**2*C, >=bn] LUT plane stack in
     (kh, kw, c) row order (the HWIO flattening); packed:
-    [kernel**2*C, >=bn//2] int4-pair bytes (``ref.pack_int4``,
-    ``block=bn``); w_scale: [(n_lut_blocks + n_dsp_blocks) * bn] fp32
+    [>=1, kernel**2*C, bn//2] slabs of int4-pair bytes
+    (``ref.pack_int4``, ``block=bn``), one per DSP column block
+    (``dsp_blocks``); w_scale: [(n_lut_blocks + n_dsp_blocks) * bn] fp32
     in split region order. The grid covers ``n_lut_blocks`` LUT column
     blocks then ``n_dsp_blocks`` DSP blocks; a region with zero blocks
     still needs one (dummy, never-consumed) weight block so its
@@ -351,5 +357,4 @@ def fused_conv_gemm(x_sp: jax.Array, planes: jax.Array, packed: jax.Array,
         name=name,
         **kwargs,
     )
-    return _named(call, name)(x, planes, dsp_blocks(packed, bn),
-                              w_scale[:n].reshape(1, n))
+    return _named(call, name)(x, planes, packed, w_scale[:n].reshape(1, n))

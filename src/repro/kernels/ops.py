@@ -2,6 +2,11 @@
 
 Responsibilities the raw kernels don't take:
   * shape padding to block multiples (and un-padding the result);
+  * the weights' layout for the fused kernels (:func:`split_operands`:
+    bit-planes, int4 bytes, padded scales), which a caller that binds
+    weights once makes once (``PallasExecutor.bind_layer``) and hands
+    to :func:`fused_matmul_split` / :func:`fused_conv_matmul_split`;
+    the code-taking wrappers lay out on every call;
   * backend dispatch by ``mode``: "auto" runs the compiled Pallas
     kernel on a TPU and the pure-jnp oracle anywhere else; "kernel"
     runs the kernel (in interpret mode off the TPU, which is how the
@@ -19,12 +24,17 @@ These wrappers are the only entry points the model zoo uses.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.fused_hetero_gemm import (
+    DEFAULT_BN,
+    dsp_blocks,
     fused_conv_gemm as _fused_conv_kernel,
     fused_conv_vmem_bytes,
     fused_hetero_gemm as _fused_kernel,
@@ -121,49 +131,80 @@ def _norm_side(w_q: jax.Array | None, w_scale: jax.Array | None
     return w_q, w_scale
 
 
-def _split_operands(w_lut: jax.Array | None, s_lut: jax.Array | None,
-                    bits: int, w_dsp: jax.Array | None,
-                    s_dsp: jax.Array | None, k: int, bn: int, bk: int = 1
-                    ) -> tuple:
-    """The fused kernels' weight operands from the split's codes:
-    ``(planes, packed, scales, n_lut_pad, n_dsp_pad)``. The LUT codes
-    become bit-planes padded to ``bk`` rows and ``bn`` columns, the DSP
-    codes int4 bytes packed per ``bn`` block (rows padded to ``bk``),
-    an empty region one dummy block that the kernel never reads; each
-    region's scales are padded to ``bn`` and concatenated.
-    ``n_*_pad // bn`` is each region's block count."""
-    k_pad = -(-k // bk) * bk
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["planes", "packed", "scales"],
+                   meta_fields=["bits", "n_lut", "n_dsp", "bn"])
+@dataclasses.dataclass(frozen=True)
+class SplitOperands:
+    """A split layer's weights in the fused kernels' layout, as
+    :func:`split_operands` lays them out; a pytree whose leaves are the
+    three arrays. ``planes`` [max(bits, 1), k, n_lut_pad] int8 {0, 1}
+    bit-planes of the LUT codes; ``packed`` [n_dsp_pad // bn, k,
+    bn // 2] int4 pairs of the DSP codes, one slab per ``bn`` block
+    (``ref.pack_int4``, ``dsp_blocks``); an empty region is one dummy
+    block that the kernel never reads; ``scales`` [n_lut_pad +
+    n_dsp_pad] fp32, each region's padded to ``bn``. ``n_lut`` and
+    ``n_dsp`` are the unpadded column counts."""
+    planes: jax.Array
+    packed: jax.Array
+    scales: jax.Array
+    bits: int
+    n_lut: int
+    n_dsp: int
+    bn: int
+
+    @property
+    def n_lut_blocks(self) -> int:
+        return -(-self.n_lut // self.bn)
+
+    @property
+    def n_dsp_blocks(self) -> int:
+        return -(-self.n_dsp // self.bn)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "bn"))
+def split_operands(w_lut: jax.Array | None, s_lut: jax.Array | None,
+                   bits: int, w_dsp: jax.Array | None,
+                   s_dsp: jax.Array | None, *, bn: int = DEFAULT_BN
+                   ) -> SplitOperands:
+    """The fused kernels' weight operands from the split's codes (as
+    :func:`fused_matmul` takes them): the LUT codes as bit-planes
+    padded to ``bn`` columns, the DSP codes as int4 bytes packed per
+    ``bn`` block, one slab a block, and each region's scales padded to
+    ``bn`` and concatenated. Rows stay unpadded, the conv kernel's
+    format. One jitted call per distinct shape."""
+    w_lut, s_lut = _norm_side(w_lut, s_lut)
+    w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
+    if w_lut is None and w_dsp is None:
+        raise ValueError("split_operands: both split sides are empty")
+    k = (w_lut if w_lut is not None else w_dsp).shape[0]
+    scales = []
     if w_lut is None:      # dummy never-consumed block keeps specs in-bounds
-        planes = jnp.zeros((max(bits, 1), k_pad, bn), jnp.int8)
-        n_lut_pad, s_l = 0, None
+        planes = jnp.zeros((max(bits, 1), k, bn), jnp.int8)
     else:
-        planes = _pad_to(_pad_to(ref.bitplane_decompose(w_lut, bits), 1, bk),
-                         2, bn)
-        n_lut_pad = planes.shape[2]
-        s_l = _pad_to(s_lut, 0, bn)
+        planes = _pad_to(ref.bitplane_decompose(w_lut, bits), 2, bn)
+        scales.append(_pad_to(s_lut, 0, bn))
     if w_dsp is None:
-        packed = jnp.zeros((k_pad, bn // 2), jnp.int8)
-        n_dsp_pad, s_d = 0, None
+        packed = jnp.zeros((1, k, bn // 2), jnp.int8)
     else:
-        packed = _pad_to(ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn),
-                         0, bk)
-        n_dsp_pad = packed.shape[1] * 2
-        s_d = _pad_to(s_dsp, 0, bn)
-    sp = jnp.concatenate([s for s in (s_l, s_d) if s is not None])
-    return planes, packed, sp, n_lut_pad, n_dsp_pad
+        packed = dsp_blocks(ref.pack_int4(_pad_to(w_dsp, 1, bn), block=bn),
+                            bn)
+        scales.append(_pad_to(s_dsp, 0, bn))
+    return SplitOperands(
+        planes, packed, jnp.concatenate(scales), bits,
+        0 if w_lut is None else w_lut.shape[1],
+        0 if w_dsp is None else w_dsp.shape[1], bn)
 
 
-def _unpad_columns(out: jax.Array, m: int, n_lut_pad: int,
-                   w_lut: jax.Array | None, w_dsp: jax.Array | None
-                   ) -> jax.Array:
+def _unpad_columns(out: jax.Array, m: int, wo: SplitOperands) -> jax.Array:
     """The kernels' first ``m`` rows in split column order, without the
     column padding that lands between the regions."""
-    n_lut = 0 if w_lut is None else w_lut.shape[1]
-    n_dsp = 0 if w_dsp is None else w_dsp.shape[1]
-    if n_lut_pad == n_lut:
-        return out[:m, :n_lut + n_dsp]
+    n_lut_pad = wo.n_lut_blocks * wo.bn
+    if n_lut_pad == wo.n_lut:
+        return out[:m, :wo.n_lut + wo.n_dsp]
     return jnp.concatenate(
-        [out[:m, :n_lut], out[:m, n_lut_pad:n_lut_pad + n_dsp]], axis=1)
+        [out[:m, :wo.n_lut], out[:m, n_lut_pad:n_lut_pad + wo.n_dsp]],
+        axis=1)
 
 
 def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
@@ -178,7 +219,9 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
     int32 codes in [-8, 7]; s_*: per-column fp32 scales. Returns fp32
     [M, n_lut + n_dsp] in split column order, bit-identical to
     ``ref.fused_hetero_gemm_ref``. A one-sided split is the same launch
-    with no block in its empty region.
+    with no block in its empty region. Off the oracle, the codes are
+    laid out (:func:`split_operands`) and :func:`fused_matmul_split`
+    runs the kernel.
     """
     w_lut, s_lut = _norm_side(w_lut, s_lut)
     w_dsp, s_dsp = _norm_side(w_dsp, s_dsp)
@@ -187,15 +230,35 @@ def fused_matmul(x_q: jax.Array, w_lut: jax.Array | None,
     if _use_ref(mode):
         return ref.fused_hetero_gemm_ref(x_q, w_lut, s_lut, bits,
                                          w_dsp, s_dsp)
+    wo = split_operands(w_lut, s_lut, bits, w_dsp, s_dsp, bn=block[2])
+    return fused_matmul_split(x_q, wo, block=block)
+
+
+def _check_rows(wo: SplitOperands, k: int) -> None:
+    if wo.planes.shape[1] != k:
+        raise ValueError(f"operands laid out for k={wo.planes.shape[1]}, "
+                         f"the activations have k={k}")
+
+
+def fused_matmul_split(x_q: jax.Array, wo: SplitOperands, *,
+                       block: tuple[int, int, int] = (128, 128, 128)
+                       ) -> jax.Array:
+    """:func:`fused_matmul` on operands :func:`split_operands` laid out:
+    the Pallas kernel (compiled on the TPU, interpreted elsewhere), with
+    no per-call weight work but a row pad to ``bk`` where ``k`` is not
+    a multiple of it."""
     bm, bk, bn = block
     m, k = x_q.shape
-    planes, packed, sp, n_lut_pad, n_dsp_pad = _split_operands(
-        w_lut, s_lut, bits, w_dsp, s_dsp, k, bn, bk)
+    _check_rows(wo, k)
+    if bn != wo.bn:
+        raise ValueError(f"operands laid out for bn={wo.bn}, block has "
+                         f"bn={bn}")
+    planes, packed = _pad_to(wo.planes, 1, bk), _pad_to(wo.packed, 1, bk)
     xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
-    out = _fused_kernel(xp, planes, packed, sp, bits, n_lut_pad // bn,
-                        n_dsp_pad // bn, bm=bm, bn=bn, bk=bk,
-                        interpret=not _on_tpu())
-    return _unpad_columns(out, m, n_lut_pad, w_lut, w_dsp)
+    out = _fused_kernel(xp, planes, packed, wo.scales, wo.bits,
+                        wo.n_lut_blocks, wo.n_dsp_blocks, bm=bm, bn=bn,
+                        bk=bk, interpret=not _on_tpu())
+    return _unpad_columns(out, m, wo)
 
 
 def _conv_xla(x_sp: jax.Array, kernel: int, stride: int, pad: int,
@@ -275,15 +338,25 @@ def fused_conv_matmul(x_sp: jax.Array, kernel: int, stride: int, pad: int,
         x_col = ref.conv_patches_ref(x_sp, kernel, stride, pad, out_hw)
         return ref.fused_hetero_gemm_ref(x_col.reshape(m, k), w_lut, s_lut,
                                          bits, w_dsp, s_dsp)
-    _, _, bn = block
+    wo = split_operands(w_lut, s_lut, bits, w_dsp, s_dsp, bn=block[2])
+    return fused_conv_matmul_split(x_sp, kernel, stride, pad, out_hw, wo,
+                                   name=name)
+
+
+def fused_conv_matmul_split(x_sp: jax.Array, kernel: int, stride: int,
+                            pad: int, out_hw: int, wo: SplitOperands, *,
+                            name: str = "fused_conv_gemm") -> jax.Array:
+    """:func:`fused_conv_matmul`'s kernel path on operands
+    :func:`split_operands` laid out: the fused conv kernel launched as
+    ``name`` (compiled on the TPU, interpreted elsewhere), with no
+    per-call weight work."""
+    _check_rows(wo, kernel * kernel * x_sp.shape[2])
     xp = jnp.pad(x_sp, ((pad, pad), (pad, pad), (0, 0)))
-    planes, packed, sp, n_lut_pad, n_dsp_pad = _split_operands(
-        w_lut, s_lut, bits, w_dsp, s_dsp, k, bn)
-    out = _fused_conv_kernel(xp, planes, packed, sp, bits,
-                             n_lut_pad // bn, n_dsp_pad // bn, kernel,
-                             stride, out_hw, bn=bn,
+    out = _fused_conv_kernel(xp, wo.planes, wo.packed, wo.scales, wo.bits,
+                             wo.n_lut_blocks, wo.n_dsp_blocks, kernel,
+                             stride, out_hw, bn=wo.bn,
                              interpret=not _on_tpu(), name=name)
-    return _unpad_columns(out, m, n_lut_pad, w_lut, w_dsp)
+    return _unpad_columns(out, out_hw * out_hw, wo)
 
 
 def fused_grouped_matmul(x_col: jax.Array, w_lut: jax.Array | None,

@@ -17,10 +17,18 @@ a traced scalar. The contract under test:
     executor on a cached table) and ``pallas.run.chain`` or
     ``pallas.run.eager``; ``check_timing=True`` and a replaced
     ``run_layer`` keep the eager chain;
-  * an unbound layer and a wrong input shape raise ``ExecutionError``.
+  * an unbound layer and a wrong input shape raise ``ExecutionError``;
+  * weights are laid out for the kernels once, when they are bound:
+    under ``mode="kernel"`` the chain's weights argument holds no int32
+    leaf of a kernel-path layer, its jaxpr makes no bit-planes or int4
+    bytes from them, ``pallas.bind.packed``/``.codes`` count the two
+    kinds of binding, and the outputs stay bitwise the eager chain's
+    and ``mode="ref"``'s.
 """
 import re
 
+import jax
+import jax.extend.core as jcore
 import numpy as np
 import pytest
 
@@ -29,6 +37,7 @@ from repro.compiler import (
     GoldenExecutor,
     PallasExecutor,
     bind_synthetic,
+    compile_network,
     lower_network,
 )
 from repro.compiler.runtime import ExecutionError
@@ -82,21 +91,31 @@ def _delta(before: dict, after: dict) -> dict:
             if after[k] != before.get(k, 0)}
 
 
-@pytest.mark.parametrize("arch,in_hw", [("resnet18", 28), ("resnet18", 32),
-                                        ("mobilenet_v2", 28),
-                                        ("mobilenet_v2", 32),
-                                        ("resnet50", 32)])
-def test_chain_bit_exact_vs_golden_and_eager(arch, in_hw):
+@pytest.mark.parametrize("arch,in_hw,mode", [
+    pytest.param(arch, in_hw, mode, id=f"{arch}-{in_hw}" + (
+        f"-{mode}" if mode != "auto" else ""))
+    for arch, in_hw, mode in [
+        ("resnet18", 28, "auto"), ("resnet18", 32, "auto"),
+        ("mobilenet_v2", 28, "auto"), ("mobilenet_v2", 32, "auto"),
+        ("resnet50", 32, "auto"),
+        ("resnet18", 32, "kernel"), ("mobilenet_v2", 32, "kernel"),
+        ("resnet50", 32, "kernel")]])
+def test_chain_bit_exact_vs_golden_and_eager(arch, in_hw, mode):
     """In-hw 28 is ``test_conv_exec``'s golden comparison, which runs
     the chain executable too; here the chain also meets the eager
-    Pallas chain, and the golden chain at in-hw 32."""
+    Pallas chain, and the golden chain at in-hw 32. Under "kernel"
+    (the kernels interpreted, on weights laid out at bind) the chain
+    and the eager chain meet ``mode="ref"``."""
     prog = _cnn_prog(arch, in_hw)
     x = _input(prog)
-    chain = np.asarray(_bound(prog).run(x))
-    eager = np.asarray(_bound(prog, check_timing=True).run(x))
+    chain = np.asarray(_bound(prog, mode=mode).run(x))
+    eager = np.asarray(_bound(prog, mode=mode, check_timing=True).run(x))
     assert chain.shape == (1, 10) and np.abs(chain).sum() > 0
     assert chain.tobytes() == eager.tobytes()
-    if in_hw != 28:
+    if mode == "kernel":
+        want = np.asarray(_bound(prog, mode="ref").run(x))
+        assert chain.tobytes() == want.tobytes()
+    elif in_hw != 28:
         golden = np.asarray(_bound(prog, GoldenExecutor).run(x))
         assert chain.tobytes() == golden.tobytes()
 
@@ -272,3 +291,159 @@ def test_eager_chain_where_the_executable_does_not_engage(how):
     assert out.tobytes() == np.asarray(_bound(prog).run(x)).tobytes()
     if seen is not None:
         assert seen == [lp.index for lp in prog.layers]
+
+
+# ---------------------------------------------------------------------------
+# weights laid out for the kernels at bind time
+# ---------------------------------------------------------------------------
+
+KERNEL_PATHS = ("kernel", "interpret")
+
+
+def _bind_counters() -> dict:
+    return {k: METRICS.counter(f"pallas.bind.{k}")
+            for k in ("packed", "codes")}
+
+
+def _ops_on_tainted(jaxpr, tainted: set) -> tuple[set, set]:
+    """The primitives ``jaxpr`` applies to values computed from the
+    ``tainted`` variables, outside Pallas kernel bodies (a
+    ``pallas_call`` consumes its operands as they are), and the
+    outputs so computed. Calls with a sub-jaxpr over the same operands
+    (``pjit``, ``closed_call``, custom derivatives) are followed."""
+    found, tainted = set(), set(tainted)
+    for eqn in jaxpr.eqns:
+        hit = [i for i, v in enumerate(eqn.invars)
+               if not isinstance(v, jcore.Literal) and v in tainted]
+        if not hit or eqn.primitive.name == "pallas_call":
+            continue
+        subs = [p for p in eqn.params.values()
+                if isinstance(p, (jcore.Jaxpr, jcore.ClosedJaxpr))]
+        sub = subs[0] if len(subs) == 1 else None
+        sub = getattr(sub, "jaxpr", sub)
+        if sub is not None and len(sub.invars) == len(eqn.invars):
+            got, out = _ops_on_tainted(sub, {sub.invars[i] for i in hit})
+            found |= got
+            tainted |= {v for v, w in zip(eqn.outvars, sub.outvars)
+                        if w in out}
+        else:
+            found.add(eqn.primitive.name)
+            tainted |= set(eqn.outvars)
+    return found, {v for v in jaxpr.outvars if v in tainted}
+
+
+@pytest.mark.parametrize("arch,in_hw", [("resnet18", 224),
+                                        ("mobilenet_v2", 32),
+                                        ("resnet50", 32)])
+def test_chain_takes_kernel_layout_weights(arch, in_hw):
+    """Under "kernel" the chain's weights argument holds each
+    kernel-path layer's ``SplitOperands``, no leaf of which is int32,
+    and no op of the chain's jaxpr makes bit-planes or int4 bytes
+    (``shift_right_*``, ``and``) from them: the layout ran at bind.
+    The other layers (resnet18's stem on ``xla_vmem`` at 224,
+    mobilenet_v2's depthwise layers) keep their int32 codes, and the
+    stem's own layout from them stays in the chain."""
+    prog = compile_network(arch, in_hw=in_hw) if in_hw == 224 \
+        else _cnn_prog(arch, in_hw)
+    ex = _bound(prog, mode="kernel")
+    weights = ex._bound_weights()
+    kernel_leaves, other_leaves = [], []
+    for lp, w in zip(prog.layers, weights):
+        leaves = jax.tree_util.tree_leaves(w)
+        if ex._chain_paths[lp.name] in KERNEL_PATHS:
+            assert isinstance(w, ops.SplitOperands)
+            assert all(leaf.dtype != np.int32 for leaf in leaves)
+            kernel_leaves += [True] * len(leaves)
+        else:
+            assert not isinstance(w, ops.SplitOperands)
+            assert any(leaf.dtype == np.int32 for leaf in leaves)
+            kernel_leaves += [False] * len(leaves)
+            other_leaves += leaves
+    assert any(kernel_leaves)
+    if in_hw == 224 or arch == "mobilenet_v2":
+        assert other_leaves
+    closed = jax.make_jaxpr(ex._fns["chain",])(weights, _input(prog),
+                                              np.float32(1.0))
+    n_w = len(kernel_leaves)
+    invars = closed.jaxpr.invars[:n_w]
+    found, _ = _ops_on_tainted(
+        closed.jaxpr, {v for v, k in zip(invars, kernel_leaves) if k})
+    assert not {p for p in found if p.startswith("shift_right")
+                or p == "and"}, found
+    # the taint reaches what the codes-keeping layers do with theirs
+    if in_hw == 224:
+        stem, _ = _ops_on_tainted(
+            closed.jaxpr, {v for v, k in zip(invars, kernel_leaves)
+                           if not k})
+        assert "and" in stem and any(p.startswith("shift_right")
+                                     for p in stem)
+
+
+@pytest.mark.parametrize("arch,in_hw,mode,packed", [
+    ("resnet18", 224, "kernel", 20), ("resnet18", 224, "ref", 0),
+    ("mobilenet_v2", 32, "kernel", None)])
+def test_bind_counts_packed_and_code_layers(arch, in_hw, mode, packed):
+    """``pallas.bind.packed`` counts the layers whose chain path runs
+    the kernel and ``pallas.bind.codes`` the rest (resnet18 at 224: 20
+    and the stem); under ``mode="ref"`` every layer keeps its codes, so
+    the oracle shares no layout with the code under test."""
+    prog = compile_network(arch, in_hw=in_hw) if in_hw == 224 \
+        else _cnn_prog(arch, in_hw)
+    before = _bind_counters()
+    ex = _bound(prog, mode=mode)
+    got = {k: v - before[k] for k, v in _bind_counters().items()}
+    on_kernel = sum(p in KERNEL_PATHS for p in ex._chain_paths.values())
+    if packed is not None:
+        assert on_kernel == packed
+    assert got == {"packed": on_kernel,
+                   "codes": len(prog.layers) - on_kernel}
+    assert set(ex._operands) == {lp.index for lp in prog.layers
+                                 if ex._chain_paths[lp.name]
+                                 in KERNEL_PATHS}
+    if mode == "ref":
+        assert not ex._operands
+        assert all(not isinstance(w, ops.SplitOperands)
+                   for w in ex._bound_weights())
+
+
+def test_rebinding_lays_out_the_new_codes():
+    """Under "kernel" a rebound layer's operands are laid out anew from
+    the new codes: the chain gives the new codes' result, bitwise
+    ``mode="ref"``'s, on the same trace."""
+    prog = _cnn_prog("resnet18", 28)
+    ex = _bound(prog, mode="kernel")
+    x = _input(prog, seed=2)
+    lp = prog.layers[5]
+    assert ex._chain_paths[lp.name] in KERNEL_PATHS
+    old = ex._operands[lp.index]
+    before = np.asarray(ex.run(x))
+    bind_synthetic(ex, lp, seed=1000)
+    assert ex._operands[lp.index] is not old
+    after = np.asarray(ex.run(x))
+    want = _bound(prog, mode="ref")
+    bind_synthetic(want, lp, seed=1000)
+    assert before.tobytes() != after.tobytes()
+    assert after.tobytes() == np.asarray(want.run(x)).tobytes()
+    assert ex._fns["chain",]._cache_size() == 1
+
+
+def test_dense_layers_row_pad_matches_ref():
+    """Dense, non-spatial layers with k % 128 != 0 under "kernel":
+    ``run_layer`` and the FC chain hand the kernel the operands laid
+    out at bind, row padded to the 128-row block, bit-identical to
+    ``mode="ref"``."""
+    prog = _fc_prog()
+    ex = _bound(prog, mode="kernel")
+    want = _bound(prog, mode="ref")
+    x = _input(prog, seed=3)
+    assert np.asarray(ex.run(x)).tobytes() == \
+        np.asarray(want.run(x)).tobytes()
+    for lp in prog.layers:
+        assert lp.dims.k % 128 and isinstance(ex._operands[lp.index],
+                                              ops.SplitOperands)
+        x = np.random.default_rng(lp.index).integers(
+            -8, 8, (lp.dims.m, lp.dims.k)).astype(np.int8)
+        got = np.asarray(ex.run_layer(lp.index, x))
+        assert ex.layer_paths[lp.name] == "interpret"
+        assert got.tobytes() == np.asarray(
+            want.run_layer(lp.index, x)).tobytes()

@@ -69,14 +69,18 @@ def _weight_fetches(prog) -> int:
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["golden", "pallas"])
+@pytest.mark.parametrize("backend", ["golden", "pallas", "pallas-kernel"])
 @pytest.mark.parametrize("name,family", FAMILIES)
 def test_session_step_matches_reference(name, family, backend):
+    """``pallas-kernel`` runs the Pallas kernels (interpreted off the
+    TPU) on the weights each executor laid out when they were bound."""
     prog = _decode_prog(name)
     assert prog.step is not None and prog.step.family == family
     ref = ReferenceSession(prog)
     ref.bind_synthetic_all(seed=0)
-    sess = ExecutorSession(prog, backend=backend)
+    backend, _, mode = backend.partition("-")
+    sess = ExecutorSession(prog, backend=backend,
+                           **({"mode": mode} if mode else {}))
     sess.bind_synthetic_all(seed=0)
     for pos, t in enumerate([3, 5, 1]):
         tok = np.array([t], np.int32)

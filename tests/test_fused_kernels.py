@@ -39,7 +39,7 @@ from repro.compiler import (
 from repro.core.scheduler import XC7Z020, DspCoreConfig, LutCoreConfig
 from repro.core.workloads import ConvSpec
 from repro.kernels import ops, ref
-from repro.kernels.fused_hetero_gemm import fused_conv_gemm
+from repro.kernels.fused_hetero_gemm import dsp_blocks, fused_conv_gemm
 from repro.models.cnn import CNNConfig, specs_for
 
 LUT = LutCoreConfig(m=8, n=16, k=128)
@@ -130,12 +130,13 @@ def test_fused_conv_kernel_in_kernel_im2col_equals_staged(kernel, stride,
     want = ref.fused_hetero_gemm_ref(col, w_lut, s_lut, bits, w_dsp, s_dsp)
 
     planes = ops._pad_to(ref.bitplane_decompose(w_lut, bits), 2, bn)
-    packed = ref.pack_int4(ops._pad_to(w_dsp, 1, bn), block=bn)
+    packed = dsp_blocks(ref.pack_int4(ops._pad_to(w_dsp, 1, bn), block=bn),
+                        bn)
     sp = jnp.concatenate([ops._pad_to(s_lut, 0, bn),
                           ops._pad_to(s_dsp, 0, bn)])
     xp = jnp.pad(x_sp, ((pad, pad), (pad, pad), (0, 0)))
     out = fused_conv_gemm(xp, planes, packed, sp, bits,
-                          planes.shape[2] // bn, packed.shape[1] * 2 // bn,
+                          planes.shape[2] // bn, packed.shape[0],
                           kernel, stride, out_hw, bn=bn, interpret=True)
     nlp = planes.shape[2]
     got = jnp.concatenate([out[:, :n_lut], out[:, nlp:nlp + n_dsp]], axis=1)

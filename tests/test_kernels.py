@@ -5,7 +5,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.fused_hetero_gemm import fused_hetero_gemm
+from repro.kernels.fused_hetero_gemm import dsp_blocks, fused_hetero_gemm
 from repro.kernels import ops
 
 RNG = np.random.default_rng(0)
@@ -42,7 +42,8 @@ def _lut_only(x, planes, scale, bits):
     """The fused kernel with every column block in the LUT region and
     one dummy packed block that is never read."""
     k = planes.shape[1]
-    return fused_hetero_gemm(x, planes, jnp.zeros((k, 32), jnp.int8), scale,
+    return fused_hetero_gemm(x, planes, jnp.zeros((1, k, 32), jnp.int8),
+                             scale,
                              bits, planes.shape[2] // 64, 0,
                              bm=64, bn=64, bk=64, interpret=True)
 
@@ -51,8 +52,9 @@ def _dsp_only(x, packed, scale):
     """The fused kernel with every column block in the DSP region and
     dummy planes that are never read."""
     k = packed.shape[0]
-    return fused_hetero_gemm(x, jnp.zeros((1, k, 64), jnp.int8), packed,
-                             scale, 0, 0, packed.shape[1] // 32,
+    return fused_hetero_gemm(x, jnp.zeros((1, k, 64), jnp.int8),
+                             dsp_blocks(packed, 64), scale, 0, 0,
+                             packed.shape[1] // 32,
                              bm=64, bn=64, bk=64, interpret=True)
 
 
@@ -109,7 +111,7 @@ def test_fused_hetero_gemm_rejects_a_region_without_a_block(
         planes_n, n_lut_blocks, n_dsp_blocks, match):
     x = jnp.zeros((64, 64), jnp.int8)
     planes = jnp.zeros((2, 64, planes_n), jnp.int8)
-    packed = jnp.zeros((64, 32), jnp.int8)
+    packed = jnp.zeros((1, 64, 32), jnp.int8)
     with pytest.raises(ValueError, match=match):
         fused_hetero_gemm(x, planes, packed, jnp.ones(128, jnp.float32), 2,
                           n_lut_blocks, n_dsp_blocks, bm=64, bn=64, bk=64,

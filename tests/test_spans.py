@@ -211,9 +211,9 @@ def test_executable_is_jit_of_the_role_name():
     ex = _executor("pallas", layers)
     lp = ex.program.layers[0]
     fn = ex._fns["fused-sp", lp.index]
-    w = ex._weights[0]
+    w = ex._bound_weights()[lp.index]
     x = np.zeros(lp.geometry.in_shape, np.int8)
-    text = fn.lower(x, w.w_lut, w.s_lut, w.w_dsp, w.s_dsp).as_text()
+    text = fn.lower(x, w).as_text()
     assert f"@jit_n3h_conv_{ex.layer_path(0, spatial=True)}" in text
 
 

@@ -55,7 +55,7 @@ def _dense(bits):
                            n_lut_blocks=n_lut // BN,
                            n_dsp_blocks=n_dsp // BN)
     return fn, [((m, k), I8), ((bits, k, n_lut), I8),
-                ((k, n_dsp // 2), I8), ((n_lut + n_dsp,), F32)]
+                ((n_dsp // BN, k, BN // 2), I8), ((n_lut + n_dsp,), F32)]
 
 
 def _conv(in_hw, c_in, kernel, stride, pad, out_hw, n_lut_blocks,
@@ -69,7 +69,7 @@ def _conv(in_hw, c_in, kernel, stride, pad, out_hw, n_lut_blocks,
         n_dsp_blocks=n_dsp_blocks, kernel=kernel, stride=stride,
         out_hw=out_hw)
     return fn, [((hp, hp, c_in), I8), ((bits, k, n_lut_blocks * BN), I8),
-                ((k, n_dsp_blocks * BN // 2), I8),
+                ((max(n_dsp_blocks, 1), k, BN // 2), I8),
                 (((n_lut_blocks + n_dsp_blocks) * BN,), F32)]
 
 
@@ -80,12 +80,12 @@ CASES = {
     "fused_hetero_gemm_dsp_only": lambda: (
         functools.partial(fused_hetero_gemm, bits=4, n_lut_blocks=0,
                           n_dsp_blocks=2),
-        [((128, 512), I8), ((4, 512, BN), I8), ((512, 128), I8),
+        [((128, 512), I8), ((4, 512, BN), I8), ((2, 512, BN // 2), I8),
          ((256,), F32)]),
     "fused_hetero_gemm_lut_only": lambda: (
         functools.partial(fused_hetero_gemm, bits=4, n_lut_blocks=2,
                           n_dsp_blocks=0),
-        [((128, 512), I8), ((4, 512, 256), I8), ((512, BN // 2), I8),
+        [((128, 512), I8), ((4, 512, 256), I8), ((1, 512, BN // 2), I8),
          ((256,), F32)]),
     # resnet18 conv2: 56x56x64, 3x3 stride 1
     "conv_resnet18_conv2": lambda: _conv(56, 64, 3, 1, 1, 56, 1, 1),
